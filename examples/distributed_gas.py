@@ -20,13 +20,13 @@ from repro.core.partition import metis_like_partition
 from repro.data.graphs import citation_graph
 from repro.gnn.model import GNNSpec, full_forward, init_gnn
 from repro.core.gas import gcn_edge_weights
-from repro.launch.mesh import compat_make_mesh
 from repro.train.optimizer import adamw_init, adamw_update, clip_by_global_norm
 
 
 def main():
     ranks = 4
-    mesh = compat_make_mesh((ranks,), ("data",))
+    mesh = jax.make_mesh((ranks,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     g = citation_graph(num_nodes=2000, num_features=64, num_classes=6,
                        homophily=0.72, feature_noise=2.2, seed=7)
     part = metis_like_partition(g.indptr, g.indices, ranks, seed=0)

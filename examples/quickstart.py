@@ -38,6 +38,7 @@ from repro.core import runtime as R
 from repro.data.graphs import citation_graph
 from repro.gnn.model import GNNSpec
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.gas_trainer import FullBatchTrainer, TrainConfig
 
 
@@ -47,8 +48,7 @@ def main(backend=None, epochs=60, nodes=2500, history_dtype=None,
     history_dtype = H.resolve_history_dtype(history_dtype)
     history_storage = H.resolve_history_storage(history_storage)
     print(f"kernel backend: {backend}, history dtype: {history_dtype}, "
-          f"history storage: {history_storage} "
-          f"(host kind {'available' if H.host_storage_supported() else 'unavailable -> device'}), "
+          f"history storage: {history_storage}, "
           f"prefetch depth: {prefetch_depth}")
     graph = citation_graph(num_nodes=nodes, num_features=128, num_classes=7,
                            homophily=0.75, feature_noise=2.0, seed=0)
@@ -130,6 +130,7 @@ if __name__ == "__main__":
                     help="training epochs (CI smoke uses a small value)")
     ap.add_argument("--nodes", type=int, default=2500)
     args = ap.parse_args()
+    enable_compile_cache()
     main(args.backend, epochs=args.epochs, nodes=args.nodes,
          history_dtype=args.history_dtype,
          history_storage=args.history_storage,

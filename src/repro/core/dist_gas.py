@@ -37,17 +37,6 @@ from . import history as H
 from .batch import GASBatch
 
 
-def _compat_shard_map(f, mesh, in_specs, out_specs):
-    """`jax.shard_map(check_vma=...)` is jax >= 0.5; older versions expose
-    `jax.experimental.shard_map.shard_map(check_rep=...)`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 @dataclass
 class DistStructs:
     """Static distributed plan. The per-rank local graph is the SAME typed
@@ -327,13 +316,14 @@ def make_dist_loss_fn(spec, structs: DistStructs, mesh,
         # non-int8 stores, so the pytree structure is static per flag)
         if quantized not in smapped_cache:
             nscl = (num_layers - 1) if quantized else 0
-            smapped_cache[quantized] = _compat_shard_map(
+            smapped_cache[quantized] = jax.shard_map(
                 make_shard_body(quantized), mesh=mesh,
                 in_specs=(P(), [P(axis)] * (num_layers - 1),
                           [P(axis)] * nscl, P(axis), P(axis),
                           P(axis), batch_specs, plan_specs),
                 out_specs=(P(), P(), [P(axis)] * (num_layers - 1),
-                           [P(axis)] * nscl, P(axis)))
+                           [P(axis)] * nscl, P(axis)),
+                check_vma=False)
         return smapped_cache[quantized]
 
     def loss_fn(params, store: Union[H.HistoryStore, List], x_pad, y_pad,

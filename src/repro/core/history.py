@@ -181,18 +181,17 @@ def resolve_history_storage(storage: Optional[str] = None) -> str:
 @functools.lru_cache(maxsize=1)
 def _memory_kinds() -> Tuple[Optional[str], Optional[str]]:
     """(host_kind, device_kind) for the default device, or (None, None)
-    when the runtime has no addressable-memory API. On TPU this is
-    ("pinned_host", "device"); on CPU both resolve to "unpinned_host"
-    (host RAM IS device memory there), so the placement/streaming code
-    paths run for real in CI and degenerate to no-op moves."""
+    when the runtime has no addressable-memory API (any other error
+    propagates). On TPU this is ("pinned_host", "device")."""
+    dev = jax.devices()[0]
     try:
-        dev = jax.devices()[0]
         kinds = {m.kind for m in dev.addressable_memories()}
-        host = next((k for k in ("pinned_host", "unpinned_host")
-                     if k in kinds), None)
-        return host, dev.default_memory().kind
-    except Exception:
+        default = dev.default_memory().kind
+    except (AttributeError, NotImplementedError):
         return None, None
+    host = next((k for k in ("pinned_host", "unpinned_host")
+                 if k in kinds), None)
+    return host, default
 
 
 def host_storage_supported() -> bool:
@@ -440,10 +439,9 @@ class HistoryStore:
     rows device-ward with an async `jax.device_put` (XLA overlaps the
     host->device copy with unrelated compute; see `prefetch`, which the
     epoch pipeline uses to hide the whole pull behind the previous
-    batch's backward). On hosts whose default memory IS host RAM (CPU
-    CI) the same code paths run as no-op moves; if the runtime has no
-    host memory kind at all, placement silently stays on device
-    (`host_storage_supported`).
+    batch's backward). A runtime with no host memory kind at all
+    (`host_storage_supported`) cannot hold a "host" store: `place`
+    raises instead of leaving the tables on the device.
     """
     tables: Tuple[jnp.ndarray, ...]
     age: jnp.ndarray
@@ -485,12 +483,17 @@ class HistoryStore:
 
     def place(self) -> "HistoryStore":
         """Re-place the tables per `storage` (host memory kind for
-        "host" stores, when the runtime has one) — idempotent, and the
-        re-placement hook after a checkpoint restore, whose
-        `jnp.asarray` leaves land in default device memory."""
-        kind = (_memory_kinds()[0] if self.storage == "host" else None)
-        if kind is None:
+        "host" stores) — idempotent, and the re-placement hook after a
+        checkpoint restore, whose `jnp.asarray` leaves land in default
+        device memory. A "host" store on a runtime without a host memory
+        kind raises rather than staying on the device."""
+        if self.storage != "host":
             return self
+        kind = _memory_kinds()[0]
+        if kind is None:
+            raise RuntimeError(
+                "history storage='host' needs a host memory kind, and "
+                f"this runtime ({jax.devices()[0].platform}) has none")
         tables = _put_kind(self.tables, kind)
         scales = (None if self.scales is None
                   else _put_kind(self.scales, kind))

@@ -166,9 +166,12 @@ def _accuracy(logits, labels, mask):
 # Plan / state construction
 # ---------------------------------------------------------------------------
 
-def build_plan(graph: Graph, spec, config: GASConfig) -> GASPlan:
+def build_plan(graph: Graph, spec, config: GASConfig,
+               part: Optional[np.ndarray] = None) -> GASPlan:
     """Partition the graph, build (stack, upload) the typed batch
-    structures, resolve the kernel backend — everything static."""
+    structures, resolve the kernel backend — everything static. `part`
+    (e.g. another plan's `part` over the same graph) skips partitioning,
+    the slow host-side step of plan construction."""
     from repro.gnn.model import BLOCK_OPS, UNIT_BLOCK_OPS
 
     backend = ops.resolve_backend(config.backend)
@@ -178,7 +181,11 @@ def build_plan(graph: Graph, spec, config: GASConfig) -> GASPlan:
     unit_blocks = build_blocks and spec.op in UNIT_BLOCK_OPS
     N = graph.num_nodes
 
-    if config.partitioner == "metis":
+    if part is not None:
+        part = np.asarray(part)
+        if part.shape != (N,):
+            raise ValueError(f"part must have shape ({N},), got {part.shape}")
+    elif config.partitioner == "metis":
         part = metis_like_partition(graph.indptr, graph.indices,
                                     config.num_parts, seed=config.seed)
     else:

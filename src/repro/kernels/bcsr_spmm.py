@@ -41,9 +41,9 @@ def _kernel(cols_ref, x_ref, vals_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "interpret"))
 def bcsr_spmm(x: jnp.ndarray, blk_vals: jnp.ndarray, blk_cols: jnp.ndarray,
               *, bn: int = 128, bd: int = 128,
-              interpret: bool = True) -> jnp.ndarray:
-    """See module docstring. interpret=True validates on CPU; on real TPU
-    pass interpret=False."""
+              interpret: bool) -> jnp.ndarray:
+    """See module docstring. interpret=True runs the Pallas interpreter
+    (CPU); interpret=False compiles the kernel for the TPU."""
     R, K, bn_, bn2 = blk_vals.shape
     assert bn_ == bn and bn2 == bn, (blk_vals.shape, bn)
     N, D = x.shape

@@ -66,7 +66,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                    static_argnames=("block_s", "interpret"))
 def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  pos: jnp.ndarray, *, block_s: int = 256,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool) -> jnp.ndarray:
     """q: [B, Kh, G, Dh] (roped, one token); k/v: [B, S, Kh, Dh] cache;
     pos: scalar int32 decode position. Returns [B, Kh, G, Dh]."""
     B, Kh, G, Dh = q.shape

@@ -32,7 +32,12 @@ lrelu'(z) with the delta term folded in once per K step, so the feature
 dimension can be tiled and summed like any other contraction.
 
 All internal compute is float32; callers pad rows/features to tile
-boundaries (see `ops.edge_softmax_aggregate`).
+boundaries (see `ops.edge_softmax_aggregate`). The per-node vectors
+(ad/as/M/L/delta and the dad/das outputs, [H, n]) move as whole-H
+[H, bn] blocks — a TPU block's second-minor dim must be a multiple of 8
+or the array's whole dim — and each step reads or writes row h.
+Per-destination columns and per-source rows swap orientation by one
+bn x bn transpose (`tiles.to_col` / `tiles.to_row`).
 """
 from __future__ import annotations
 
@@ -43,13 +48,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tiles import to_col, to_row
+
 NEG = -1e30     # f32-internal mask value (kernels always compute in f32)
 TINY = 1e-30
 
 
+def _vec(ref, h):
+    """Row h of a whole-H [H, bn] block as an f32 [1, bn] row."""
+    return ref[pl.ds(h, 1), :].astype(jnp.float32)
+
+
 def _scores(ad_col, as_row, mult, neg_slope):
-    """Masked leaky-relu attention scores for one bn x bn block (f32)."""
-    z = ad_col[:, None] + as_row[None, :]
+    """Masked leaky-relu attention scores for one bn x bn block (f32).
+    ad_col [bn, bn] (constant along columns) / as_row [1, bn]."""
+    z = ad_col + as_row
     s = jnp.where(z > 0, z, neg_slope * z)
     return z, jnp.where(mult > 0, s, NEG)
 
@@ -57,6 +70,7 @@ def _scores(ad_col, as_row, mult, neg_slope):
 def _fwd_kernel(cols_ref, ad_ref, as_ref, wx_ref, ublk_ref,
                 out_ref, mmax_ref, lsum_ref, m_scr, l_scr, acc,
                 *, neg_slope: float):
+    h = pl.program_id(1)
     k = pl.program_id(3)
 
     @pl.when(k == 0)
@@ -65,8 +79,8 @@ def _fwd_kernel(cols_ref, ad_ref, as_ref, wx_ref, ublk_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc[...] = jnp.zeros_like(acc)
 
-    ad = ad_ref[0, :].astype(jnp.float32)           # [bn] dst logits
-    as_ = as_ref[0, :].astype(jnp.float32)          # [bn] src logits
+    ad = to_col(_vec(ad_ref, h))                      # dst logits down rows
+    as_ = _vec(as_ref, h)                           # src logits along lanes
     mult = ublk_ref[0, 0]                           # [bn, bn] multiplicities
     _, s = _scores(ad, as_, mult, neg_slope)
 
@@ -83,8 +97,8 @@ def _fwd_kernel(cols_ref, ad_ref, as_ref, wx_ref, ublk_ref,
     @pl.when(k == pl.num_programs(3) - 1)
     def _finish():
         out_ref[0] = acc[...] / jnp.maximum(l_scr[...], TINY)
-        mmax_ref[0, :] = m_scr[:, 0]
-        lsum_ref[0, :] = l_scr[:, 0]
+        mmax_ref[pl.ds(h, 1), :] = to_row(m_scr[...])
+        lsum_ref[pl.ds(h, 1), :] = to_row(l_scr[...])
 
 
 @functools.partial(jax.jit,
@@ -92,7 +106,7 @@ def _fwd_kernel(cols_ref, ad_ref, as_ref, wx_ref, ublk_ref,
 def edge_softmax_fwd(ad: jnp.ndarray, as_: jnp.ndarray, wx: jnp.ndarray,
                      ublk_vals: jnp.ndarray, blk_cols: jnp.ndarray, *,
                      neg_slope: float = 0.2, bn: int = 128, bd: int = 128,
-                     interpret: bool = True):
+                     interpret: bool):
     """Online-softmax attention aggregation over BCSR blocks.
 
     ad [H, R*bn] destination logits; as_ [H, C*bn] source logits;
@@ -113,8 +127,8 @@ def edge_softmax_fwd(ad: jnp.ndarray, as_: jnp.ndarray, wx: jnp.ndarray,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, r)),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, cols[r, k])),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, r)),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, cols[r, k])),
             pl.BlockSpec((1, bn, bd),
                          lambda r, h, f, k, cols: (h, cols[r, k], f)),
             pl.BlockSpec((1, 1, bn, bn),
@@ -122,8 +136,8 @@ def edge_softmax_fwd(ad: jnp.ndarray, as_: jnp.ndarray, wx: jnp.ndarray,
         ],
         out_specs=[
             pl.BlockSpec((1, bn, bd), lambda r, h, f, k, cols: (h, r, f)),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, r)),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, r)),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, r)),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, r)),
         ],
         scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32),
                         pltpu.VMEM((bn, 1), jnp.float32),
@@ -142,7 +156,8 @@ def edge_softmax_fwd(ad: jnp.ndarray, as_: jnp.ndarray, wx: jnp.ndarray,
 
 def _alpha(ad_col, as_row, mult, mmax, lsum, neg_slope):
     """Recompute normalized attention + leaky-relu slope for one block.
-    mmax/lsum broadcast over the *destination* axis (axis of ad_col)."""
+    mmax/lsum [bn, bn] are constant along the destination rows (like
+    ad_col)."""
     z, s = _scores(ad_col, as_row, mult, neg_slope)
     p = mult * jnp.exp(s - mmax)
     alpha = p / jnp.maximum(lsum, TINY)
@@ -153,6 +168,7 @@ def _alpha(ad_col, as_row, mult, mmax, lsum, neg_slope):
 def _bwd_row_kernel(cols_ref, ad_ref, as_ref, wx_ref, g_ref, mmax_ref,
                     lsum_ref, delta_ref, ublk_ref, dad_ref, dad_scr,
                     *, neg_slope: float):
+    h = pl.program_id(1)
     ft = pl.program_id(2)
     k = pl.program_id(3)
 
@@ -160,11 +176,11 @@ def _bwd_row_kernel(cols_ref, ad_ref, as_ref, wx_ref, g_ref, mmax_ref,
     def _init():
         dad_scr[...] = jnp.zeros_like(dad_scr)
 
-    ad = ad_ref[0, :].astype(jnp.float32)
-    as_ = as_ref[0, :].astype(jnp.float32)
+    ad = to_col(_vec(ad_ref, h))
+    as_ = _vec(as_ref, h)
     mult = ublk_ref[0, 0]
-    mmax = mmax_ref[0, :][:, None]                   # [bn, 1] dst rows
-    lsum = lsum_ref[0, :][:, None]
+    mmax = to_col(_vec(mmax_ref, h))                   # dst rows
+    lsum = to_col(_vec(lsum_ref, h))
     _, ap = _alpha(ad, as_, mult, mmax, lsum, neg_slope)
 
     # dz = alpha' * (g.v - delta): the f-contraction g.v is tiled over ft;
@@ -176,19 +192,19 @@ def _bwd_row_kernel(cols_ref, ad_ref, as_ref, wx_ref, g_ref, mmax_ref,
 
     @pl.when(ft == 0)
     def _delta_term():
-        delta = delta_ref[0, :][:, None]
+        delta = to_col(_vec(delta_ref, h))[:, 0:1]
         dad_scr[...] += -(ap.sum(axis=-1, keepdims=True) * delta)
 
     @pl.when((ft == pl.num_programs(2) - 1) & (k == pl.num_programs(3) - 1))
     def _finish():
-        dad_ref[0, :] = dad_scr[:, 0]
+        dad_ref[pl.ds(h, 1), :] = to_row(dad_scr[...])
 
 
 @functools.partial(jax.jit,
                    static_argnames=("neg_slope", "bn", "bd", "interpret"))
 def edge_softmax_bwd_row(ad, as_, wx, g, mmax, lsum, delta, ublk_vals,
                          blk_cols, *, neg_slope: float = 0.2, bn: int = 128,
-                         bd: int = 128, interpret: bool = True):
+                         bd: int = 128, interpret: bool):
     """Destination-side cotangent dad [H, R*bn] = rowsum(dz) over the
     forward block structure. g is the out cotangent [H, R*bn, Fp];
     delta [H, R*bn] = sum_f g * out (computed by the caller in XLA)."""
@@ -203,18 +219,18 @@ def edge_softmax_bwd_row(ad, as_, wx, g, mmax, lsum, delta, ublk_vals,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, r)),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, cols[r, k])),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, r)),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, cols[r, k])),
             pl.BlockSpec((1, bn, bd),
                          lambda r, h, f, k, cols: (h, cols[r, k], f)),
             pl.BlockSpec((1, bn, bd), lambda r, h, f, k, cols: (h, r, f)),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, r)),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, r)),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, r)),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, r)),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, r)),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, r)),
             pl.BlockSpec((1, 1, bn, bn),
                          lambda r, h, f, k, cols: (r, k, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, r)),
+        out_specs=pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, r)),
         scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)],
     )
     kern = functools.partial(_bwd_row_kernel, neg_slope=neg_slope)
@@ -229,6 +245,7 @@ def edge_softmax_bwd_row(ad, as_, wx, g, mmax, lsum, delta, ublk_vals,
 def _bwd_col_kernel(colst_ref, as_ref, ad_ref, wx_ref, g_ref, mmax_ref,
                     lsum_ref, delta_ref, ublkt_ref, dwx_ref, das_ref,
                     das_scr, *, neg_slope: float):
+    h = pl.program_id(1)
     ft = pl.program_id(2)
     k = pl.program_id(3)
 
@@ -242,14 +259,14 @@ def _bwd_col_kernel(colst_ref, as_ref, ad_ref, wx_ref, g_ref, mmax_ref,
 
     # transposed block: rows = sources, columns = destinations; softmax
     # stats (mmax/lsum/delta) are destination-side -> broadcast over rows
-    as_ = as_ref[0, :].astype(jnp.float32)           # [bn] sources (rows)
-    ad = ad_ref[0, :].astype(jnp.float32)            # [bn] dsts (cols)
+    as_ = to_col(_vec(as_ref, h))                      # sources down rows
+    ad = _vec(ad_ref, h)                             # dsts along lanes
     mult_t = ublkt_ref[0, 0]
-    z_t = as_[:, None] + ad[None, :]
+    z_t = as_ + ad
     s_t = jnp.where(z_t > 0, z_t, neg_slope * z_t)
     s_t = jnp.where(mult_t > 0, s_t, NEG)
-    mmax = mmax_ref[0, :][None, :]                   # [1, bn] dst cols
-    lsum = lsum_ref[0, :][None, :]
+    mmax = _vec(mmax_ref, h)                         # [1, bn] dst cols
+    lsum = _vec(lsum_ref, h)
     p_t = mult_t * jnp.exp(s_t - mmax)
     alpha_t = p_t / jnp.maximum(lsum, TINY)
     ap = alpha_t * jnp.where(z_t > 0, 1.0, neg_slope)
@@ -263,12 +280,12 @@ def _bwd_col_kernel(colst_ref, as_ref, ad_ref, wx_ref, g_ref, mmax_ref,
 
     @pl.when(ft == 0)
     def _delta_term():
-        delta = delta_ref[0, :][None, :]
+        delta = _vec(delta_ref, h)
         das_scr[...] += -(ap * delta).sum(axis=-1, keepdims=True)
 
     @pl.when((ft == pl.num_programs(2) - 1) & (k == pl.num_programs(3) - 1))
     def _finish():
-        das_ref[0, :] = das_scr[:, 0]
+        das_ref[pl.ds(h, 1), :] = to_row(das_scr[...])
 
 
 @functools.partial(jax.jit,
@@ -276,7 +293,7 @@ def _bwd_col_kernel(colst_ref, as_ref, ad_ref, wx_ref, g_ref, mmax_ref,
 def edge_softmax_bwd_col(ad, as_, wx, g, mmax, lsum, delta, ublk_vals_t,
                          blk_cols_t, *, neg_slope: float = 0.2,
                          bn: int = 128, bd: int = 128,
-                         interpret: bool = True):
+                         interpret: bool):
     """Source-side cotangents over the *transposed* block structure:
     dwx [H, C*bn, Fp] = alpha^T @ g and das [H, C*bn] = colsum(dz).
     All destination-side operands (ad, mmax, lsum, delta, g) are fetched
@@ -292,20 +309,20 @@ def edge_softmax_bwd_col(ad, as_, wx, g, mmax, lsum, delta, ublk_vals_t,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, r)),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, cols[r, k])),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, r)),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, cols[r, k])),
             pl.BlockSpec((1, bn, bd), lambda r, h, f, k, cols: (h, r, f)),
             pl.BlockSpec((1, bn, bd),
                          lambda r, h, f, k, cols: (h, cols[r, k], f)),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, cols[r, k])),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, cols[r, k])),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, cols[r, k])),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, cols[r, k])),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, cols[r, k])),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, cols[r, k])),
             pl.BlockSpec((1, 1, bn, bn),
                          lambda r, h, f, k, cols: (r, k, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bn, bd), lambda r, h, f, k, cols: (h, r, f)),
-            pl.BlockSpec((1, bn), lambda r, h, f, k, cols: (h, r)),
+            pl.BlockSpec((H, bn), lambda r, h, f, k, cols: (0, r)),
         ],
         scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)],
     )

@@ -1,23 +1,19 @@
 """History pull (row gather) Pallas kernels.
 
-The paper's PyGAS hides history I/O behind compute with CUDA streams; the
-TPU analogue is a pipelined row-mover: the scalar-prefetched index vector
-drives the BlockSpec index_map, so Pallas's automatic double-buffering
-overlaps the HBM->VMEM row DMA of iteration i+1 with the copy-out of
-iteration i. Rows are moved in (rows_per_tile x bd) tiles.
+Rows move in their aligned 8-row HBM tile and are picked out in VMEM
+(`tiles.GROUP`, `tiles.pick_row`).
 
-`gather_rows_dq` is the quantized variant: the table holds symmetric
-per-row int8 rows (see `core.history.quantize_rows`) and the per-row f32
-scale vector rides along as a SECOND scalar-prefetch operand, so the
-dequant multiply happens on the VPU between the int8 row DMA and the f32
-copy-out — only int8 bytes ever cross HBM for the table, and no f32 copy
-of any table row exists outside VMEM. Unlike `gather_rows`, its table
-rows are HAND-PIPELINED: the table stays whole in HBM (`pltpu.ANY`) and
-rows move in (8, bd) tiles via explicit `pltpu.make_async_copy` double
-buffering — grid step t+1's eight rows stream into one VMEM slot while
-step t's rows dequantize out of the other. The 8-row tile also clears
-the old (1, bd)-tile debt: sublane-dim 8 matches the f32 min tile on
-real TPUs (int8 stages at 8 sublanes and widens to f32 in VMEM).
+`gather_rows` moves GROUP output rows per grid step. The table is passed
+GROUP times, each operand with a BlockSpec whose index_map selects the
+tile group of one of the step's rows from the scalar-prefetched index
+vector, so Pallas's automatic double buffering overlaps the next step's
+tile DMAs with this step's picks, and Pallas handles the table's partial
+last tile. With `scales` the table holds symmetric per-row int8 rows
+(`core.history.quantize_rows`) and the dequant multiply runs on the VPU
+after the pick: only int8 bytes cross HBM for the table.
+
+`gather_rows_vq` is the codebook variant: uint8 code rows [N, S] move the
+same way and are decoded in VMEM against the resident codebook.
 """
 from __future__ import annotations
 
@@ -28,176 +24,99 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tiles import GROUP, pick_row, vq_decode_tile
 
-def _kernel(idx_ref, table_ref, out_ref):
-    out_ref[...] = table_ref[...]
+def tile_specs(block_w: int, n_lane_blocks: bool):
+    """GROUP BlockSpecs over one table: spec j fetches the tile group of
+    row idx[g * GROUP + j] (lane block d when `n_lane_blocks`)."""
+    def spec(j):
+        if n_lane_blocks:
+            return pl.BlockSpec(
+                (GROUP, block_w),
+                lambda g, d, idx, *_: (idx[g * GROUP + j] // GROUP, d))
+        return pl.BlockSpec(
+            (GROUP, block_w),
+            lambda g, idx, *_: (idx[g * GROUP + j] // GROUP, 0))
+    return [spec(j) for j in range(GROUP)]
+
+
+def _pad_idx(idx):
+    M = idx.shape[0]
+    Mp = max(-(-M // GROUP) * GROUP, GROUP)
+    return jnp.pad(idx, (0, Mp - M)) if Mp != M else idx
+
+
+def _gather_kernel(*refs, dq: bool):
+    idx_ref = refs[0]
+    scl_ref = refs[1] if dq else None
+    tiles, out_ref = refs[1 + dq:-1], refs[-1]
+    g = pl.program_id(0)
+    rows = []
+    for j, tile in enumerate(tiles):
+        i = g * GROUP + j
+        row = pick_row(tile[...], idx_ref[i] % GROUP)
+        if dq:
+            row = row * scl_ref[i]
+        rows.append(row)
+    out_ref[...] = jnp.concatenate(rows, axis=0).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bd", "interpret"))
-def gather_rows(table: jnp.ndarray, idx: jnp.ndarray, *, bd: int = 128,
-                interpret: bool = True) -> jnp.ndarray:
-    """out[i] = table[idx[i]]. idx must be pre-clipped to [0, N). table's
-    feature dim must be a multiple of bd."""
+def gather_rows(table: jnp.ndarray, idx: jnp.ndarray,
+                scales: jnp.ndarray = None, *, bd: int = 128,
+                interpret: bool) -> jnp.ndarray:
+    """out[i] = table[idx[i]], or with `scales` [N] f32 the dequantizing
+    pull out[i] = table[idx[i]] * scales[idx[i]] in f32. idx must be
+    pre-clipped to [0, N); table's feature dim must be a multiple of bd.
+    Rows move in aligned tile groups (module docstring)."""
     N, D = table.shape
     M = idx.shape[0]
     assert D % bd == 0, (D, bd)
-    grid = (M, D // bd)
+    idx_p = _pad_idx(idx)
+    prefetch = (idx_p,)
+    out_dtype = table.dtype
+    if scales is not None:
+        assert scales.shape == (N,), (scales.shape, N)
+        # per-OUTPUT-row scales: the SMEM operand grows with M, not N
+        prefetch += (jnp.take(scales, idx_p, mode="clip"),)
+        out_dtype = jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, bd), lambda i, d, idx: (idx[i], d))],
-        out_specs=pl.BlockSpec((1, bd), lambda i, d, idx: (i, d)),
-    )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, D), table.dtype),
-        interpret=interpret,
-    )(idx, table)
-
-
-MB = 8  # gather_rows_dq row-tile height (f32 min sublane tile)
-
-
-def _make_dq_kernel(mb, bd, nd):
-    def _dq_kernel(idx_ref, scl_ref, table_ref, out_ref, stage_ref,
-                   sem_ref):
-        g = pl.program_id(0)
-        d = pl.program_id(1)
-        t = g * nd + d                       # flattened sequential step
-        nt = pl.num_programs(0) * nd
-        slot = jax.lax.rem(t, 2)
-
-        def rows(step, slot_, start):
-            gg = step // nd
-            dd = jax.lax.rem(step, nd)
-
-            def one(row, carry):
-                dma = pltpu.make_async_copy(
-                    table_ref.at[idx_ref[gg * mb + row],
-                                 pl.ds(dd * bd, bd)],
-                    stage_ref.at[slot_, row], sem_ref.at[slot_])
-                dma.start() if start else dma.wait()
-                return carry
-
-            jax.lax.fori_loop(0, mb, one, None)
-
-        @pl.when(t == 0)
-        def _warmup():
-            rows(0, 0, start=True)
-
-        # stream the NEXT tile's rows before draining this one — the
-        # HBM->VMEM DMAs overlap this step's dequant + copy-out
-        @pl.when(t + 1 < nt)
-        def _prefetch():
-            rows(t + 1, jax.lax.rem(t + 1, 2), start=True)
-
-        rows(t, slot, start=False)
-
-        # per-row scalar dequant, statically unrolled over the tile —
-        # bitwise table[idx[i]] * scales[idx[i]], same as the oracle
-        for row in range(mb):
-            out_ref[row, :] = (stage_ref[slot, row].astype(jnp.float32) *
-                               scl_ref[idx_ref[g * mb + row]])
-
-    return _dq_kernel
-
-
-@functools.partial(jax.jit, static_argnames=("bd", "interpret"))
-def gather_rows_dq(table: jnp.ndarray, scales: jnp.ndarray,
-                   idx: jnp.ndarray, *, bd: int = 128,
-                   interpret: bool = True) -> jnp.ndarray:
-    """out[i] = table[idx[i]] * scales[idx[i]] in f32 — the fused
-    dequantizing gather. table [N, D] int8 (any dtype works; the cast is
-    a no-op for floats), scales [N] f32, idx pre-clipped to [0, N).
-    Rows move in double-buffered (8, bd) tiles (module docstring)."""
-    N, D = table.shape
-    M = idx.shape[0]
-    assert scales.shape == (N,), (scales.shape, N)
-    assert D % bd == 0, (D, bd)
-    Mp = max(-(-M // MB) * MB, MB)
-    idx_p = jnp.pad(idx, (0, Mp - M)) if Mp != M else idx
-    grid = (Mp // MB, D // bd)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((MB, bd), lambda g, d, idx, scl: (g, d)),
-        scratch_shapes=[pltpu.VMEM((2, MB, bd), table.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
+        num_scalar_prefetch=len(prefetch),
+        grid=(idx_p.shape[0] // GROUP, D // bd),
+        in_specs=tile_specs(bd, True),
+        out_specs=pl.BlockSpec((GROUP, bd), lambda g, d, *_: (g, d)),
     )
     out = pl.pallas_call(
-        _make_dq_kernel(MB, bd, D // bd),
+        functools.partial(_gather_kernel, dq=scales is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Mp, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((idx_p.shape[0], D), out_dtype),
         interpret=interpret,
-    )(idx_p, scales, table)
-    return out[:M] if Mp != M else out
+    )(*prefetch, *([table] * GROUP))
+    return out[:M]
 
 
-def _make_vq_kernel(mb, s, c, ds, dp):
-    d = s * ds
-
-    def _vq_gather_kernel(idx_ref, scl_ref, table_ref, cb_ref, out_ref,
-                          stage_ref, sem_ref):
-        g = pl.program_id(0)
-        nt = pl.num_programs(0)
-        slot = jax.lax.rem(g, 2)
-
-        def rows(step, slot_, start):
-            def one(row, carry):
-                dma = pltpu.make_async_copy(
-                    table_ref.at[idx_ref[step * mb + row]],
-                    stage_ref.at[slot_, row], sem_ref.at[slot_])
-                dma.start() if start else dma.wait()
-                return carry
-
-            jax.lax.fori_loop(0, mb, one, None)
-
-        @pl.when(g == 0)
-        def _warmup():
-            rows(0, 0, start=True)
-
-        # stream the NEXT tile's code rows while this one decodes — same
-        # double-buffered schedule as `_make_dq_kernel`
-        @pl.when(g + 1 < nt)
-        def _prefetch():
-            rows(g + 1, jax.lax.rem(g + 1, 2), start=True)
-
-        rows(g, slot, start=False)
-
-        # codebook decode as one one-hot matmul per subvector: every
-        # output element is exactly one codebook element * 1.0 plus
-        # exact zeros, so this is bitwise `core.history.vq_decode_rows`
-        codes = stage_ref[slot].astype(jnp.int32)          # [mb, S]
-        iota_c = jax.lax.broadcasted_iota(jnp.int32, (mb, c), 1)
-        parts = []
-        for sub in range(s):
-            onehot = (codes[:, sub][:, None] == iota_c).astype(jnp.float32)
-            parts.append(jnp.dot(onehot, cb_ref[sub],
-                                 preferred_element_type=jnp.float32))
-        rec = jnp.concatenate(parts, axis=1)               # [mb, d]
-        svec = jnp.stack([scl_ref[idx_ref[g * mb + row]]
-                          for row in range(mb)])
-        out_ref[...] = jnp.pad(rec * svec[:, None],
-                               ((0, 0), (0, dp - d)))
-
-    return _vq_gather_kernel
+def _vq_gather_kernel(idx_ref, scl_ref, *refs, dp: int):
+    tiles, cb_ref, out_ref = refs[:GROUP], refs[GROUP], refs[GROUP + 1]
+    g = pl.program_id(0)
+    codes = jnp.concatenate(
+        [pick_row(tile[...], idx_ref[g * GROUP + j] % GROUP)
+         for j, tile in enumerate(tiles)], axis=0)          # [GROUP, S]
+    rec = vq_decode_tile(codes, cb_ref[...])
+    svec = jnp.concatenate(
+        [jnp.full((1, 1), scl_ref[g * GROUP + j], jnp.float32)
+         for j in range(GROUP)], axis=0)
+    out_ref[...] = jnp.pad(rec * svec, ((0, 0), (0, dp - rec.shape[1])))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows_vq(table: jnp.ndarray, codebook: jnp.ndarray,
                    scales: jnp.ndarray, idx: jnp.ndarray, *,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool) -> jnp.ndarray:
     """out[i] = decode(table[idx[i]], codebook) * scales[idx[i]] in f32 —
     the codebook-dequantizing gather (`history_dtype="vq"`). table [N, S]
     uint8 codes, codebook [S, C, ds] f32, scales [N] f32, idx pre-clipped
-    to [0, N). Only S code bytes per row ever cross HBM; the f32 row is
-    born in VMEM. Code rows move in the same hand-pipelined
-    double-buffered (8, S) tiles as `gather_rows_dq`; the decode happens
-    between the DMA wait and the copy-out. The codebook is too large for
-    the SMEM scalar-prefetch lane, so it rides as a whole-VMEM operand
-    instead (~0.5 MB worst case, resident across the whole grid).
+    to [0, N). Only code bytes cross HBM; the f32 row is born in VMEM.
+    The codebook rides as a whole-VMEM operand, resident across the grid.
     Returns [M, Dp] with d = S*ds zero-padded to a 128-lane multiple —
     callers slice `[:, :d]`."""
     N, S = table.shape
@@ -205,24 +124,20 @@ def gather_rows_vq(table: jnp.ndarray, codebook: jnp.ndarray,
     M = idx.shape[0]
     assert s_ == S, (s_, S)
     assert scales.shape == (N,), (scales.shape, N)
-    d = S * ds
-    Dp = max(-(-d // 128) * 128, 128)
-    Mp = max(-(-M // MB) * MB, MB)
-    idx_p = jnp.pad(idx, (0, Mp - M)) if Mp != M else idx
+    Dp = max(-(-(S * ds) // 128) * 128, 128)
+    idx_p = _pad_idx(idx)
+    rscl = jnp.take(scales, idx_p, mode="clip")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(Mp // MB,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec((S, c, ds),
-                               lambda g, idx, scl: (0, 0, 0))],
-        out_specs=pl.BlockSpec((MB, Dp), lambda g, idx, scl: (g, 0)),
-        scratch_shapes=[pltpu.VMEM((2, MB, S), table.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
+        grid=(idx_p.shape[0] // GROUP,),
+        in_specs=tile_specs(S, False) + [
+            pl.BlockSpec((S, c, ds), lambda g, *_: (0, 0, 0))],
+        out_specs=pl.BlockSpec((GROUP, Dp), lambda g, *_: (g, 0)),
     )
     out = pl.pallas_call(
-        _make_vq_kernel(MB, S, c, ds, Dp),
+        functools.partial(_vq_gather_kernel, dp=Dp),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Mp, Dp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((idx_p.shape[0], Dp), jnp.float32),
         interpret=interpret,
-    )(idx_p, scales, table, codebook)
-    return out[:M] if Mp != M else out
+    )(idx_p, rscl, *([table] * GROUP), codebook)
+    return out[:M]

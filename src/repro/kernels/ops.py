@@ -36,8 +36,8 @@ import numpy as np
 
 from .bcsr_spmm import bcsr_spmm
 from .decode_attn import flash_decode
-from .gather import gather_rows, gather_rows_dq, gather_rows_vq
-from .scatter import scatter_rows, scatter_rows_q, scatter_rows_vq
+from .gather import gather_rows, gather_rows_vq
+from .scatter import scatter_rows, scatter_rows_vq
 from . import edge_softmax as esk
 from . import fused
 from . import pna_reduce as pnk
@@ -246,10 +246,10 @@ def gcn_aggregate(x_all: jnp.ndarray, edges, edge_w: jnp.ndarray,
 def _gather_spmm_kernel(x_in, table, scales, codebook, blk_vals, blk_cols,
                         blk_vals_t, blk_cols_t, halo_nodes, halo_mask, bn,
                         bd, interpret):
-    sel, xrow, trow = fused.gather_plan(blk_cols, halo_nodes, halo_mask,
-                                        x_in.shape[0], table.shape[0], bn)
-    return fused.gather_spmm(x_in, table, blk_vals, blk_cols, sel, xrow,
-                             trow, scales, codebook, bn=bn, bd=bd,
+    sel, trow = fused.gather_plan(blk_cols, halo_nodes, halo_mask,
+                                  x_in.shape[0], table.shape[0], bn)
+    return fused.gather_spmm(x_in, table, blk_vals, blk_cols, sel, trow,
+                             scales, codebook, bn=bn, bd=bd,
                              interpret=interpret)
 
 
@@ -343,6 +343,16 @@ def gas_aggregate(x_in: jnp.ndarray, table: jnp.ndarray,
             "blk_cols, blk_vals_t, blk_cols_t) — build batches with "
             "build_blocks=True (transposed structure included) or use "
             "the unfused path")
+    if codebook is not None and backend == "pallas":
+        # Mosaic lays the uint8 [N, S] code table out 128 lanes wide and
+        # refuses the S-lane row-group DMA the vq form needs; the
+        # interpret backend still runs it
+        raise NotImplementedError(
+            "fused gas_aggregate with vq histories does not compile for "
+            "TPU (Mosaic: 'Slice shape along dimension 1 must be aligned "
+            "to tiling (128), but is S'); on the pallas backend use "
+            "fuse_halo=False (vq pulls compile), history_dtype "
+            "f32/bf16/int8, or backend='jnp'")
     blk_vals, blk_cols, blk_vals_t, blk_cols_t = blocks
     bn = blk_vals.shape[-1]
     d_pad = _pad_dim(D, bd)
@@ -568,9 +578,10 @@ def pull_rows(table: jnp.ndarray, idx: jnp.ndarray, *,
     With `scales` [N] f32 the table holds symmetric per-row int8 rows and
     the pull dequantizes: out[i] = table[idx[i]] * scales[idx[i]] in f32.
     On the kernel backends the multiply is fused into the row gather
-    (`gather_rows_dq` — the scale vector rides the scalar-prefetch lane),
-    so only int8 table bytes cross HBM. With `codebook` [S, C, ds] as
-    well, the table holds uint8 vq code rows and the pull decodes them
+    (`gather_rows` with its scales — the per-row scales ride the
+    scalar-prefetch lane), so only int8 table bytes cross HBM. With
+    `codebook` [S, C, ds] as well, the table holds uint8 vq code rows
+    and the pull decodes them
     (`gather_rows_vq` on the kernel backends — only S code bytes per row
     cross HBM).
 
@@ -609,10 +620,7 @@ def pull_rows(table: jnp.ndarray, idx: jnp.ndarray, *,
     d_pad = _pad_dim(D, bd)
     tp = jnp.pad(table, ((0, 0), (0, d_pad - D))) if d_pad != D else table
     interpret = backend == "interpret"
-    if scales is not None:
-        out = gather_rows_dq(tp, scales, idx, bd=bd, interpret=interpret)
-    else:
-        out = gather_rows(tp, idx, bd=bd, interpret=interpret)
+    out = gather_rows(tp, idx, scales, bd=bd, interpret=interpret)
     return out if pad_out else out[:, :D]
 
 
@@ -666,8 +674,9 @@ def push_rows_q(table: jnp.ndarray, scales: jnp.ndarray, idx: jnp.ndarray,
     s = max|v| / 127, q = round(v / s)) and scattered as int8, and its
     scale lands in the scale table at the same row. On the kernel
     backends the divide-round-clip runs inside the scatter kernel
-    (`scatter_rows_q`), so the quantized copy of the payload is never
-    materialized in HBM; only the [M] row-max reduction happens outside.
+    (`scatter_rows` with its scales), so the quantized copy of the
+    payload is never materialized in HBM; only the [M] row-max reduction
+    happens outside.
     Returns (new_table, new_scales); masking / `scratch_last_row` match
     `push_rows` (the sentinel row's scale becomes garbage — sentinel
     reads are masked everywhere).
@@ -685,15 +694,15 @@ def push_rows_q(table: jnp.ndarray, scales: jnp.ndarray, idx: jnp.ndarray,
                                         unique_indices=False)
         return new_t, new_s
     interpret = backend == "interpret"
-    # kernel path: the divide-round-clip runs inside scatter_rows_q; the
+    # kernel path: the divide-round-clip runs inside scatter_rows; the
     # per-row scale comes from the SAME row_scales the jnp path uses, so
     # backends agree bit-for-bit
     row_scale = row_scales(v)
     if scratch_last_row and D % bd == 0:
         safe_idx = jnp.where(mask, jnp.clip(idx, 0, N - 2),
                              N - 1).astype(jnp.int32)
-        new_t = scatter_rows_q(table, safe_idx, v, row_scale, bd=bd,
-                               interpret=interpret)
+        new_t = scatter_rows(table, safe_idx, v, row_scale, bd=bd,
+                             interpret=interpret)
         new_s = scales.at[safe_idx].set(row_scale, unique_indices=False)
         return new_t, new_s
     # general path: appended sacrificial row (pad + slice copies)
@@ -701,8 +710,8 @@ def push_rows_q(table: jnp.ndarray, scales: jnp.ndarray, idx: jnp.ndarray,
     d_pad = _pad_dim(D, bd)
     tp = jnp.pad(table, ((0, 1), (0, d_pad - D)))
     vp = jnp.pad(v, ((0, 0), (0, d_pad - D)))
-    new_t = scatter_rows_q(tp, safe_idx, vp, row_scale, bd=bd,
-                           interpret=interpret)
+    new_t = scatter_rows(tp, safe_idx, vp, row_scale, bd=bd,
+                         interpret=interpret)
     new_s = scales.at[safe_idx].set(row_scale, mode="drop",
                                     unique_indices=False)
     return new_t[:N, :D], new_s
@@ -763,8 +772,8 @@ def push_rows_vq(table: jnp.ndarray, scales: jnp.ndarray, idx: jnp.ndarray,
 
 
 __all__ = ["BACKENDS", "set_default_backend", "resolve_backend",
-           "bcsr_spmm", "gather_rows", "gather_rows_dq", "gather_rows_vq",
-           "scatter_rows", "scatter_rows_q", "scatter_rows_vq",
+           "bcsr_spmm", "gather_rows", "gather_rows_vq",
+           "scatter_rows", "scatter_rows_vq",
            "flash_decode",
            "build_bcsr", "build_bcsr_rect", "bcsr_density",
            "spmm", "gcn_aggregate", "gas_aggregate",

@@ -26,7 +26,10 @@ detection against the saved min/max is exact) and apply
                                  + tie_max * g_max/c_max).
 
 All internal compute is float32; callers pad to tile boundaries (see
-`ops.pna_reduce`).
+`ops.pna_reduce`). A TPU block's last two dims must be (8, 128)-aligned
+or whole, so the multiplicity row of destination a is read out of the
+whole bn x bn block (fetched once per k) and turned into a column by one
+bn x bn transpose (`tiles.to_col`).
 """
 from __future__ import annotations
 
@@ -36,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .tiles import to_col, to_row
 
 BIG = 1e30      # f32-internal min/max sentinel (kernels compute in f32)
 
@@ -55,21 +60,21 @@ def _fwd_kernel(cols_ref, xd_ref, xs_ref, mrow_ref, s_ref, mn_ref, mx_ref,
         cmin_acc[...] = jnp.zeros_like(cmin_acc)
         cmax_acc[...] = jnp.zeros_like(cmax_acc)
 
-    m = mrow_ref[0, 0, 0, :]                        # [bn] multiplicities
+    m = to_col(mrow_ref[0, 0, pl.ds(a, 1), :])[:, 0:1]    # [bn, 1] mults
     xd_a = xd_ref[pl.ds(a, 1), :].astype(jnp.float32)   # [1, bd]
     xs = xs_ref[...].astype(jnp.float32)            # [bn, bd] source tile
     msg = jnp.maximum(xd_a + xs, 0.0)               # [bn, bd]
-    valid = (m > 0)[:, None]
+    valid = m > 0
 
     row = pl.ds(a, 1)
-    s_acc[row, :] += (m[:, None] * msg).sum(axis=0, keepdims=True)
-    cnt_scr[row, :] += m.sum()[None, None]
+    s_acc[row, :] += (m * msg).sum(axis=0, keepdims=True)
+    cnt_scr[row, :] += m.sum(axis=0, keepdims=True)
 
     # online min/max with multiplicity-weighted tie counts: a strictly
     # better block value resets the count, an equal one adds to it
     mn_blk = jnp.where(valid, msg, BIG).min(axis=0, keepdims=True)
     new_mn = jnp.minimum(mn_acc[row, :], mn_blk)
-    here_mn = (m[:, None] * jnp.where(valid & (msg == new_mn), 1.0, 0.0)
+    here_mn = (m * jnp.where(valid & (msg == new_mn), 1.0, 0.0)
                ).sum(axis=0, keepdims=True)
     cmin_acc[row, :] = jnp.where(mn_acc[row, :] == new_mn,
                                  cmin_acc[row, :], 0.0) + here_mn
@@ -77,7 +82,7 @@ def _fwd_kernel(cols_ref, xd_ref, xs_ref, mrow_ref, s_ref, mn_ref, mx_ref,
 
     mx_blk = jnp.where(valid, msg, -BIG).max(axis=0, keepdims=True)
     new_mx = jnp.maximum(mx_acc[row, :], mx_blk)
-    here_mx = (m[:, None] * jnp.where(valid & (msg == new_mx), 1.0, 0.0)
+    here_mx = (m * jnp.where(valid & (msg == new_mx), 1.0, 0.0)
                ).sum(axis=0, keepdims=True)
     cmax_acc[row, :] = jnp.where(mx_acc[row, :] == new_mx,
                                  cmax_acc[row, :], 0.0) + here_mx
@@ -89,7 +94,7 @@ def _fwd_kernel(cols_ref, xd_ref, xs_ref, mrow_ref, s_ref, mn_ref, mx_ref,
         s_ref[...] = s_acc[...]
         mn_ref[...] = jnp.where(has, mn_acc[...], 0.0)
         mx_ref[...] = jnp.where(has, mx_acc[...], 0.0)
-        cnt_ref[0, :] = cnt_scr[:, 0]
+        cnt_ref[0] = to_row(cnt_scr[...])
         cmin_ref[...] = cmin_acc[...]
         cmax_ref[...] = cmax_acc[...]
 
@@ -97,7 +102,7 @@ def _fwd_kernel(cols_ref, xd_ref, xs_ref, mrow_ref, s_ref, mn_ref, mx_ref,
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "interpret"))
 def pna_reduce_fwd(xd: jnp.ndarray, xs: jnp.ndarray,
                    ublk_vals: jnp.ndarray, blk_cols: jnp.ndarray, *,
-                   bn: int = 128, bd: int = 128, interpret: bool = True):
+                   bn: int = 128, bd: int = 128, interpret: bool):
     """Blockwise sum/min/max/count of msg = relu(xd[dst] + xs[src]).
 
     xd [R*bn, Fp] destination-side transform; xs [C*bn, Fp] source-side;
@@ -121,14 +126,14 @@ def pna_reduce_fwd(xd: jnp.ndarray, xs: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bn, bd), tile),
             pl.BlockSpec((bn, bd), lambda r, f, k, a, cols: (cols[r, k], f)),
-            pl.BlockSpec((1, 1, 1, bn),
-                         lambda r, f, k, a, cols: (r, k, a, 0)),
+            pl.BlockSpec((1, 1, bn, bn),
+                         lambda r, f, k, a, cols: (r, k, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bn, bd), tile),
             pl.BlockSpec((bn, bd), tile),
             pl.BlockSpec((bn, bd), tile),
-            pl.BlockSpec((1, bn), lambda r, f, k, a, cols: (r, 0)),
+            pl.BlockSpec((1, 1, bn), lambda r, f, k, a, cols: (r, 0, 0)),
             pl.BlockSpec((bn, bd), tile),
             pl.BlockSpec((bn, bd), tile),
         ],
@@ -145,7 +150,7 @@ def pna_reduce_fwd(xd: jnp.ndarray, xs: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct((Rp, Fp), jnp.float32),
                    jax.ShapeDtypeStruct((Rp, Fp), jnp.float32),
                    jax.ShapeDtypeStruct((Rp, Fp), jnp.float32),
-                   jax.ShapeDtypeStruct((R, bn), jnp.float32),
+                   jax.ShapeDtypeStruct((R, 1, bn), jnp.float32),
                    jax.ShapeDtypeStruct((Rp, Fp), jnp.float32),
                    jax.ShapeDtypeStruct((Rp, Fp), jnp.float32)],
         interpret=interpret,
@@ -155,14 +160,14 @@ def pna_reduce_fwd(xd: jnp.ndarray, xs: jnp.ndarray,
 
 def _dmsg(msg, z, m, gs, gmn, gmx, mn, mx, cmin, cmax):
     """Even-split cotangent of (sum, min, max) w.r.t. one message tile.
-    All stat operands broadcast against msg [*, bd]; m is the
+    All stat operands broadcast against msg [*, bd]; m [*, 1] is the
     multiplicity aligned with msg's leading axis."""
-    valid = (m > 0)[:, None]
+    valid = m > 0
     tie_mn = jnp.where(valid & (msg == mn), 1.0, 0.0)
     tie_mx = jnp.where(valid & (msg == mx), 1.0, 0.0)
     grad = gs + tie_mn * gmn / jnp.maximum(cmin, 1.0) \
         + tie_mx * gmx / jnp.maximum(cmax, 1.0)
-    return jnp.where(z > 0, 1.0, 0.0) * m[:, None] * grad
+    return jnp.where(z > 0, 1.0, 0.0) * m * grad
 
 
 def _bwd_row_kernel(cols_ref, xd_ref, xs_ref, mrow_ref, gs_ref, gmn_ref,
@@ -175,7 +180,7 @@ def _bwd_row_kernel(cols_ref, xd_ref, xs_ref, mrow_ref, gs_ref, gmn_ref,
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    m = mrow_ref[0, 0, 0, :]                        # [bn] over sources
+    m = to_col(mrow_ref[0, 0, pl.ds(a, 1), :])[:, 0:1]    # over sources
     row = pl.ds(a, 1)
     z = xd_ref[row, :].astype(jnp.float32) + \
         xs_ref[...].astype(jnp.float32)             # [bn_src, bd]
@@ -193,7 +198,7 @@ def _bwd_row_kernel(cols_ref, xd_ref, xs_ref, mrow_ref, gs_ref, gmn_ref,
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "interpret"))
 def pna_reduce_bwd_row(xd, xs, gs, gmn, gmx, mn, mx, cmin, cmax,
                        ublk_vals, blk_cols, *, bn: int = 128,
-                       bd: int = 128, interpret: bool = True):
+                       bd: int = 128, interpret: bool):
     """Destination-side cotangent dxd [R*bn, Fp] = sum_src dmsg over the
     forward block structure. gs/gmn/gmx are the (s, mn, mx) cotangents;
     mn/mx/cmin/cmax are the forward kernel's saved stats."""
@@ -210,8 +215,8 @@ def pna_reduce_bwd_row(xd, xs, gs, gmn, gmx, mn, mx, cmin, cmax,
         in_specs=[
             pl.BlockSpec((bn, bd), tile),
             pl.BlockSpec((bn, bd), lambda r, f, k, a, cols: (cols[r, k], f)),
-            pl.BlockSpec((1, 1, 1, bn),
-                         lambda r, f, k, a, cols: (r, k, a, 0)),
+            pl.BlockSpec((1, 1, bn, bn),
+                         lambda r, f, k, a, cols: (r, k, 0, 0)),
         ] + [pl.BlockSpec((bn, bd), tile)] * 7,
         out_specs=pl.BlockSpec((bn, bd), tile),
         scratch_shapes=[pltpu.VMEM((bn, bd), jnp.float32)],
@@ -236,7 +241,7 @@ def _bwd_col_kernel(colst_ref, xs_ref, xd_ref, mrow_ref, gs_ref, gmn_ref,
 
     # transposed block: rows = sources, columns = destinations; all stat
     # tiles are destination-space (fetched via the transposed column ids)
-    m = mrow_ref[0, 0, 0, :]                        # [bn] over destinations
+    m = to_col(mrow_ref[0, 0, pl.ds(s_row, 1), :])[:, 0:1]  # over dsts
     row = pl.ds(s_row, 1)
     z = xs_ref[row, :].astype(jnp.float32) + \
         xd_ref[...].astype(jnp.float32)             # [bn_dst, bd]
@@ -253,7 +258,7 @@ def _bwd_col_kernel(colst_ref, xs_ref, xd_ref, mrow_ref, gs_ref, gmn_ref,
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "interpret"))
 def pna_reduce_bwd_col(xd, xs, gs, gmn, gmx, mn, mx, cmin, cmax,
                        ublk_vals_t, blk_cols_t, *, bn: int = 128,
-                       bd: int = 128, interpret: bool = True):
+                       bd: int = 128, interpret: bool):
     """Source-side cotangent dxs [C*bn, Fp] = sum_dst dmsg over the
     *transposed* block structure (destination-space stat tiles are fetched
     through the transposed column ids)."""
@@ -271,8 +276,8 @@ def pna_reduce_bwd_col(xd, xs, gs, gmn, gmx, mn, mx, cmin, cmax,
         in_specs=[
             pl.BlockSpec((bn, bd), tile),
             pl.BlockSpec((bn, bd), col_tile),
-            pl.BlockSpec((1, 1, 1, bn),
-                         lambda r, f, k, a, cols: (r, k, a, 0)),
+            pl.BlockSpec((1, 1, bn, bn),
+                         lambda r, f, k, a, cols: (r, k, 0, 0)),
         ] + [pl.BlockSpec((bn, bd), col_tile)] * 7,
         out_specs=pl.BlockSpec((bn, bd), tile),
         scratch_shapes=[pltpu.VMEM((bn, bd), jnp.float32)],

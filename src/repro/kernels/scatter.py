@@ -1,25 +1,29 @@
 """History push (row scatter) Pallas kernels — the dual of `gather.py`.
 
-The scalar-prefetched index vector drives the *output* BlockSpec index_map:
-grid step i copies value row i into table row idx[i], and
-`input_output_aliases` donates the table into the output so every row NOT
-named by `idx` keeps its historical value. Pallas's automatic pipelining
-overlaps the VMEM->HBM copy-out of row i with the value-row DMA of i+1 —
-the TPU analogue of PyGAS's CUDA-stream history write-back.
+A DMA cannot write one row into a tiled HBM table either (see
+`tiles.py`), so the push is a read-modify-write of the row's aligned
+8-row tile. The wrapper sorts the pushed rows by table index (stably), so
+rows that share a tile are consecutive grid steps: grid step i's output
+BlockSpec maps to the tile of sorted row i, the first step on a tile
+copies the table's tile in, every step overwrites its own row, and Pallas
+writes the tile back once the next step moves to another tile.
+`input_output_aliases` donates the table into the output, so every tile
+no row touches keeps its historical values.
 
 Semantics (matching `core/history.push`):
   * masked rows must be pre-redirected to a trash row by the caller
     (`kernels/ops.push_rows` appends one and slices it off afterwards);
   * duplicate indices resolve to the LAST occurrence in row order (the
-    sequential grid makes this deterministic, unlike raw XLA scatter).
-    GAS batches never contain duplicates — each node is in one cluster.
+    stable sort keeps their order, and the sequential grid makes this
+    deterministic, unlike raw XLA scatter). GAS batches never contain
+    duplicates — each node is in one cluster.
 
-`scatter_rows_q` is the quantizing dual of `gather.gather_rows_dq`: the
-f32 value rows stream through VMEM, the symmetric divide-round-clip to
-int8 happens on the VPU against the scalar-prefetched per-row scales
-(precomputed by one cheap jnp row-max, `core.history.quantize_rows`
-semantics), and only the int8 row is copied out into the aliased table —
-the quantized copy of the push payload is never materialized in HBM.
+With `scales` (`scatter_rows` quantizing mode) the f32 value rows stream
+through VMEM, the symmetric divide-round-clip to int8 happens on the VPU
+against the scalar-prefetched per-row scales (precomputed by one cheap
+jnp row-max, `core.history.quantize_rows` semantics), and only int8
+tiles are written back. `scatter_rows_vq` is the codebook dual of
+`gather.gather_rows_vq`.
 """
 from __future__ import annotations
 
@@ -30,127 +34,132 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tiles import GROUP, pick_row
 
-def _kernel(idx_ref, vals_ref, table_ref, out_ref):
-    out_ref[...] = vals_ref[...]
+
+def _sorted_rows(idx, values, scales):
+    """Stable sort of the pushed rows by table index; values are padded
+    to a whole number of GROUP-row blocks."""
+    M = idx.shape[0]
+    order = jnp.argsort(idx, stable=True)
+    Mp = -(-M // GROUP) * GROUP
+    vals = jnp.pad(jnp.take(values, order, axis=0),
+                   ((0, Mp - M), (0, 0)))
+    prefetch = (jnp.take(idx, order),)
+    if scales is not None:
+        prefetch += (jnp.take(scales, order),)
+    return prefetch, vals
+
+
+def _rmw(idx_ref, i, tbl_ref, out_ref, row):
+    """Copy the tile in on its first step, then overwrite row idx[i]."""
+    t = idx_ref[i]
+    prev = idx_ref[jnp.maximum(i - 1, 0)]
+
+    @pl.when((i == 0) | (prev // GROUP != t // GROUP))
+    def _load():
+        out_ref[...] = tbl_ref[...]
+
+    cur = out_ref[...]
+    wide = cur.astype(row.dtype)
+    rows = jax.lax.broadcasted_iota(jnp.int32, wide.shape, 0)
+    out_ref[...] = jnp.where(rows == t % GROUP, row, wide).astype(cur.dtype)
+
+
+def _scatter_kernel(*refs, quantize: bool):
+    idx_ref = refs[0]
+    scl_ref = refs[1] if quantize else None
+    vals_ref, tbl_ref, out_ref = refs[1 + quantize:]
+    i = pl.program_id(1)
+    row = pick_row(vals_ref[...], i % GROUP)
+    if quantize:
+        # the in-kernel mirror of core.history.quantize_rows' round/clip —
+        # keep in lockstep (scales themselves come from
+        # history.row_scales via ops.push_rows_q, shared with the jnp path)
+        row = jnp.clip(jnp.round(row / scl_ref[i]), -127.0, 127.0)
+    _rmw(idx_ref, i, tbl_ref, out_ref, row)
 
 
 @functools.partial(jax.jit, static_argnames=("bd", "interpret"))
 def scatter_rows(table: jnp.ndarray, idx: jnp.ndarray,
-                 values: jnp.ndarray, *, bd: int = 128,
-                 interpret: bool = True) -> jnp.ndarray:
-    """out = table; out[idx[i]] = values[i]. idx must be pre-clipped to
-    [0, N); rows to drop must point at a sacrificial row. table's feature
-    dim must be a multiple of bd."""
+                 values: jnp.ndarray, scales: jnp.ndarray = None, *,
+                 bd: int = 128, interpret: bool) -> jnp.ndarray:
+    """out = table; out[idx[i]] = values[i]. With `scales` the table is
+    int8 and out[idx[i]] = int8(round(values[i] / scales[i])) — the
+    quantizing scatter; `scales` is the per-PUSHED-row scale vector [M]
+    (row i of `values`, NOT table row order; the caller scatters the
+    scales into its [N] scale table separately). idx must be pre-clipped
+    to [0, N); rows to drop must point at a sacrificial row. table's
+    feature dim must be a multiple of bd."""
     N, D = table.shape
     M = idx.shape[0]
     assert values.shape == (M, D), (values.shape, (M, D))
     assert D % bd == 0, (D, bd)
-    grid = (M, D // bd)
+    if scales is not None:
+        assert table.dtype == jnp.int8, table.dtype
+        assert scales.shape == (M,), (scales.shape, M)
+        values = values.astype(jnp.float32)
+    else:
+        values = values.astype(table.dtype)
+    prefetch, vals = _sorted_rows(idx, values, scales)
+    tile = lambda d, i, idx, *_: (idx[i] // GROUP, d)          # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
+        num_scalar_prefetch=len(prefetch),
+        grid=(D // bd, M),
         in_specs=[
-            pl.BlockSpec((1, bd), lambda i, d, idx: (i, d)),       # values
-            # aliased table stays in HBM (ANY): the kernel never reads it,
-            # so a block-mapped spec would DMA one table row per grid step
-            # for nothing — this keeps the push write-only
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((GROUP, bd), lambda d, i, *_: (i // GROUP, d)),
+            pl.BlockSpec((GROUP, bd), tile),
         ],
-        out_specs=pl.BlockSpec((1, bd), lambda i, d, idx: (idx[i], d)),
+        out_specs=pl.BlockSpec((GROUP, bd), tile),
     )
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_scatter_kernel, quantize=scales is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, D), table.dtype),
-        # alias table -> out (index 2 counts the scalar-prefetch operand):
-        # unwritten rows keep their historical values; when the caller's
-        # table buffer is donated (the train step donates histories) XLA
-        # performs the push in place.
-        input_output_aliases={2: 0},
+        # alias table -> out (after the scalar-prefetch operands and the
+        # value rows): untouched tiles keep their historical values; when
+        # the caller's table buffer is donated (the train step donates
+        # histories) XLA performs the push in place
+        input_output_aliases={len(prefetch) + 1: 0},
         interpret=interpret,
-    )(idx, values.astype(table.dtype), table)
+    )(*prefetch, vals, table)
 
 
-def _q_kernel(idx_ref, scl_ref, vals_ref, table_ref, out_ref):
-    # the in-kernel mirror of core.history.quantize_rows' round/clip —
-    # keep in lockstep (scales themselves come from history.row_scales
-    # via ops.push_rows_q, shared with the jnp path)
-    i = pl.program_id(0)
-    v = vals_ref[...].astype(jnp.float32) / scl_ref[i]
-    out_ref[...] = jnp.clip(jnp.round(v), -127.0, 127.0).astype(jnp.int8)
-
-
-@functools.partial(jax.jit, static_argnames=("bd", "interpret"))
-def scatter_rows_q(table: jnp.ndarray, idx: jnp.ndarray,
-                   values: jnp.ndarray, scales: jnp.ndarray, *,
-                   bd: int = 128, interpret: bool = True) -> jnp.ndarray:
-    """out = table; out[idx[i]] = int8(round(values[i] / scales[i])) —
-    the quantizing scatter. `scales` is the per-PUSHED-row scale vector
-    [M] (row i of `values`, NOT table row order; the caller scatters the
-    scales into its [N] scale table separately). Same index contract as
-    `scatter_rows`: idx pre-clipped, dropped rows pointed at a
-    sacrificial row, duplicates resolve to the last occurrence."""
-    N, D = table.shape
-    M = idx.shape[0]
-    assert table.dtype == jnp.int8, table.dtype
-    assert values.shape == (M, D), (values.shape, (M, D))
-    assert scales.shape == (M,), (scales.shape, M)
-    assert D % bd == 0, (D, bd)
-    grid = (M, D // bd)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bd), lambda i, d, idx, scl: (i, d)),  # values
-            # aliased table stays in HBM (ANY): write-only push
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, bd), lambda i, d, idx, scl: (idx[i], d)),
-    )
-    return pl.pallas_call(
-        _q_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, D), jnp.int8),
-        # alias table -> out (index 3: after the two scalar-prefetch
-        # operands and the value rows)
-        input_output_aliases={3: 0},
-        interpret=interpret,
-    )(idx, scales, values.astype(jnp.float32), table)
-
-
-def _make_vq_kernel(s, c, ds):
-    d = s * ds
-
-    def _vq_kernel(idx_ref, scl_ref, cb_ref, vals_ref, table_ref,
-                   out_ref):
-        # the in-kernel mirror of core.history.vq_encode_rows' nearest-
-        # entry search — keep in lockstep (scales themselves come from
-        # history.vq_row_scales via ops.push_rows_vq, shared with the
-        # jnp path)
-        i = pl.program_id(0)
-        u = (vals_ref[0, :d].astype(jnp.float32) /
-             scl_ref[i]).reshape(s, 1, ds)
-        d2 = jnp.sum(jnp.square(u - cb_ref[...]), axis=-1)    # [S, C]
-        out_ref[...] = jnp.argmin(d2, axis=-1).astype(jnp.uint8)[None, :]
-
-    return _vq_kernel
+def _vq_kernel(idx_ref, scl_ref, cb_ref, vals_ref, tbl_ref, out_ref):
+    # the in-kernel mirror of core.history.vq_encode_rows' nearest-entry
+    # search — keep in lockstep (scales themselves come from
+    # history.vq_row_scales via ops.push_rows_vq, shared with the jnp
+    # path). One subvector at a time: Mosaic cannot reshape the [1, d] row
+    # into [S, 1, ds]. argmin is spelled min-then-first-index (same ties).
+    i = pl.program_id(1)
+    s, c, ds = cb_ref.shape
+    u = pick_row(vals_ref[...], i % GROUP) / scl_ref[i]
+    entries = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
+    codes = jnp.zeros((1, s), jnp.int32)
+    for sub in range(s):
+        d2 = jnp.sum(jnp.square(u[:, sub * ds:(sub + 1) * ds] - cb_ref[sub]),
+                     axis=-1, keepdims=True)                  # [C, 1]
+        best = jnp.min(d2, axis=0, keepdims=True)
+        code = jnp.min(jnp.where(d2 == best, entries, c), axis=0,
+                       keepdims=True)
+        codes = jnp.where(lanes == sub, code, codes)
+    _rmw(idx_ref, i, tbl_ref, out_ref, codes)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def scatter_rows_vq(table: jnp.ndarray, idx: jnp.ndarray,
                     values: jnp.ndarray, scales: jnp.ndarray,
                     codebook: jnp.ndarray, *,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool) -> jnp.ndarray:
     """out = table; out[idx[i]] = vq_encode(values[i] / scales[i]) — the
-    codebook-quantizing scatter (`history_dtype="vq"`), the vq dual of
-    `scatter_rows_q`. The nearest-codebook-entry search runs on the VPU
-    between the value-row DMA and the uint8 code copy-out, so only S
-    code bytes per row are ever written back to HBM. `values` may be
-    column-padded past d = S*ds (the kernel slices); `scales` is the
-    per-PUSHED-row normalizer [M] from `history.vq_row_scales`; the
-    codebook rides as a whole-VMEM operand (too big for SMEM scalar
-    prefetch). Same index contract as `scatter_rows`."""
+    codebook-quantizing scatter (`history_dtype="vq"`). The
+    nearest-codebook-entry search runs on the VPU between the value-row
+    DMA and the uint8 code write-back, so only code tiles are written to
+    HBM. `values` may be column-padded past d = S*ds (the kernel slices);
+    `scales` is the per-PUSHED-row normalizer [M] from
+    `history.vq_row_scales`; the codebook rides as a whole-VMEM operand.
+    Same index contract as `scatter_rows`."""
     N, S = table.shape
     s_, c, ds = codebook.shape
     M = idx.shape[0]
@@ -159,24 +168,25 @@ def scatter_rows_vq(table: jnp.ndarray, idx: jnp.ndarray,
     assert values.shape[0] == M and values.shape[1] >= S * ds, \
         (values.shape, M, S * ds)
     assert scales.shape == (M,), (scales.shape, M)
+    prefetch, vals = _sorted_rows(idx, values.astype(jnp.float32), scales)
+    tile = lambda d, i, idx, *_: (idx[i] // GROUP, 0)          # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(M,),
+        grid=(1, M),
         in_specs=[
-            pl.BlockSpec((S, c, ds), lambda i, idx, scl: (0, 0, 0)),
-            pl.BlockSpec((1, values.shape[1]),
-                         lambda i, idx, scl: (i, 0)),          # values
-            # aliased table stays in HBM (ANY): write-only push
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((S, c, ds), lambda d, i, *_: (0, 0, 0)),
+            pl.BlockSpec((GROUP, vals.shape[1]),
+                         lambda d, i, *_: (i // GROUP, 0)),
+            pl.BlockSpec((GROUP, S), tile),
         ],
-        out_specs=pl.BlockSpec((1, S), lambda i, idx, scl: (idx[i], 0)),
+        out_specs=pl.BlockSpec((GROUP, S), tile),
     )
     return pl.pallas_call(
-        _make_vq_kernel(S, c, ds),
+        _vq_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, S), jnp.uint8),
-        # alias table -> out (index 4: after the two scalar-prefetch
-        # operands, the codebook, and the value rows)
+        # alias table -> out (after the two scalar-prefetch operands, the
+        # codebook, and the value rows)
         input_output_aliases={4: 0},
         interpret=interpret,
-    )(idx, scales, codebook, values.astype(jnp.float32), table)
+    )(*prefetch, codebook, vals, table)

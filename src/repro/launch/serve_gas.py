@@ -25,6 +25,11 @@ Roles (`--role`, the process split of core/serve_service.py):
     # exactness mode: --slo 0 re-pushes every stale dependency first
     # pure-cache mode: --slo none never refreshes
 
+The backend and each frontend are separate JAX processes, and a TPU chip
+belongs to one process at a time. On a chip host, run the store-service
+backend with `JAX_PLATFORMS=cpu` (it only stores and moves rows) so the
+frontend can hold the chip, or serve in one process with `--role both`.
+
 A checkpoint round-trip carries its model metadata inline:
 
     ... serve_gas --save-checkpoint /tmp/gas.npz ...
@@ -34,9 +39,9 @@ A checkpoint round-trip carries its model metadata inline:
 the two-process backend+frontend pairing) serves two request batches on
 a tiny graph and asserts the SLO contract: `halo_age_max <= slo` after
 refresh, repeat requests are served bit-identically from the warm cache,
-and — for lossless stores — SLO=0 logits equal the jitted full-graph
-recompute bit-for-bit. Frontend smokes assert the same contract through
-the wire.
+and — for lossless stores — SLO=0 logits match the jitted full-graph
+recompute within `EXACT_ATOL` with the same argmax. Frontend smokes
+assert the same contract through the wire.
 """
 from __future__ import annotations
 
@@ -52,6 +57,7 @@ from repro.core import serve as S
 from repro.core import serve_service as SS
 from repro.data.graphs import citation_graph
 from repro.gnn.model import GNNSpec, full_forward
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.checkpoint import (load_gas_meta, load_gas_state,
                                     save_gas_state)
 
@@ -241,7 +247,25 @@ def _smoke_asserts(args, g, spec, params, history_dtype, results, replay):
             (jnp.asarray(dst), jnp.asarray(src)), jnp.asarray(w),
             g.num_nodes))
         for q, lg, _ in results:
-            np.testing.assert_array_equal(lg, exact[q])
+            assert_matches_full_forward(lg, exact[q])
+
+
+# SLO=0 serving and `full_forward` sum the same f32 terms in different
+# orders (cached per-layer rows, block-dense kernels), so they agree to a
+# few ulps, not bitwise.
+EXACT_ATOL = 1e-5
+
+
+def assert_matches_full_forward(logits, exact, atol: float = EXACT_ATOL):
+    """Served logits vs the full-graph forward: within `atol` (absolute
+    and relative) and the same argmax wherever the exact top-2 margin is
+    wider than the tolerance allows to flip."""
+    logits, exact = np.asarray(logits), np.asarray(exact)
+    np.testing.assert_allclose(logits, exact, rtol=atol, atol=atol)
+    top2 = np.sort(exact, axis=-1)[:, -2:]
+    decided = top2[:, 1] - top2[:, 0] > 4 * atol * (1 + np.abs(top2[:, 1]))
+    agree = np.argmax(logits, -1) == np.argmax(exact, -1)
+    assert agree[decided].all(), "served argmax differs from full_forward"
 
 
 def main(argv=None):
@@ -282,6 +306,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="tiny run asserting the SLO contract (CI)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         args.nodes = min(args.nodes, 200)

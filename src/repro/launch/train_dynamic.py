@@ -30,6 +30,7 @@ from repro.core import delta as D
 from repro.core import dynamic as DY
 from repro.core import runtime as R
 from repro.data.graphs import citation_graph
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -65,6 +66,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="tiny run asserting the dynamic contract (CI)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         args.nodes = min(args.nodes, 180)

@@ -14,7 +14,7 @@ IDENTICAL to the synchronous schedule. These tests pin that contract:
    no-op move but drives the same placement/streaming code path);
  - the pipelined step really does dispatch batch i+depth's halo pull
    BEFORE batch i's push (jaxpr order assertion — the overlap claim);
- - the row-blocked `gather_rows_dq` (8, bd) tiles match the dequant
+ - the row-blocked dequantizing `gather_rows` (8, bd) tiles match the dequant
    oracle bitwise for ragged row counts.
 """
 import dataclasses
@@ -213,17 +213,17 @@ def test_prefetch_step_pull_dispatched_before_push():
 
 @pytest.mark.parametrize("m", [1, 5, 8, 13, 32])
 def test_gather_rows_dq_row_blocks_bitwise(m):
-    """(8, bd)-tiled `gather_rows_dq` pads M up to the tile height and
+    """(8, bd)-tiled dequantizing `gather_rows` pads M up to the tile height and
     slices back; every ragged M must match `table[idx] * scales[idx]`
     bitwise."""
-    from repro.kernels.gather import gather_rows_dq
+    from repro.kernels.gather import gather_rows
 
     rng = np.random.default_rng(m)
     n, d = 57, 128
     table = jnp.asarray(rng.integers(-127, 128, (n, d)).astype(np.int8))
     scales = jnp.asarray(rng.uniform(0.01, 2.0, n).astype(np.float32))
     idx = jnp.asarray(rng.integers(0, n, m).astype(np.int32))
-    got = gather_rows_dq(table, scales, idx, interpret=True)
+    got = gather_rows(table, idx, scales, interpret=True)
     want = (jnp.take(table, idx, axis=0).astype(jnp.float32)
             * jnp.take(scales, idx)[:, None])
     assert got.shape == (m, d)

@@ -23,10 +23,10 @@ def test_dist_gas_converges_to_exact():
         from repro.core.partition import metis_like_partition
         from repro.data.graphs import citation_graph
         from repro.gnn.model import GNNSpec, full_forward, init_gnn
-        from repro.launch.mesh import compat_make_mesh
 
         ranks = 4
-        mesh = compat_make_mesh((ranks,), ("data",))
+        mesh = jax.make_mesh((ranks,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         g = citation_graph(num_nodes=600, num_features=16, num_classes=4,
                            seed=9)
         part = metis_like_partition(g.indptr, g.indices, ranks, seed=0)
@@ -112,10 +112,10 @@ def test_dist_quantized_exchange_bitwise():
         from repro.core.partition import metis_like_partition
         from repro.data.graphs import citation_graph
         from repro.gnn.model import GNNSpec, init_gnn
-        from repro.launch.mesh import compat_make_mesh
 
         ranks = 2
-        mesh = compat_make_mesh((ranks,), ("data",))
+        mesh = jax.make_mesh((ranks,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         g = citation_graph(num_nodes=150, num_features=8, num_classes=3,
                            seed=11)
         part = metis_like_partition(g.indptr, g.indices, ranks, seed=0)
@@ -139,11 +139,11 @@ def test_dist_quantized_exchange_bitwise():
             deq = raw.astype(jnp.float32) * scl[:, None]
             return deq * hm[0][:, None]
 
-        sm = DG._compat_shard_map(
+        sm = jax.shard_map(
             body, mesh=mesh,
             in_specs=([P("data")] * 2, [P("data")] * 2, P("data"),
                       {k: P("data") for k in plan}),
-            out_specs=P("data"))
+            out_specs=P("data"), check_vma=False)
         with mesh:
             got = np.asarray(sm(list(store.tables), list(store.scales),
                                 hmask, plan))

@@ -685,3 +685,18 @@ def test_gas_forward_diags_and_fused_hook():
                                      fused_layer_apply=fused_layer_apply)
     np.testing.assert_allclose(np.asarray(out_b), np.asarray(out_a),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_fused_vq_aggregate_refused_on_pallas():
+    """Mosaic refuses the vq form of the fused gather-SpMM: the compiled
+    backend says so instead of falling back."""
+    from repro.kernels import ops
+    blocks = (jnp.zeros((1, 1, 128, 128)), jnp.zeros((1, 1), jnp.int32),
+              jnp.zeros((1, 1, 128, 128)), jnp.zeros((1, 1), jnp.int32))
+    with pytest.raises(NotImplementedError, match="fuse_halo=False"):
+        ops.gas_aggregate(jnp.zeros((8, 128)),
+                          jnp.zeros((16, 16), jnp.uint8),
+                          jnp.zeros((4,), jnp.int32), jnp.ones((4,), bool),
+                          8, blocks, scales=jnp.ones((16,)),
+                          codebook=jnp.zeros((16, 256, 8)),
+                          backend="pallas")

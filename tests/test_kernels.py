@@ -142,7 +142,8 @@ def test_flash_decode_vs_ref(dtype, B, Kh, G, Dh, S, pos):
     q = jax.random.normal(ks[0], (B, Kh, G, Dh), dtype)
     k = jax.random.normal(ks[1], (B, S, Kh, Dh), dtype)
     v = jax.random.normal(ks[2], (B, S, Kh, Dh), dtype)
-    out = flash_decode(q, k, v, jnp.array(pos, jnp.int32), block_s=256)
+    out = flash_decode(q, k, v, jnp.array(pos, jnp.int32), block_s=256,
+                       interpret=True)
     ref = _decode_ref(q.astype(jnp.float32), k.astype(jnp.float32),
                       v.astype(jnp.float32), pos)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
@@ -161,11 +162,13 @@ def test_flash_decode_position_property(pos, data):
     q = jax.random.normal(ks[0], (B, Kh, G, Dh))
     k = jax.random.normal(ks[1], (B, S, Kh, Dh))
     v = jax.random.normal(ks[2], (B, S, Kh, Dh))
-    out1 = flash_decode(q, k, v, jnp.array(pos, jnp.int32), block_s=256)
+    out1 = flash_decode(q, k, v, jnp.array(pos, jnp.int32), block_s=256,
+                       interpret=True)
     # perturb only the masked tail
     if pos < S - 1:
         k2 = k.at[:, pos + 1:].set(jax.random.normal(ks[3],
                                                      k[:, pos + 1:].shape))
-        out2 = flash_decode(q, k2, v, jnp.array(pos, jnp.int32), block_s=256)
+        out2 = flash_decode(q, k2, v, jnp.array(pos, jnp.int32), block_s=256,
+                       interpret=True)
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                    rtol=1e-6, atol=1e-6)

@@ -204,6 +204,22 @@ def test_gas_state_checkpoint_roundtrip_bit_identical(tmp_path):
                                   np.asarray(m_res["loss"]))
 
 
+def test_build_plan_reuses_a_given_partition():
+    """A partition passed to `build_plan` replaces partitioning: a plan
+    built from another plan's `part` trains bit-identically to it, and a
+    partition of the wrong length is refused."""
+    plan, state = _small_plan()
+    again = R.build_plan(plan.graph, plan.spec, plan.config, part=plan.part)
+    np.testing.assert_array_equal(again.part, plan.part)
+    _, m = R.train_step(plan, state, plan.batch(0))
+    _, m_again = R.train_step(again, R.init_state(again), again.batch(0))
+    np.testing.assert_array_equal(np.asarray(m["loss"]),
+                                  np.asarray(m_again["loss"]))
+    with pytest.raises(ValueError, match="part must have shape"):
+        R.build_plan(plan.graph, plan.spec, plan.config,
+                     part=plan.part[:-1])
+
+
 def test_runtime_matches_trainer_shell():
     """GASTrainer is a thin shell: running the runtime surface directly
     reproduces its training trajectory exactly."""

@@ -1,0 +1,136 @@
+"""Compile the Pallas kernels for a TPU v5e chip that is described, not
+attached: Mosaic refuses layouts (unaligned slices, one-row blocks) that
+the interpreter runs, so interpret-mode tests alone cannot show that a
+kernel runs on the chip. Nothing executes here; each case lowers and
+compiles one kernel at the widths of `chip_smoke.py` (GCN 3 x 256 on a
+10k-node graph: ~1k nodes and ~1.5k halo rows per batch, forward blocks
+[9, 20, 128, 128]; an SLO=0 serving refresh of the whole graph,
+[64, 80, 128, 128]). The vq form of `fused.gather_spmm` is left out: it
+does not compile, and `ops.gas_aggregate` refuses it on `pallas`.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, and every xdist worker imports this
+file."""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+N, D, M = 10_001, 256, 1_536            # history rows, width, pulled rows
+R, K, BN = 9, 20, 128                   # forward block grid
+N_IN = 1_032                            # in-batch rows
+R_SERVE, K_SERVE = 64, 80               # SLO=0 refresh of the whole graph
+HEADS, FP = 8, 128                      # GAT heads, lane-padded head width
+S, C, DS = 32, 256, 8                   # vq subvectors, entries, sub-width
+f32, i32, i8, u8 = jnp.float32, jnp.int32, jnp.int8, jnp.uint8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _cases():
+    from repro.kernels import (bcsr_spmm, edge_softmax as esk, fused,
+                               gather, pna_reduce as pnk, scatter)
+    blocks = [((R, K, BN, BN), f32), ((R, K), i32)]
+    plan = [((R, K, BN), i32)] * 2                      # sel, trow
+    kw = dict(interpret=False)
+    return {
+        "bcsr_spmm": (functools.partial(bcsr_spmm.bcsr_spmm, **kw),
+                      [((K * BN, D), f32)] + blocks),
+        "gather_rows": (functools.partial(gather.gather_rows, **kw),
+                        [((N, D), f32), ((M,), i32)]),
+        "gather_rows_dq": (
+            lambda t, s, i: gather.gather_rows(t, i, s, **kw),
+            [((N, D), i8), ((N,), f32), ((M,), i32)]),
+        "gather_rows_vq": (
+            functools.partial(gather.gather_rows_vq, **kw),
+            [((N, S), u8), ((S, C, DS), f32), ((N,), f32), ((M,), i32)]),
+        "scatter_rows": (functools.partial(scatter.scatter_rows, **kw),
+                         [((N, D), f32), ((M,), i32), ((M, D), f32)]),
+        "scatter_rows_vq": (
+            functools.partial(scatter.scatter_rows_vq, **kw),
+            [((N, S), u8), ((M,), i32), ((M, D), f32), ((M,), f32),
+             ((S, C, DS), f32)]),
+        "scatter_rows_q": (
+            functools.partial(scatter.scatter_rows, **kw),
+            [((N, D), i8), ((M,), i32), ((M, D), f32), ((M,), f32)]),
+        "gather_spmm_f32": (
+            functools.partial(fused.gather_spmm, **kw),
+            [((N_IN, D), f32), ((N, D), f32)] + blocks + plan),
+        "gather_spmm_int8": (
+            functools.partial(fused.gather_spmm, **kw),
+            [((N_IN, D), f32), ((N, D), i8)] + blocks + plan
+            + [((N,), f32)]),
+        # the gather plan outgrows SMEM as whole arrays at this size
+        "gather_spmm_refresh": (
+            functools.partial(fused.gather_spmm, **kw),
+            [((R_SERVE * BN, D), f32), ((N, D), i8),
+             ((R_SERVE, K_SERVE, BN, BN), f32), ((R_SERVE, K_SERVE), i32)]
+            + [((R_SERVE, K_SERVE, BN), i32)] * 2 + [((N,), f32)]),
+        "edge_softmax_fwd": (
+            functools.partial(esk.edge_softmax_fwd, **kw),
+            [((HEADS, R * BN), f32), ((HEADS, K * BN), f32),
+             ((HEADS, K * BN, FP), f32)] + blocks),
+        "edge_softmax_bwd_row": (
+            functools.partial(esk.edge_softmax_bwd_row, **kw),
+            [((HEADS, R * BN), f32), ((HEADS, K * BN), f32),
+             ((HEADS, K * BN, FP), f32), ((HEADS, R * BN, FP), f32)]
+            + [((HEADS, R * BN), f32)] * 3 + blocks),
+        "edge_softmax_bwd_col": (
+            functools.partial(esk.edge_softmax_bwd_col, **kw),
+            [((HEADS, R * BN), f32), ((HEADS, R * BN), f32),
+             ((HEADS, R * BN, FP), f32), ((HEADS, R * BN, FP), f32)]
+            + [((HEADS, R * BN), f32)] * 3 + blocks),
+        "pna_reduce_fwd": (
+            functools.partial(pnk.pna_reduce_fwd, **kw),
+            [((R * BN, D), f32), ((K * BN, D), f32)] + blocks),
+        "pna_reduce_bwd_row": (
+            functools.partial(pnk.pna_reduce_bwd_row, **kw),
+            [((R * BN, D), f32), ((K * BN, D), f32)]
+            + [((R * BN, D), f32)] * 7 + blocks),
+        "pna_reduce_bwd_col": (
+            functools.partial(pnk.pna_reduce_bwd_col, **kw),
+            [((R * BN, D), f32), ((R * BN, D), f32)]
+            + [((R * BN, D), f32)] * 7 + blocks),
+    }
+
+
+KERNELS = ["bcsr_spmm", "gather_rows", "gather_rows_dq", "gather_rows_vq",
+           "scatter_rows", "scatter_rows_q", "scatter_rows_vq",
+           "gather_spmm_f32", "gather_spmm_int8", "gather_spmm_refresh",
+           "edge_softmax_fwd", "edge_softmax_bwd_row",
+           "edge_softmax_bwd_col", "pna_reduce_fwd", "pna_reduce_bwd_row",
+           "pna_reduce_bwd_col"]
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e(name, one_chip):
+    fn, shapes = _cases()[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -59,3 +59,24 @@ def test_checkpoint_roundtrip(tmp_path):
     for a, b in zip(jax.tree_util.tree_leaves(opt),
                     jax.tree_util.tree_leaves(o2)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_compile_cache_dir_from_env_or_checkout(monkeypatch):
+    """`enable_compile_cache` leaves an env-placed cache to JAX and sets
+    nothing; without the variable the cache sits at the checkout's one
+    fixed path."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.REPO_CACHE)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.REPO_CACHE.parent.joinpath("src").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
