@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple, Union
 
@@ -50,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.graphs import Graph
+from repro.utils import spans
 from . import delta as D
 from . import gas as G
 from .batch import BlockStructure, GASBatch
@@ -80,7 +80,9 @@ class DynamicGASConfig:
 
 @dataclass
 class AdvanceInfo:
-    """What one `advance` did, and where its time went (seconds)."""
+    """What one `advance` did, and where its time went: the seconds of
+    its spans `gas/advance/partition`, `/batches` and `/repush` (summed
+    where a phase ran twice)."""
     cold: bool
     reason: str
     num_new_nodes: int
@@ -91,7 +93,10 @@ class AdvanceInfo:
     partition_s: float
     batches_s: float
     repush_s: float
-    total_s: float
+
+    @property
+    def total_s(self) -> float:
+        return self.partition_s + self.batches_s + self.repush_s
 
 
 def _slacked(n: int, frac: float) -> int:
@@ -220,102 +225,113 @@ def advance(plan: GASPlan, state: GASState, delta: D.GraphDelta,
     docstring for the three incremental repairs and the cold fallback.
     Returns (new plan, new state, AdvanceInfo). The old plan/state are
     not mutated (the plan's cached jit closures are shared)."""
-    t0 = time.perf_counter()
     cfg = dcfg.base
-    g_old = plan.graph
-    n_old = g_old.num_nodes
-    g_new = D.apply_delta(g_old, delta)
-    N = g_new.num_nodes
-    n_new_nodes = delta.num_new_nodes
-    hops = (dcfg.closure_hops if dcfg.closure_hops is not None
-            else plan.spec.num_layers - 1)
-    seeds = delta.invalidation_seeds(n_old)
-    closure = D.hop_closure(g_new.indptr, g_new.indices, seeds, hops)
-    closure_frac = len(closure) / max(N, 1)
+    partition_s = batches_s = 0.0
+    with spans.span("gas/advance/partition") as sp:
+        g_old = plan.graph
+        n_old = g_old.num_nodes
+        g_new = D.apply_delta(g_old, delta)
+        N = g_new.num_nodes
+        n_new_nodes = delta.num_new_nodes
+        hops = (dcfg.closure_hops if dcfg.closure_hops is not None
+                else plan.spec.num_layers - 1)
+        seeds = delta.invalidation_seeds(n_old)
+        closure = D.hop_closure(g_new.indptr, g_new.indices, seeds, hops)
+        closure_frac = len(closure) / max(N, 1)
 
-    cold = closure_frac > dcfg.cold_rebuild_frac
-    reason = (f"closure {closure_frac:.3f} > cold_rebuild_frac "
-              f"{dcfg.cold_rebuild_frac}" if cold else "incremental")
-    part_new = None
-    patched = None
-    rebuilt: np.ndarray = np.zeros(0, np.int64)
-    reassigned = 0
+        cold = closure_frac > dcfg.cold_rebuild_frac
+        reason = (f"closure {closure_frac:.3f} > cold_rebuild_frac "
+                  f"{dcfg.cold_rebuild_frac}" if cold else "incremental")
+        part_new = None
+        patched = None
+        rebuilt: np.ndarray = np.zeros(0, np.int64)
+        reassigned = 0
+        if not cold:
+            part_ext = assign_new_nodes(g_new.indptr, g_new.indices,
+                                        plan.part, cfg.num_parts)
+            region = D.hop_closure(g_new.indptr, g_new.indices, seeds, 1)
+            part_new = incremental_repair(
+                g_new.indptr, g_new.indices, part_ext, cfg.num_parts,
+                region, passes=dcfg.repair_passes, seed=cfg.seed)
+            moved = np.flatnonzero(part_new[:n_old]
+                                   != np.asarray(plan.part)[:n_old])
+            reassigned = int(len(moved))
+    partition_s += sp.seconds
+
     if not cold:
-        part_ext = assign_new_nodes(g_new.indptr, g_new.indices,
-                                    plan.part, cfg.num_parts)
-        region = D.hop_closure(g_new.indptr, g_new.indices, seeds, 1)
-        part_new = incremental_repair(
-            g_new.indptr, g_new.indices, part_ext, cfg.num_parts,
-            region, passes=dcfg.repair_passes, seed=cfg.seed)
-        moved = np.flatnonzero(part_new[:n_old]
-                               != np.asarray(plan.part)[:n_old])
-        reassigned = int(len(moved))
-        t_part = time.perf_counter()
-        # a batch needs re-emission iff its membership or any of its
-        # edge weights changed: parts holding a structural endpoint or a
-        # new node (adjacency changed), a neighbor of one (its incident
-        # GCN weights renormalize with the endpoint's degree), or a
-        # repartitioned node (membership/halo changed — old AND new
-        # part). Feature-only updates touch no batch structure.
-        touched = delta.touched_nodes(n_old)
-        nbrs = D.csr_neighbors(g_new.indptr, g_new.indices, touched)
-        aff = np.unique(np.concatenate(
-            [touched, nbrs, moved,
-             np.arange(n_old, N, dtype=np.int64)]))
-        rebuilt = np.unique(np.concatenate(
-            [part_new[aff],
-             np.asarray(plan.part)[moved]])).astype(np.int64)
-        patched = G.patch_batches(g_new, part_new, plan.batches, rebuilt,
-                                  num_nodes_old=n_old)
+        with spans.span("gas/advance/batches") as sp:
+            # a batch needs re-emission iff its membership or any of its
+            # edge weights changed: parts holding a structural endpoint
+            # or a new node (adjacency changed), a neighbor of one (its
+            # incident GCN weights renormalize with the endpoint's
+            # degree), or a repartitioned node (membership/halo changed
+            # — old AND new part). Feature-only updates touch no batch
+            # structure.
+            touched = delta.touched_nodes(n_old)
+            nbrs = D.csr_neighbors(g_new.indptr, g_new.indices, touched)
+            aff = np.unique(np.concatenate(
+                [touched, nbrs, moved,
+                 np.arange(n_old, N, dtype=np.int64)]))
+            rebuilt = np.unique(np.concatenate(
+                [part_new[aff],
+                 np.asarray(plan.part)[moved]])).astype(np.int64)
+            patched = G.patch_batches(g_new, part_new, plan.batches,
+                                      rebuilt, num_nodes_old=n_old)
+        batches_s += sp.seconds
         if patched is None:
             cold = True
             reason = "pad overflow (or changed part count)"
 
     new_plan = dataclasses.replace(plan)   # shallow copy, caches shared
     if cold:
-        if cfg.partitioner == "metis":
-            part_new = metis_like_partition(g_new.indptr, g_new.indices,
-                                            cfg.num_parts, seed=cfg.seed)
-        else:
-            part_new = random_partition(N, cfg.num_parts, seed=cfg.seed)
-        t_part = time.perf_counter()
-        patched, new_plan._pad_to, new_plan._pad_k, new_plan._pad_k_t = \
-            _build_slacked(g_new, part_new, plan.build_blocks,
-                           plan.unit_blocks, dcfg.pad_slack)
+        with spans.span("gas/advance/partition") as sp:
+            if cfg.partitioner == "metis":
+                part_new = metis_like_partition(
+                    g_new.indptr, g_new.indices, cfg.num_parts,
+                    seed=cfg.seed)
+            else:
+                part_new = random_partition(N, cfg.num_parts,
+                                            seed=cfg.seed)
+        partition_s += sp.seconds
+        with spans.span("gas/advance/batches") as sp:
+            (patched, new_plan._pad_to, new_plan._pad_k,
+             new_plan._pad_k_t) = _build_slacked(
+                g_new, part_new, plan.build_blocks, plan.unit_blocks,
+                dcfg.pad_slack)
+        batches_s += sp.seconds
         rebuilt = np.arange(patched.num_batches, dtype=np.int64)
-    t_batches = time.perf_counter()
 
-    new_plan.graph = g_new
-    new_plan.part = part_new
-    new_plan.batches = patched
-    new_plan.batch_stack = patched.device()
-    new_plan.x = jnp.asarray(g_new.x)
-    new_plan.y = jnp.concatenate([jnp.asarray(g_new.y),
-                                  jnp.zeros((1,), jnp.int32)])
-    new_plan.train_mask = jnp.asarray(
-        np.concatenate([g_new.train_mask, [False]]))
-    dst, src, w = G.gcn_edge_weights(g_new)
-    new_plan.eval_edges = (jnp.asarray(dst), jnp.asarray(src))
-    new_plan.eval_w = jnp.asarray(w)
-    # predict() bakes N/num_classes into its trace as constants — always
-    # drop it; the step/epoch closures only capture spec/config/backend
-    # and re-trace themselves on any shape change
-    new_plan._predict = None
+    with spans.span("gas/advance/repush") as sp:
+        new_plan.graph = g_new
+        new_plan.part = part_new
+        new_plan.batches = patched
+        new_plan.batch_stack = patched.device()
+        new_plan.x = jnp.asarray(g_new.x)
+        new_plan.y = jnp.concatenate([jnp.asarray(g_new.y),
+                                      jnp.zeros((1,), jnp.int32)])
+        new_plan.train_mask = jnp.asarray(
+            np.concatenate([g_new.train_mask, [False]]))
+        dst, src, w = G.gcn_edge_weights(g_new)
+        new_plan.eval_edges = (jnp.asarray(dst), jnp.asarray(src))
+        new_plan.eval_w = jnp.asarray(w)
+        # predict() bakes N/num_classes into its trace as constants —
+        # always drop it; the step/epoch closures only capture
+        # spec/config/backend and re-trace themselves on any shape change
+        new_plan._predict = None
 
-    store = state.histories
-    if n_new_nodes:
-        store = store.grow(n_new_nodes)
-    repush = np.arange(N, dtype=np.int64) if cold else closure
-    new_state = state.replace(
-        histories=_repush_closure(new_plan, state, store, repush))
-    t_end = time.perf_counter()
+        store = state.histories
+        if n_new_nodes:
+            store = store.grow(n_new_nodes)
+        repush = np.arange(N, dtype=np.int64) if cold else closure
+        new_state = state.replace(
+            histories=_repush_closure(new_plan, state, store, repush))
 
     return new_plan, new_state, AdvanceInfo(
         cold=cold, reason=reason, num_new_nodes=n_new_nodes,
         closure_size=int(len(closure)), closure_frac=float(closure_frac),
         rebuilt_parts=int(len(rebuilt)), reassigned=reassigned,
-        partition_s=t_part - t0, batches_s=t_batches - t_part,
-        repush_s=t_end - t_batches, total_s=t_end - t0)
+        partition_s=partition_s, batches_s=batches_s,
+        repush_s=sp.seconds)
 
 
 # ---------------------------------------------------------------------------

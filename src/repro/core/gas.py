@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.data.graphs import Graph
 from repro.kernels import ops
+from repro.utils import spans
 from . import history as H
 from .batch import BlockStructure, GASBatch
 
@@ -110,57 +111,58 @@ def build_batches(graph: Graph, part: np.ndarray,
     for them. Pass True/False to force."""
     if build_blocks is None:
         build_blocks = ops.resolve_backend(None) != "jnp"
-    N = graph.num_nodes
-    B = int(part.max()) + 1
-    dst, src, w = gcn_edge_weights(graph, add_self_loops)
+    with spans.span("gas/plan/coo"):
+        N = graph.num_nodes
+        B = int(part.max()) + 1
+        dst, src, w = gcn_edge_weights(graph, add_self_loops)
 
-    order = np.argsort(part[dst], kind="stable")
-    dst_s, src_s, w_s = dst[order], src[order], w[order]
-    edge_part = part[dst_s]
-    bounds = np.searchsorted(edge_part, np.arange(B + 1))
+        order = np.argsort(part[dst], kind="stable")
+        dst_s, src_s, w_s = dst[order], src[order], w[order]
+        edge_part = part[dst_s]
+        bounds = np.searchsorted(edge_part, np.arange(B + 1))
 
-    batches, halos, edges = [], [], []
-    for b in range(B):
-        nodes_b = np.flatnonzero(part == b).astype(np.int32)
-        e0, e1 = bounds[b], bounds[b + 1]
-        d_b, s_b, w_b = dst_s[e0:e1], src_s[e0:e1], w_s[e0:e1]
-        halo = np.setdiff1d(s_b, nodes_b)
-        # local index map: batch nodes -> [0, nb), halo -> [nb, nb+nh)
-        batches.append(nodes_b)
-        halos.append(halo.astype(np.int32))
-        edges.append((d_b, s_b, w_b))
+        batches, halos, edges = [], [], []
+        for b in range(B):
+            nodes_b = np.flatnonzero(part == b).astype(np.int32)
+            e0, e1 = bounds[b], bounds[b + 1]
+            d_b, s_b, w_b = dst_s[e0:e1], src_s[e0:e1], w_s[e0:e1]
+            halo = np.setdiff1d(s_b, nodes_b)
+            # local index map: batch nodes -> [0, nb), halo -> [nb, nb+nh)
+            batches.append(nodes_b)
+            halos.append(halo.astype(np.int32))
+            edges.append((d_b, s_b, w_b))
 
-    max_b = max(len(x) for x in batches)
-    max_h = max(max(len(x) for x in halos), 1)
-    max_e = max(len(e[0]) for e in edges)
-    if pad_to is not None:
-        max_b = max(max_b, pad_to[0])
-        max_h = max(max_h, pad_to[1])
-        max_e = max(max_e, pad_to[2])
+        max_b = max(len(x) for x in batches)
+        max_h = max(max(len(x) for x in halos), 1)
+        max_e = max(len(e[0]) for e in edges)
+        if pad_to is not None:
+            max_b = max(max_b, pad_to[0])
+            max_h = max(max_h, pad_to[1])
+            max_e = max(max_e, pad_to[2])
 
-    bnode = np.full((B, max_b), N, np.int32)
-    bmask = np.zeros((B, max_b), bool)
-    hn = np.full((B, max_h), N, np.int32)
-    hm = np.zeros((B, max_h), bool)
-    ed = np.full((B, max_e), max_b, np.int32)          # trash row
-    es = np.full((B, max_e), max_b + max_h, np.int32)  # dummy zero row
-    ew = np.zeros((B, max_e), np.float32)
+        bnode = np.full((B, max_b), N, np.int32)
+        bmask = np.zeros((B, max_b), bool)
+        hn = np.full((B, max_h), N, np.int32)
+        hm = np.zeros((B, max_h), bool)
+        ed = np.full((B, max_e), max_b, np.int32)          # trash row
+        es = np.full((B, max_e), max_b + max_h, np.int32)  # dummy zero row
+        ew = np.zeros((B, max_e), np.float32)
 
-    for b in range(B):
-        nodes_b, halo = batches[b], halos[b]
-        d_b, s_b, w_b = edges[b]
-        nb, nh, ne = len(nodes_b), len(halo), len(d_b)
-        bnode[b, :nb] = nodes_b
-        bmask[b, :nb] = True
-        hn[b, :nh] = halo
-        hm[b, :nh] = True
-        # global -> local
-        lookup = np.full(N + 1, max_b + max_h, np.int64)
-        lookup[nodes_b] = np.arange(nb)
-        lookup[halo] = max_b + np.arange(nh)
-        ed[b, :ne] = lookup[d_b]      # always < nb (dst in batch)
-        es[b, :ne] = lookup[s_b]
-        ew[b, :ne] = w_b
+        for b in range(B):
+            nodes_b, halo = batches[b], halos[b]
+            d_b, s_b, w_b = edges[b]
+            nb, nh, ne = len(nodes_b), len(halo), len(d_b)
+            bnode[b, :nb] = nodes_b
+            bmask[b, :nb] = True
+            hn[b, :nh] = halo
+            hm[b, :nh] = True
+            # global -> local
+            lookup = np.full(N + 1, max_b + max_h, np.int64)
+            lookup[nodes_b] = np.arange(nb)
+            lookup[halo] = max_b + np.arange(nh)
+            ed[b, :ne] = lookup[d_b]      # always < nb (dst in batch)
+            es[b, :ne] = lookup[s_b]
+            ew[b, :ne] = w_b
 
     blk_vals = blk_cols = blk_vals_t = blk_cols_t = None
     ublk_vals = ublk_vals_t = None
@@ -169,22 +171,28 @@ def build_batches(graph: Graph, part: np.ndarray,
         # BCSR — forward AND transposed (backward-on-MXU) structures, plus
         # optional unit-weight value blocks (GIN). K/K_t padded to the max
         # over batches (pad_k/pad_k_t let regrouped epochs share one jit
-        # trace — see GASTrainer._regroup)
-        per = [_emit_part_blocks(ed[b], es[b], ew[b], max_b, max_h, bn,
-                                 unit_weights) for b in range(B)]
-        R = per[0]["v"].shape[0]
-        R_t = per[0]["vt"].shape[0]
-        K = max(max(e["c"].shape[1] for e in per), pad_k or 1)
-        K_t = max(max(e["ct"].shape[1] for e in per), pad_k_t or 1)
-        vals = np.zeros((B, R, K, bn, bn), np.float32)
-        blk_cols = np.zeros((B, R, K), np.int32)
-        vals_t = np.zeros((B, R_t, K_t, bn, bn), np.float32)
-        blk_cols_t = np.zeros((B, R_t, K_t), np.int32)
-        for b, e in enumerate(per):
-            vals[b, :, :e["v"].shape[1]] = e["v"]
-            blk_cols[b, :, :e["c"].shape[1]] = e["c"]
-            vals_t[b, :, :e["vt"].shape[1]] = e["vt"]
-            blk_cols_t[b, :, :e["ct"].shape[1]] = e["ct"]
+        # trace — see runtime._regroup)
+        with spans.span("gas/plan/emit"):
+            per = [_emit_part_blocks(ed[b], es[b], ew[b], max_b, max_h, bn,
+                                     unit_weights) for b in range(B)]
+        with spans.span("gas/plan/stack"):
+            R = per[0]["v"].shape[0]
+            R_t = per[0]["vt"].shape[0]
+            K = max(max(e["c"].shape[1] for e in per), pad_k or 1)
+            K_t = max(max(e["ct"].shape[1] for e in per), pad_k_t or 1)
+            vals = np.zeros((B, R, K, bn, bn), np.float32)
+            blk_cols = np.zeros((B, R, K), np.int32)
+            vals_t = np.zeros((B, R_t, K_t, bn, bn), np.float32)
+            blk_cols_t = np.zeros((B, R_t, K_t), np.int32)
+            for b, e in enumerate(per):
+                vals[b, :, :e["v"].shape[1]] = e["v"]
+                blk_cols[b, :, :e["c"].shape[1]] = e["c"]
+                vals_t[b, :, :e["vt"].shape[1]] = e["vt"]
+                blk_cols_t[b, :, :e["ct"].shape[1]] = e["ct"]
+        # block fill, from the COO and the shapes: every valid edge slot
+        # (ew > 0) is one nonzero entry of each of the two stacks
+        spans.count("gas/plan/edge_entries", 2 * int((ew > 0).sum()))
+        spans.count("gas/plan/block_entries", vals.size + vals_t.size)
         if unit_weights:
             ublk_vals, ublk_vals_t = vals, vals_t
         else:

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.data.graphs import Graph
 from repro.kernels import ops
+from repro.utils import spans
 from . import gas as G
 from . import history as H
 from .batch import GASBatch
@@ -171,7 +172,16 @@ def build_plan(graph: Graph, spec, config: GASConfig,
     """Partition the graph, build (stack, upload) the typed batch
     structures, resolve the kernel backend — everything static. `part`
     (e.g. another plan's `part` over the same graph) skips partitioning,
-    the slow host-side step of plan construction."""
+    the slow host-side step of plan construction. Timed as span
+    `gas/plan`, with `gas/plan/partition` and `gas/plan/device_put`
+    inside (`utils.spans`); the bytes handed to the device count into
+    `gas/plan/upload_bytes`."""
+    with spans.span("gas/plan"):
+        return _build_plan(graph, spec, config, part)
+
+
+def _build_plan(graph: Graph, spec, config: GASConfig,
+                part: Optional[np.ndarray]) -> GASPlan:
     from repro.gnn.model import BLOCK_OPS, UNIT_BLOCK_OPS
 
     backend = ops.resolve_backend(config.backend)
@@ -185,22 +195,21 @@ def build_plan(graph: Graph, spec, config: GASConfig,
         part = np.asarray(part)
         if part.shape != (N,):
             raise ValueError(f"part must have shape ({N},), got {part.shape}")
-    elif config.partitioner == "metis":
-        part = metis_like_partition(graph.indptr, graph.indices,
-                                    config.num_parts, seed=config.seed)
     else:
-        part = random_partition(N, config.num_parts, seed=config.seed)
+        with spans.span("gas/plan/partition"):
+            if config.partitioner == "metis":
+                part = metis_like_partition(graph.indptr, graph.indices,
+                                            config.num_parts,
+                                            seed=config.seed)
+            else:
+                part = random_partition(N, config.num_parts,
+                                        seed=config.seed)
 
     plan = GASPlan(
         graph=graph, spec=spec, config=config, backend=backend,
         history_dtype=history_dtype, history_storage=history_storage,
         part=part,
-        batches=None, batch_stack=None,
-        x=jnp.asarray(graph.x),
-        y=jnp.concatenate([jnp.asarray(graph.y),
-                           jnp.zeros((1,), jnp.int32)]),   # pad row
-        train_mask=jnp.asarray(
-            np.concatenate([graph.train_mask, [False]])),
+        batches=None, batch_stack=None, x=None, y=None, train_mask=None,
         eval_edges=None, eval_w=None,
         build_blocks=build_blocks, unit_blocks=unit_blocks,
         _np_rng=np.random.default_rng(config.seed + 17))
@@ -218,15 +227,28 @@ def build_plan(graph: Graph, spec, config: GASConfig,
         plan.batches = G.build_batches(graph, part,
                                        build_blocks=build_blocks,
                                        unit_weights=unit_blocks)
-        plan.batch_stack = plan.batches.device()
-
     dst, src, w = G.gcn_edge_weights(graph)   # exact full-propagation eval
-    plan.eval_edges = (jnp.asarray(dst), jnp.asarray(src))
-    plan.eval_w = jnp.asarray(w)
+
+    with spans.span("gas/plan/device_put"):
+        plan.batch_stack = plan.batches.device()
+        plan.x = jnp.asarray(graph.x)
+        plan.y = jnp.concatenate([jnp.asarray(graph.y),
+                                  jnp.zeros((1,), jnp.int32)])   # pad row
+        plan.train_mask = jnp.asarray(
+            np.concatenate([graph.train_mask, [False]]))
+        plan.eval_edges = (jnp.asarray(dst), jnp.asarray(src))
+        plan.eval_w = jnp.asarray(w)
+    spans.count("gas/plan/upload_bytes", sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(
+            (plan.batch_stack, plan.x, plan.y, plan.train_mask,
+             plan.eval_edges, plan.eval_w))))
     return plan
 
 
 def _regroup(plan: GASPlan) -> None:
+    """Draws a new grouping of clusters into batches and builds its host
+    batches (`plan.batches`), growing the lazy block pads; the caller
+    uploads them."""
     cfg = plan.config
     grouped = G.group_partition(plan.part, cfg.clusters_per_batch,
                                 plan._np_rng)
@@ -240,7 +262,6 @@ def _regroup(plan: GASPlan) -> None:
         tr = plan.batches.transposed or plan.batches.unit_transposed
         plan._pad_k = max(plan._pad_k, fwd.cols.shape[2])
         plan._pad_k_t = max(plan._pad_k_t, tr.cols.shape[2])
-    plan.batch_stack = plan.batches.device()
 
 
 def init_state(plan: GASPlan) -> GASState:
@@ -403,24 +424,47 @@ def train_epoch(plan: GASPlan, state: GASState, epoch: int
     i+depth's halo before running batch i — so history I/O rides behind
     compute, the paper's §5 concurrent execution at the epoch level.
     Bit-identical to the synchronous schedule (state, metrics, and
-    checkpoint round-trips), fused or not."""
+    checkpoint round-trips), fused or not.
+
+    Timed as span `gas/epoch` (`utils.spans`), in which
+    `gas/epoch/regroup` rebuilds the batches, `gas/epoch/dispatch` draws
+    the order and dispatches the epoch, `gas/epoch/wait` waits for its
+    metrics and `gas/epoch/readback` turns them into host floats."""
+    with spans.span("gas/epoch"):
+        cfg = plan.config
+        cadence_due = (cfg.vq_refit_every > 0 and epoch > 0
+                       and epoch % cfg.vq_refit_every == 0)
+        drift_due = (cfg.vq_refit_drift > 0 and plan._last_qerr is not None
+                     and plan._last_qerr > cfg.vq_refit_drift)
+        if (cadence_due or drift_due) and plan.history_dtype == "vq":
+            # k-means M-step on the vq codebooks from the stats last
+            # epoch's pushes accumulated — on the fixed cadence and/or
+            # whenever the measured quantization error drifted past
+            # `vq_refit_drift`. Host-driven, OUTSIDE the jitted step: the
+            # codebook is a constant within an epoch, which keeps the
+            # prefetch pipeline's bit-identity guarantees
+            state = replace(state,
+                            histories=state.histories.refit_codebooks())
+        if cfg.clusters_per_batch > 1 and epoch > 0:
+            with spans.span("gas/epoch/regroup"):
+                _regroup(plan)
+                plan.batch_stack = plan.batches.device()
+        with spans.span("gas/epoch/dispatch"):
+            rng = np.random.default_rng(cfg.seed * 1000 + epoch)
+            order = rng.permutation(plan.batches.num_batches)
+            state, metrics = _dispatch_epoch(plan, state, order)
+        with spans.span("gas/epoch/wait"):
+            jax.block_until_ready(metrics)
+        with spans.span("gas/epoch/readback"):
+            out = {k: float(np.mean(v)) for k, v in metrics.items()}
+        return state, _epoch_metrics(plan, out)
+
+
+def _dispatch_epoch(plan: GASPlan, state: GASState, order: np.ndarray
+                    ) -> Tuple[GASState, Dict[str, Any]]:
+    """Dispatches one epoch's steps in `order`; returns the state and
+    each metric's per-batch values (device arrays, not waited for)."""
     cfg = plan.config
-    cadence_due = (cfg.vq_refit_every > 0 and epoch > 0
-                   and epoch % cfg.vq_refit_every == 0)
-    drift_due = (cfg.vq_refit_drift > 0 and plan._last_qerr is not None
-                 and plan._last_qerr > cfg.vq_refit_drift)
-    if (cadence_due or drift_due) and plan.history_dtype == "vq":
-        # k-means M-step on the vq codebooks from the stats last epoch's
-        # pushes accumulated — on the fixed cadence and/or whenever the
-        # measured quantization error drifted past `vq_refit_drift`.
-        # Host-driven, OUTSIDE the jitted step: the codebook is a
-        # constant within an epoch, which keeps the prefetch pipeline's
-        # bit-identity guarantees
-        state = replace(state, histories=state.histories.refit_codebooks())
-    if cfg.clusters_per_batch > 1 and epoch > 0:
-        _regroup(plan)
-    order = np.random.default_rng(cfg.seed * 1000 + epoch).permutation(
-        plan.batches.num_batches)
     depth = _resolved_depth(plan)
     if cfg.fused_epoch:
         if plan._epoch is None:
@@ -464,11 +508,8 @@ def train_epoch(plan: GASPlan, state: GASState, epoch: int
                     return state, metrics
 
             plan._epoch = epoch_fn
-        state, metrics = plan._epoch(state, plan.batch_stack,
-                                  jnp.asarray(order), plan.x, plan.y,
-                                  plan.train_mask)
-        return state, _epoch_metrics(
-            plan, {k: float(np.mean(v)) for k, v in metrics.items()})
+        return plan._epoch(state, plan.batch_stack, jnp.asarray(order),
+                           plan.x, plan.y, plan.train_mask)
     if depth > 0:
         if plan._pf_step is None:
             plan._pf_step = jax.jit(make_prefetch_step_fn(plan, depth),
@@ -485,14 +526,12 @@ def train_epoch(plan: GASPlan, state: GASState, epoch: int
                 state, plan.batch_stack[int(b)], fb, queue, plan.x,
                 plan.y, plan.train_mask)
             agg.append(metrics)
-        return state, _epoch_metrics(
-            plan, {k: float(np.mean([m[k] for m in agg])) for k in agg[0]})
+        return state, {k: [m[k] for m in agg] for k in agg[0]}
     agg = []
     for b in order:
         state, metrics = train_step(plan, state, plan.batch_stack[int(b)])
         agg.append(metrics)
-    return state, _epoch_metrics(
-        plan, {k: float(np.mean([m[k] for m in agg])) for k in agg[0]})
+    return state, {k: [m[k] for m in agg] for k in agg[0]}
 
 
 def _epoch_metrics(plan: GASPlan, out: Dict[str, float]) -> Dict[str, float]:
