@@ -42,6 +42,7 @@ from . import edge_softmax as esk
 from . import fused
 from . import pna_reduce as pnk
 from . import ref as kref
+from ..utils import spans
 
 BACKENDS = ("pallas", "interpret", "jnp")
 
@@ -246,10 +247,8 @@ def gcn_aggregate(x_all: jnp.ndarray, edges, edge_w: jnp.ndarray,
 def _gather_spmm_kernel(x_in, table, scales, codebook, blk_vals, blk_cols,
                         blk_vals_t, blk_cols_t, halo_nodes, halo_mask, bn,
                         bd, interpret):
-    sel, trow = fused.gather_plan(blk_cols, halo_nodes, halo_mask,
-                                  x_in.shape[0], table.shape[0], bn)
-    return fused.gather_spmm(x_in, table, blk_vals, blk_cols, sel, trow,
-                             scales, codebook, bn=bn, bd=bd,
+    return fused.gather_spmm(x_in, table, blk_vals, blk_cols, halo_nodes,
+                             halo_mask, scales, codebook, bn=bn, bd=bd,
                              interpret=interpret)
 
 
@@ -314,22 +313,30 @@ def gas_aggregate(x_in: jnp.ndarray, table: jnp.ndarray,
     """Fused GAS aggregation: out = A @ [x_in ; dequant(table)[halo]*mask
     ; 0].
 
-    The kernel backends never materialize the bracket: the fused
-    `gather_spmm` kernel reads halo columns directly out of the history
-    table (scalar-prefetched gather plan), in-batch columns out of x_in,
-    and zeros for masked/padding columns — eliminating the per-layer
-    `pull_rows` + `jnp.concatenate` copies of the unfused path. With
-    `scales` [N] f32 the table is symmetric per-row int8
+    The kernel backends never materialize the bracket in HBM: the fused
+    `gather_spmm` kernel reads halo rows directly out of the history
+    table, in-batch rows out of x_in, and zeros for masked/padding
+    columns — eliminating the per-layer `pull_rows` + `jnp.concatenate`
+    copies of the unfused path. It stages each of the bracket's
+    Ncols = ceil((n_in + max_h + 1) / bn) column blocks once per call
+    into a VMEM panel of Ncols * bn * pw * 4 bytes (pw = D, or bd where
+    only one feature tile fits `fused.VMEM_BUDGET`), and every adjacency
+    block then contracts against the panel; where neither fits, it
+    falls back to staging each adjacency block's columns on its own, once
+    per row block (`fused` module docstring). The choice depends only on
+    shapes; tracing a call counts `gas/agg/panel_calls` or
+    `gas/agg/per_block_calls` (`repro.utils.spans`). With `scales` [N]
+    f32 the table is symmetric per-row int8
     (`core.history.quantize_rows`) and the dequant multiply is fused into
-    the halo-column load too; with `codebook` [S, C, ds] as well, the
-    table holds uint8 vq code rows that are codebook-decoded in VMEM —
-    either way no f32 copy of the table (or any halo row)
-    ever exists in HBM. `blocks` must be the 4-tuple (blk_vals, blk_cols,
-    blk_vals_t, blk_cols_t) from `core.gas.build_batches`; the transposed
-    pair keeps the backward on the MXU. The jnp backend runs the
-    materialized oracle (`kref.gather_spmm_ref`). Differentiable w.r.t.
-    x_in on every backend, and w.r.t. a float table (quantized tables get
-    zero cotangents).
+    the halo-row load too; with `codebook` [S, C, ds] as well, the table
+    holds uint8 vq code rows that are codebook-decoded in VMEM — either
+    way no f32 copy of the table (or any halo row) ever exists in HBM.
+    `blocks` must be the 4-tuple (blk_vals, blk_cols, blk_vals_t,
+    blk_cols_t) from `core.gas.build_batches`; the transposed pair keeps
+    the backward on the MXU. The jnp backend runs the materialized
+    oracle (`kref.gather_spmm_ref`). Differentiable w.r.t. x_in on every
+    backend, and w.r.t. a float table (quantized tables get zero
+    cotangents).
     """
     backend = resolve_backend(backend)
     D = x_in.shape[1]
@@ -362,6 +369,10 @@ def gas_aggregate(x_in: jnp.ndarray, table: jnp.ndarray,
     else:
         tp = jnp.pad(table, ((0, 0), (0, d_pad - D))) \
             if d_pad != D else table
+    panel = fused.panel_width(xp, tp, blk_vals, halo_nodes, codebook,
+                              bd=bd)
+    spans.count("gas/agg/panel_calls" if panel else
+                "gas/agg/per_block_calls", 1)
     out = _gather_spmm_kernel(xp, tp, scales, codebook, blk_vals,
                               blk_cols, blk_vals_t, blk_cols_t,
                               halo_nodes.astype(jnp.int32),

@@ -28,7 +28,7 @@ from repro.core import history as H
 from repro.data.graphs import citation_graph
 from repro.gnn.model import (BLOCK_OPS, UNIT_BLOCK_OPS, GNNSpec,
                              gas_batch_forward, init_gnn)
-from repro.kernels import ops
+from repro.kernels import fused, ops
 from repro.kernels import ref as kref
 
 KERNEL_BACKENDS = ("interpret", "pallas")
@@ -197,6 +197,124 @@ def test_gas_aggregate_masked_halo_rows_are_zeroed():
     out = ops.gas_aggregate(x_in, poisoned, hn, hm_none, n_out, blocks,
                             backend="interpret")
     assert np.isfinite(np.asarray(out)).all()
+
+
+# ---------------------------------------------------------------------------
+# gather_spmm's staging paths (VMEM panel at full width or one feature
+# tile, per-block fallback) == the materialized oracle
+# ---------------------------------------------------------------------------
+
+PANEL_CASES = ("shared_cols", "padded_k", "masked_halo", "tail_tile",
+               "one_tile_panel", "over_budget")
+
+
+@pytest.fixture
+def vmem_budget(monkeypatch):
+    """Sets `fused.VMEM_BUDGET` for one test. The budget steers tracing
+    but is no part of a jit cache key, so caches are cleared both ways."""
+    def set_budget(n):
+        monkeypatch.setattr(fused, "VMEM_BUDGET", n)
+        jax.clear_caches()
+    yield set_budget
+    jax.clear_caches()
+
+
+def _panel_problem(case, hd, seed=21, n_in=200, max_h=150, N=325, D=256,
+                   bn=64):
+    """R = 4 row blocks over 6 column blocks. Each case adds its feature
+    to a plain problem (dense random edges, every halo row unmasked and
+    inside the table's whole 8-row tiles); the last two cases have all:
+      padded_k    — the last row block reads one column block, so its
+                    other K entries are padding (column 0, zero values);
+      masked_halo — about half the halo rows are masked;
+      tail_tile   — halo rows in the table's partial last tile (N % 8).
+    """
+    rng = np.random.default_rng(seed)
+    every = case in ("one_tile_panel", "over_budget")
+    n_cols = n_in + max_h + 1
+    padded = every or case == "padded_k"
+    dst = rng.integers(0, 192 if padded else n_in, 1200)
+    src = rng.integers(0, n_cols, 1200)
+    if padded:
+        dst = np.append(dst, rng.integers(192, n_in, 16))
+        src = np.append(src, rng.integers(4 * bn, 5 * bn, 16))
+    dst, src = dst.astype(np.int32), src.astype(np.int32)
+    w = rng.normal(size=len(dst)).astype(np.float32)
+    v, c, _, _ = ops.build_bcsr_rect(dst, src, w, n_in, n_cols, bn=bn)
+    vt, ct, _, _ = ops.build_bcsr_rect(src, dst, w, n_cols, n_in, bn=bn)
+    blocks = tuple(jnp.asarray(a) for a in (v, c, vt, ct))
+    n8 = N // 8 * 8
+    hn = rng.integers(0, n8, max_h).astype(np.int32)
+    if every or case == "tail_tile":
+        hn[::6] = rng.integers(n8, N, len(hn[::6]))
+    hm = np.ones(max_h, bool)
+    if every or case == "masked_halo":
+        hm = rng.random(max_h) < 0.5
+    x_in = jnp.asarray(rng.normal(size=(n_in, D)).astype(np.float32))
+    table = jnp.asarray(rng.normal(size=(N, D)).astype(np.float32))
+    scales = codebook = None
+    if hd == "int8":
+        table, scales = H.quantize_rows(table)
+    elif hd == "vq":
+        codebook = H.vq_init_codebook(D)
+        table, scales = H.vq_encode_rows(table, codebook)
+    return (x_in, table, scales, codebook, jnp.asarray(hn),
+            jnp.asarray(hm), blocks, n_in)
+
+
+@pytest.mark.parametrize("hd", ("f32", "int8", "vq"))
+@pytest.mark.parametrize("case", PANEL_CASES)
+def test_gas_aggregate_staging_paths_match_oracle(case, hd, vmem_budget):
+    x_in, table, scales, cb, hn, hm, blocks, n_out = _panel_problem(case,
+                                                                    hd)
+    v, c = np.asarray(blocks[0]), np.asarray(blocks[1])
+    R, K, bn, _ = v.shape
+    # the feature each case stands for is there
+    assert R >= 3
+    if case == "shared_cols":
+        readers = [len({r for r in range(R) if j in c[r]})
+                   for j in range(int(c.max()) + 1)]
+        assert min(readers) >= 3, readers
+    if case in ("padded_k", "one_tile_panel", "over_budget"):
+        assert not np.abs(v[-1]).sum(axis=(1, 2)).all()
+    if case in ("masked_halo", "one_tile_panel", "over_budget"):
+        assert (~np.asarray(hm)).any()
+    if case in ("tail_tile", "one_tile_panel", "over_budget"):
+        n8 = table.shape[0] // 8 * 8
+        assert (np.asarray(hm) & (np.asarray(hn) >= n8)).any()
+    ncols = -(-(x_in.shape[0] + hn.shape[0] + 1) // bn)
+    if case == "one_tile_panel":      # the whole width just misses
+        vmem_budget(fused._panel_vmem(
+            ncols, x_in.shape[1], bn=bn, x_dtype=x_in.dtype, table=table,
+            vals_dtype=v.dtype, codebook=cb) - 1)
+    elif case == "over_budget":
+        vmem_budget(1)
+    want = {"one_tile_panel": 128, "over_budget": None}.get(case, 256)
+    assert fused.panel_width(x_in, table, blocks[0], hn, cb,
+                             bd=128) == want
+
+    def loss(xi, tb):
+        out = ops.gas_aggregate(xi, tb, hn, hm, n_out, blocks,
+                                scales=scales, codebook=cb,
+                                backend="interpret")
+        return jnp.sum(out ** 2), out
+
+    def loss_ref(xi, tb):
+        out = kref.gather_spmm_ref(xi, tb, hn, hm, blocks[0], blocks[1],
+                                   scales, cb)[:n_out]
+        return jnp.sum(out ** 2), out
+
+    # a quantized table is integer: no gradient to take there
+    argnums = (0, 1) if hd == "f32" else (0,)
+    (_, o_ker), g_ker = jax.value_and_grad(loss, argnums, has_aux=True)(
+        x_in, table)
+    (_, o_ref), g_ref = jax.value_and_grad(loss_ref, argnums,
+                                           has_aux=True)(x_in, table)
+    np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
+                               rtol=1e-4, atol=1e-4)
+    for gk, gr, name in zip(g_ker, g_ref, ("dx_in", "dtable")):
+        np.testing.assert_allclose(np.asarray(gk), np.asarray(gr),
+                                   rtol=1e-4, atol=1e-4, err_msg=name)
 
 
 # ---------------------------------------------------------------------------

@@ -210,3 +210,32 @@ def test_advance_reads_its_timings_off_its_spans(recording):
                         ("repush_s", "gas/advance/repush")):
         assert getattr(info, field) == pytest.approx(rec.seconds(name),
                                                      abs=1e-6)
+
+
+@pytest.mark.parametrize("budget,counted,absent", [
+    (None, "gas/agg/panel_calls", "gas/agg/per_block_calls"),
+    (1, "gas/agg/per_block_calls", "gas/agg/panel_calls")])
+def test_fused_epoch_counts_its_staging_path(recording, monkeypatch, budget,
+                                             counted, absent):
+    """Each traced `ops.gas_aggregate` call counts the staging path its
+    `gather_spmm` compiles: the VMEM panel at these shapes, the per-block
+    path once the budget is too small for any panel."""
+    from repro.kernels import fused
+    if budget is not None:
+        monkeypatch.setattr(fused, "VMEM_BUDGET", budget)
+    jax.clear_caches()              # trace again: counters count traces
+    g = _graph(200)
+    cfg = R.GASConfig(num_parts=4, backend="interpret", partitioner="random",
+                      fused_epoch=True, fuse_halo=True)
+    plan = R.build_plan(g, _spec(3), cfg)
+    state = R.init_state(plan)
+    spans.start()
+    try:
+        _, metrics = R.train_epoch(plan, state, 0)
+    finally:
+        rec = spans.stop()
+        jax.clear_caches()
+    # one fused aggregation per layer after the first, per trace
+    assert rec.counters[counted] >= 2
+    assert absent not in rec.counters
+    assert np.isfinite(metrics["loss"])

@@ -5,7 +5,11 @@ kernel runs on the chip. Nothing executes here; each case lowers and
 compiles one kernel at the widths of `chip_smoke.py` (GCN 3 x 256 on a
 10k-node graph: ~1k nodes and ~1.5k halo rows per batch, forward blocks
 [9, 20, 128, 128]; an SLO=0 serving refresh of the whole graph,
-[64, 80, 128, 128]). The vq form of `fused.gather_spmm` is left out: it
+[64, 80, 128, 128]). `fused.gather_spmm` is also compiled at the
+benchmark cells' shape (forward blocks [17, 80, 128, 128], 2,176 batch
+and 8,107 halo rows of a 100,001-row table), where its VMEM panel is
+largest, and for a refresh whose halo outgrows the panel, which takes
+the per-block path. The vq form of `fused.gather_spmm` is left out: it
 does not compile, and `ops.gas_aggregate` refuses it on `pallas`.
 
 The topology is described inside a module fixture, never at import: only
@@ -23,6 +27,9 @@ N, D, M = 10_001, 256, 1_536            # history rows, width, pulled rows
 R, K, BN = 9, 20, 128                   # forward block grid
 N_IN = 1_032                            # in-batch rows
 R_SERVE, K_SERVE = 64, 80               # SLO=0 refresh of the whole graph
+M_SERVE = K_SERVE * BN - R_SERVE * BN - 1   # its halo rows: 80 column blocks
+M_LARGE = 64 * 1024 - R_SERVE * BN - 1  # a halo of 512 column blocks
+N_CELL, R_CELL, N_IN_CELL, M_CELL = 100_001, 17, 2_176, 8_107  # the cells
 HEADS, FP = 8, 128                      # GAT heads, lane-padded head width
 S, C, DS = 32, 256, 8                   # vq subvectors, entries, sub-width
 f32, i32, i8, u8 = jnp.float32, jnp.int32, jnp.int8, jnp.uint8
@@ -56,7 +63,13 @@ def _cases():
     from repro.kernels import (bcsr_spmm, edge_softmax as esk, fused,
                                gather, pna_reduce as pnk, scatter)
     blocks = [((R, K, BN, BN), f32), ((R, K), i32)]
-    plan = [((R, K, BN), i32)] * 2                      # sel, trow
+
+    def halo(m):                                        # nodes, mask
+        return [((m,), i32), ((m,), jnp.bool_)]
+
+    serve = [((R_SERVE * BN, D), f32), ((N, D), i8),
+             ((R_SERVE, K_SERVE, BN, BN), f32), ((R_SERVE, K_SERVE), i32)]
+    cell = [((R_CELL, K, BN, BN), f32), ((R_CELL, K), i32)]
     kw = dict(interpret=False)
     return {
         "bcsr_spmm": (functools.partial(bcsr_spmm.bcsr_spmm, **kw),
@@ -80,17 +93,28 @@ def _cases():
             [((N, D), i8), ((M,), i32), ((M, D), f32), ((M,), f32)]),
         "gather_spmm_f32": (
             functools.partial(fused.gather_spmm, **kw),
-            [((N_IN, D), f32), ((N, D), f32)] + blocks + plan),
+            [((N_IN, D), f32), ((N, D), f32)] + blocks + halo(M)),
         "gather_spmm_int8": (
             functools.partial(fused.gather_spmm, **kw),
-            [((N_IN, D), f32), ((N, D), i8)] + blocks + plan
+            [((N_IN, D), f32), ((N, D), i8)] + blocks + halo(M)
             + [((N,), f32)]),
-        # the gather plan outgrows SMEM as whole arrays at this size
+        # 80 column blocks: a 10.5 MB panel, its [80, bn] plan whole in SMEM
         "gather_spmm_refresh": (
             functools.partial(fused.gather_spmm, **kw),
-            [((R_SERVE * BN, D), f32), ((N, D), i8),
-             ((R_SERVE, K_SERVE, BN, BN), f32), ((R_SERVE, K_SERVE), i32)]
-            + [((R_SERVE, K_SERVE, BN), i32)] * 2 + [((N,), f32)]),
+            serve + halo(M_SERVE) + [((N,), f32)]),
+        # 512 column blocks outgrow the panel: the per-block path, whose
+        # [R, K, bn] plan would outgrow SMEM as whole arrays
+        "gather_spmm_refresh_per_block": (
+            functools.partial(fused.gather_spmm, **kw),
+            serve + halo(M_LARGE) + [((N,), f32)]),
+        "gather_spmm_cell_f32": (
+            functools.partial(fused.gather_spmm, **kw),
+            [((N_IN_CELL, D), f32), ((N_CELL, D), f32)] + cell
+            + halo(M_CELL)),
+        "gather_spmm_cell_int8": (
+            functools.partial(fused.gather_spmm, **kw),
+            [((N_IN_CELL, D), f32), ((N_CELL, D), i8)] + cell
+            + halo(M_CELL) + [((N_CELL,), f32)]),
         "edge_softmax_fwd": (
             functools.partial(esk.edge_softmax_fwd, **kw),
             [((HEADS, R * BN), f32), ((HEADS, K * BN), f32),
@@ -122,6 +146,8 @@ def _cases():
 KERNELS = ["bcsr_spmm", "gather_rows", "gather_rows_dq", "gather_rows_vq",
            "scatter_rows", "scatter_rows_q", "scatter_rows_vq",
            "gather_spmm_f32", "gather_spmm_int8", "gather_spmm_refresh",
+           "gather_spmm_refresh_per_block", "gather_spmm_cell_f32",
+           "gather_spmm_cell_int8",
            "edge_softmax_fwd", "edge_softmax_bwd_row",
            "edge_softmax_bwd_col", "pna_reduce_fwd", "pna_reduce_bwd_row",
            "pna_reduce_bwd_col"]
@@ -134,3 +160,17 @@ def test_kernel_compiles_for_v5e(name, one_chip):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,width", [
+    ("gather_spmm_f32", D), ("gather_spmm_int8", D),
+    ("gather_spmm_refresh", D), ("gather_spmm_refresh_per_block", None),
+    ("gather_spmm_cell_f32", D), ("gather_spmm_cell_int8", D)])
+def test_gather_spmm_staging_path(name, width):
+    """Which staging path each compiled case takes (`fused.panel_width`,
+    shapes alone): the whole-width panel, or the per-block fallback."""
+    from repro.kernels import fused
+    x_in, table, vals, _, halo_nodes = [jax.ShapeDtypeStruct(s, dt) for
+                                        s, dt in _cases()[name][1][:5]]
+    assert fused.panel_width(x_in, table, vals, halo_nodes,
+                             bd=BN) == width
