@@ -2,7 +2,7 @@
 """One-process smoke of GAS training and serving on a TPU.
 
     python3 chip_smoke.py              # one chip
-    python3 chip_smoke.py --chips 4    # distributed GAS on a 4-chip mesh
+    python3 chip_smoke.py --chips 4    # sharded GAS on a 4-chip mesh
     python3 chip_smoke.py --rehearse [--chips 4]
                                        # tiny CPU rehearsal (not a chip run)
 
@@ -25,10 +25,11 @@ synthetic citation graph, through the public runtime on the compiled
   * serving: `staleness_slo=0` requests from the trained f32 state,
     compared with `full_forward` on the same chip.
 
-`--chips 4` runs only distributed GAS (`core/dist_gas.py`) on a mesh of
-the four chips: fixed-parameter supersteps must reach `full_forward` to
-within 1e-3 by superstep `num_layers`, then training supersteps must
-lower the loss.
+`--chips 4` runs only sharded GAS (`GASConfig(ranks=4)`, `core/dist_gas.py`)
+through the runtime on a mesh of the four chips: the first epoch on the
+kernels must match the same epoch on `backend="jnp"` (loss, parameters,
+history rows, at float32 precision), then further epochs must lower the
+loss.
 
 Earlier lines report the device, the resolved backend, set-up, compile
 and step seconds and peak device bytes. The last line is one JSON object,
@@ -70,9 +71,6 @@ PARAM_TOL = 1e-3
 # the same comparison at the default precision: a few bf16 rounding
 # steps (2^-8 = 3.9e-3) over the 3-layer forward
 BF16_TOL = 1e-2
-# fixed-parameter distributed supersteps vs `full_forward`, absolute
-# (tests/test_dist_gas.py)
-DIST_TOL = 1e-3
 
 
 def parse_args(argv=None):
@@ -459,96 +457,76 @@ def one_chip(args, rehearse):
 # ---------------------------------------------------------------------------
 
 def four_chips(args, rehearse):
+    """Sharded GAS through the runtime (`GASConfig(ranks=4)`) on a mesh
+    of the four chips: the first epoch on the kernel backend against the
+    same epoch on jnp (same partition, weights and superstep order), at
+    float32 precision, then further epochs must lower the loss."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
-    from repro.core import dist_gas as DG
-    from repro.core.gas import gcn_edge_weights
+    from repro.core import runtime as R
     from repro.core.partition import metis_like_partition
     from repro.data.graphs import citation_graph
-    from repro.gnn.model import GNNSpec, full_forward, init_gnn
-    from repro.train.optimizer import (adamw_init, adamw_update,
-                                       clip_by_global_norm)
+    from repro.gnn.model import GNNSpec
 
     ranks = 4
     devices = jax.devices()
     check(len(devices) >= ranks, f"--chips 4 needs 4 devices, "
           f"found {len(devices)}")
-    mesh = jax.make_mesh((ranks,), ("data",), devices=devices[:ranks],
-                         axis_types=(jax.sharding.AxisType.Auto,))
+    backend = "interpret" if rehearse else None
     nodes, _ = graph_size(rehearse)
+    parts = 8 if rehearse else 20             # 2 or 5 supersteps an epoch
     spec = GNNSpec(op="gcn", d_in=128, d_hidden=256, num_classes=40,
                    num_layers=3)
     t0 = time.perf_counter()
     g = citation_graph(num_nodes=nodes, avg_degree=10, num_features=128,
                        num_classes=40, seed=args.seed)
-    part = metis_like_partition(g.indptr, g.indices, ranks, seed=args.seed)
-    structs = DG.build_dist_structs(g, part)
-    log(f"dist: {g.num_nodes} nodes, {len(g.indices)} edges on {ranks} "
-        f"ranks, {structs.rows} rows/rank, max halo {structs.max_halo}; "
+    part = metis_like_partition(g.indptr, g.indices, parts, seed=args.seed)
+
+    def plan_on(be):
+        return R.build_plan(g, spec, R.GASConfig(
+            num_parts=parts, ranks=ranks, backend=be, seed=args.seed),
+            part=part, devices=devices[:ranks])
+
+    plan, ref = plan_on(backend), plan_on("jnp")
+    check(plan.backend == ("interpret" if rehearse else "pallas"),
+          f"dist: backend resolved to {plan.backend}, not pallas")
+    dist = plan.batch_stack
+    log(f"dist: {g.num_nodes} nodes, {len(g.indices)} edges, {parts} parts "
+        f"on {ranks} chips, {dist.slots.shape[1]} supersteps an epoch, "
+        f"{dist.rows} rows a shard, {dist.send_idx.shape[-1]} rows a peer; "
         f"set-up {time.perf_counter() - t0:.2f} s")
+    state, ref_state = R.init_state(plan), R.init_state(ref)
 
-    params = init_gnn(jax.random.key(args.seed), spec)
-    store = structs.init_store(spec.hist_dims())
-    x_pad = jnp.asarray(DG.permute_node_array(structs, g.x))
-    y_pad = jnp.asarray(DG.permute_node_array(structs,
-                                              g.y.astype(np.int32)))
-    m_pad = jnp.asarray(DG.permute_node_array(structs, g.train_mask))
-    batch = structs.device_batch()
-    exchange = structs.exchange_arrays()
-    loss_fn = DG.make_dist_loss_fn(spec, structs, mesh)
-    dst, src, w = gcn_edge_weights(g)
+    misses = Misses("dist")
     with jax.default_matmul_precision(COMPARE_PRECISION):
-        exact, ff_s = timed(
-            jax.jit(full_forward, static_argnums=(1, 5)), params, spec,
-            jnp.asarray(g.x), (jnp.asarray(dst), jnp.asarray(src)),
-            jnp.asarray(w), g.num_nodes)
-    exact = np.asarray(exact)
-    valid = structs.old_of_new >= 0
+        (state, m), secs = timed(R.train_epoch, plan, state, 0)
+        (ref_state, ref_m), ref_secs = timed(R.train_epoch, ref, ref_state,
+                                             0)
+    log(f"dist: epoch 0 at {COMPARE_PRECISION} precision (compile + run) "
+        f"{secs:.3f} s on {plan.backend}, {ref_secs:.3f} s on jnp; loss "
+        f"{m['loss']:.6f} vs jnp {ref_m['loss']:.6f}")
+    misses.within("epoch 0 loss rel err vs jnp",
+                  abs(m["loss"] - ref_m["loss"]) / abs(ref_m["loss"]),
+                  F32_TOL)
+    misses.within("epoch 0 parameters rel err vs jnp", max(
+        rel_err(a, b) for a, b in zip(
+            jax.tree_util.tree_leaves(state.params),
+            jax.tree_util.tree_leaves(ref_state.params))), PARAM_TOL)
+    for ell, (a, b) in enumerate(zip(state.histories.tables,
+                                     ref_state.histories.tables)):
+        misses.within(f"epoch 0 layer-{ell} history rows rel err vs jnp",
+                      rel_err(np.asarray(a)[plan.node_rows],
+                              np.asarray(b)[ref.node_rows]), F32_TOL)
+    misses.raise_any()
 
-    @jax.jit
-    def forward(params, store):
-        return loss_fn(params, store, x_pad, y_pad, m_pad, batch, exchange)
-
-    @jax.jit
-    def superstep(params, opt, store):
-        (loss, (store, acc, _)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, store, x_pad, y_pad, m_pad,
-                                   batch, exchange)
-        grads, _ = clip_by_global_norm(grads, 2.0)
-        params, opt = adamw_update(grads, opt, params, lr=0.01, b1=0.9,
-                                   b2=0.999, weight_decay=5e-4)
-        return params, opt, store, loss
-
-    with mesh:
-        errs, fwd_s = [], []
-        for _ in range(spec.num_layers):
-            with jax.default_matmul_precision(COMPARE_PRECISION):
-                (_, (store, _, logits)), s = timed(forward, params, store)
-            fwd_s.append(s)
-            out = np.asarray(logits)
-            got = np.zeros_like(exact)
-            got[structs.old_of_new[valid]] = out[valid]
-            errs.append(float(np.abs(got - exact).max()))
-        log(f"dist: fixed-parameter superstep at {COMPARE_PRECISION} "
-            f"matmul precision, max |logits - full_forward| "
-            f"{['%.3e' % e for e in errs]} (max |full_forward| "
-            f"{np.abs(exact).max():.3f}); superstep s "
-            f"{['%.4f' % s for s in fwd_s]} (the first two compile: the "
-            f"store comes back sharded); "
-            f"full_forward {ff_s:.3f} s")
-        check(errs[-1] < DIST_TOL and errs[0] > errs[-1],
-              f"dist: supersteps did not reach full_forward within "
-              f"{DIST_TOL:g}: {errs}")
-
-        opt, losses, step_s = adamw_init(params), [], []
-        for _ in range(6 if rehearse else 10):
-            (params, opt, store, loss), s = timed(superstep, params, opt,
-                                                  store)
-            step_s.append(s)
-            losses.append(float(loss))
-    log(f"dist: training superstep loss {['%.5f' % v for v in losses]}; "
-        f"s {['%.4f' % s for s in step_s]} (the first two compile)")
+    losses, epoch_s = [m["loss"]], []
+    for e in range(1, 3 if rehearse else 6):
+        (state, m), s = timed(R.train_epoch, plan, state, e)
+        losses.append(m["loss"])
+        epoch_s.append(s)
+    log(f"dist: epoch loss {['%.5f' % v for v in losses]}; s "
+        f"{['%.4f' % s for s in epoch_s]} (the first compiles at the "
+        "default precision)")
     check(all(np.isfinite(losses)) and losses[-1] < losses[0],
           f"dist: training loss did not fall: {losses}")
 

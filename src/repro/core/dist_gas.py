@@ -1,353 +1,329 @@
-"""Distributed GAS: partition-parallel training under `shard_map`.
+"""Sharded GAS: the runtime's epoch on R chips under `shard_map`.
 
-The paper names "the fusion of GAS into a distributed training algorithm"
-as future work (§7); this module implements it JAX-natively:
+`GASConfig(ranks=R)` puts this module on the normal path
+(`runtime.build_plan` -> `init_state` -> `train_epoch`):
 
- - P ranks on the mesh's `data` axis; METIS-like cluster r lives on rank r.
-   Nodes are re-indexed into a padded id space (new_id = rank*rows + slot)
-   so every rank owns a contiguous, equally-sized row block — the paper's
-   "contiguous memory transfers" taken to its distributed conclusion.
- - Histories are row-sharded: rank r stores H̄[rank block]. Pushes are
-   always LOCAL (a rank only updates embeddings of its own cluster).
- - Pulls need remote rows: a static halo exchange — (P-1) rounds of
-   `lax.ppermute`, each round sending exactly the rows the peer statically
-   needs. XLA schedules these collectives alongside layer compute (the
-   distributed analogue of PyGAS's concurrent CUDA-stream transfers).
-   Quantized stores exchange RAW int8 rows + per-row scales and
-   dequantize at the receiver — no f32 halo on the wire, and pushes
-   re-quantize locally (`history.quantize_rows`).
- - One superstep = every rank processes its cluster concurrently; the loss
-   is `psum`-averaged and grads flow through `shard_map` AD. Halo rows are
-   one superstep stale — the "one-shot" regime of Cong et al. (2020),
-   error-bounded by Theorem 2.
+ - The P parts go to ranks P/R each, contiguous by part id: rank r holds
+   parts r*S .. r*S+S-1, S = P/R, and an epoch is S supersteps.
+ - Each rank owns a contiguous block of history rows: node n of rank r is
+   row `r * (rows + 1) + slot` of every table, `rows` the largest rank's
+   node count, and each shard ends in its own sentinel row (the kernel
+   push scatters padding into it). Tables, their int8 scales and the age
+   clock are row-sharded over the mesh; parameters, Adam's state and the
+   features are replicated.
+ - The block stacks are the one-chip `gas.build_batches` stacks of all P
+   parts, regrouped [R, S, ...]: every rank runs the same
+   `gnn.model.gas_batch_forward` as one chip, so the fused
+   `gather_spmm`, the transposed `bcsr_spmm` backward and the
+   `scatter_rows` push run unchanged inside `shard_map`.
+ - In superstep s every rank takes its own next part, in the order the
+   epoch's permutation of all P parts lists them (`rank_orders`). Before
+   the step each rank builds each hidden layer's halo view: its own rows
+   through the `gather_rows` kernel, every other rank's rows through one
+   `ppermute` per peer, in storage precision (int8 rows travel with
+   their f32 scales). The view feeds `gas_batch_forward(pulled=...)`, so
+   every read in a superstep sees the tables as they stood at its start.
+ - The loss is psum(cross-entropy) / psum(count) over the superstep's R
+   parts; gradients are summed over ranks and every rank takes the same
+   AdamW step. The pushes then land, each rank into its own shard.
+
+Routing (`DistBatches`, built once on the host): for part j of rank r,
+`send_idx[q, r, j]` lists the rows of q's shard that r's halo needs, in
+halo order, and `src_pos[r, j]` says where each halo slot's row lies in
+the exchange buffer [own rows; the rows from rank r-1; from r-2; ...;
+one zero row].
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Union
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.data.graphs import Graph
-from . import gas as G
+from repro.kernels import ops
+from repro.utils import spans
 from . import history as H
 from .batch import GASBatch
 
-
-@dataclass
-class DistStructs:
-    """Static distributed plan. The per-rank local graph is the SAME typed
-    structure the single-host executor uses — a `GASBatch` stacked over
-    the rank axis (batch r == rank r's cluster: `batch_mask` is the
-    node-slot validity mask, `edge_*` the local padded COO, `halo_*` the
-    remote rows this rank pulls) — so model code consumes one batch type
-    on both paths. Only the halo-exchange routing tables (`send_idx` /
-    `send_mask` / `recv_pos`) are dist-specific."""
-    num_ranks: int
-    rows: int                      # row slots per rank
-    sizes: np.ndarray              # [P] real nodes per rank
-    old_of_new: np.ndarray         # [P*rows] padded new id -> old id (or -1)
-    new_of_old: np.ndarray         # [N] old id -> padded new id
-    max_halo: int
-    max_edges: int
-    batch: GASBatch                # stacked over ranks (numpy leaves)
-    send_idx: np.ndarray           # [P, P, C] my local slots to send to peer q
-    send_mask: np.ndarray          # [P, P, C]
-    recv_pos: np.ndarray           # [P, P, C] halo slots for rows from peer q
-
-    def device_batch(self) -> GASBatch:
-        return self.batch.device()
-
-    def exchange_arrays(self) -> Dict[str, jnp.ndarray]:
-        return {k: jnp.asarray(getattr(self, k)) for k in
-                ("send_idx", "send_mask", "recv_pos")}
-
-    def init_store(self, dims: List[int], dtype=jnp.float32,
-                   history_dtype: str = None) -> H.HistoryStore:
-        """Row-sharded histories: [P*rows, d] per hidden layer. The dist
-        path pulls via collective halo exchange (not the kernel gather),
-        so the store is bound to the jnp backend; `history_dtype`
-        resolves arg > $REPRO_HISTORY_DTYPE > "f32" like the single-host
-        store, and int8 stores carry per-row scale shards that
-        `halo_exchange` ppermutes alongside the raw rows (the exchange
-        never materializes an f32 halo on the wire). vq stores are not
-        supported on the dist path (the wire protocol exchanges raw
-        rows + scales only; broadcasting per-layer codebooks across
-        ranks is future work) and raise here. Tables stay
-        device-resident — the host-spill path (`storage="host"`) is a
-        single-host feature."""
-        resolved = H.resolve_history_dtype(history_dtype)
-        if H.get_codec(resolved).vq:
-            raise NotImplementedError(
-                "dist_gas does not support history_dtype='vq': the halo "
-                "exchange wire protocol carries raw rows + per-row "
-                "scales, not codebooks — use f32/bf16/int8 for sharded "
-                "runs")
-        n = self.num_ranks * self.rows
-        return H.HistoryStore.create(
-            n, dims, dtype=dtype, backend="jnp",
-            history_dtype=resolved, storage="device")
+AXIS = "ranks"
 
 
-def build_dist_structs(graph: Graph, part: np.ndarray) -> DistStructs:
-    N = graph.num_nodes
-    P_ = int(part.max()) + 1
-    sizes = np.bincount(part, minlength=P_)
-    rows = int(sizes.max())
-
-    new_of_old = np.empty(N, np.int64)
-    old_of_new = np.full(P_ * rows, -1, np.int64)
-    for r in range(P_):
-        mine = np.flatnonzero(part == r)
-        new_of_old[mine] = r * rows + np.arange(len(mine))
-        old_of_new[r * rows: r * rows + len(mine)] = mine
-
-    dst, src, w = G.gcn_edge_weights(graph)
-    dst_n, src_n = new_of_old[dst], new_of_old[src]
-    owner_d = dst_n // rows
-
-    halos: List[np.ndarray] = []
-    edges = []
-    for r in range(P_):
-        sel = owner_d == r
-        d_r, s_r, w_r = dst_n[sel], src_n[sel], w[sel]
-        remote = s_r[(s_r // rows) != r]
-        halo = np.unique(remote)
-        halos.append(halo)
-        edges.append((d_r, s_r, w_r))
-    max_h = max(max((len(h) for h in halos), default=1), 1)
-    max_e = max(len(e[0]) for e in edges)
-
-    node_mask = np.arange(rows)[None, :] < sizes[:, None]
-    ed = np.full((P_, max_e), rows, np.int32)              # trash row
-    es = np.full((P_, max_e), rows + max_h, np.int32)      # dummy zero row
-    ew = np.zeros((P_, max_e), np.float32)
-    hmask = np.zeros((P_, max_h), bool)
-
-    C = 1
-    plans = []
-    for r in range(P_):
-        halo = halos[r]
-        hmask[r, :len(halo)] = True
-        lookup = np.full(P_ * rows + 1, rows + max_h, np.int64)
-        lookup[r * rows: (r + 1) * rows] = np.arange(rows)
-        lookup[halo] = rows + np.arange(len(halo))
-        d_r, s_r, w_r = edges[r]
-        ed[r, :len(d_r)] = (d_r - r * rows)
-        es[r, :len(s_r)] = lookup[s_r]
-        ew[r, :len(w_r)] = w_r
-        plan = []
-        for q in range(P_):
-            sel = np.flatnonzero((halo // rows) == q)
-            plan.append((sel, halo[sel] - q * rows))
-            if q != r:
-                C = max(C, len(sel))
-        plans.append(plan)
-
-    send_idx = np.zeros((P_, P_, C), np.int32)
-    send_mask = np.zeros((P_, P_, C), bool)
-    recv_pos = np.zeros((P_, P_, C), np.int32)
-    for r in range(P_):
-        for q in range(P_):
-            if q == r:
-                continue
-            slots, qrows = plans[r][q]
-            send_idx[q, r, :len(qrows)] = qrows
-            send_mask[q, r, :len(qrows)] = True
-            recv_pos[r, q, :len(slots)] = slots
-
-    bnode = np.where(node_mask,
-                     np.arange(rows, dtype=np.int64)[None, :]
-                     + rows * np.arange(P_, dtype=np.int64)[:, None],
-                     P_ * rows).astype(np.int32)
-    hnode = np.full((P_, max_h), P_ * rows, np.int32)
-    for r in range(P_):
-        hnode[r, :len(halos[r])] = halos[r]
-    batch = GASBatch(bnode, node_mask, hnode, hmask, ed, es, ew,
-                     num_batches=P_, max_b=rows, max_h=max_h, max_e=max_e)
-    return DistStructs(num_ranks=P_, rows=rows, sizes=sizes,
-                       old_of_new=old_of_new, new_of_old=new_of_old,
-                       max_halo=max_h, max_edges=max_e, batch=batch,
-                       send_idx=send_idx, send_mask=send_mask,
-                       recv_pos=recv_pos)
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["batch", "slots", "loc_idx", "send_idx", "src_pos"],
+    meta_fields=["ranks", "rows"])
+@dataclass(frozen=True)
+class DistBatches:
+    """Every part's batch and exchange routing, with a leading rank axis
+    on every leaf (the axis the mesh shards)."""
+    batch: GASBatch      # [R, S, ...]: part r*S + j at [r, j], global ids
+    slots: Any           # [R, S, max_b] shard row of each batch node
+    #                      (padding: `rows`, the shard's sentinel)
+    loc_idx: Any         # [R, S, L] shard rows of the halo rows the rank
+    #                      owns itself (padding 0)
+    send_idx: Any        # [R, R, S, C] [q, r, j]: rows of q's shard that
+    #                      r's part j needs from q (padding 0)
+    src_pos: Any         # [R, S, max_h] row of each halo slot in the
+    #                      exchange buffer (padding: its zero row)
+    ranks: int = 1
+    rows: int = 0        # node rows per shard, sentinel excluded
 
 
-def permute_node_array(structs: DistStructs, arr: np.ndarray,
-                       fill=0) -> np.ndarray:
-    """old-id array [N, ...] -> padded new-id layout [P*rows, ...]."""
-    out = np.full((structs.num_ranks * structs.rows,) + arr.shape[1:], fill,
-                  arr.dtype)
-    valid = structs.old_of_new >= 0
-    out[valid] = arr[structs.old_of_new[valid]]
-    return out
+def check_config(config, history_dtype: str, history_storage: str) -> None:
+    """Refuses what the sharded path has no form of."""
+    R = config.ranks
+    if config.num_parts % R:
+        raise ValueError(f"num_parts ({config.num_parts}) must be a "
+                         f"multiple of ranks ({R})")
+    if H.get_codec(history_dtype).vq:
+        raise NotImplementedError(
+            "ranks > 1 does not support history_dtype='vq': the halo "
+            "exchange carries rows and per-row scales, not codebooks")
+    unsupported = {
+        "clusters_per_batch > 1": config.clusters_per_batch > 1,
+        "prefetch_depth > 0": config.prefetch_depth > 0,
+        "history_storage='host'": history_storage != "device",
+        "halo_age_decay": bool(config.halo_age_decay),
+    }
+    bad = [k for k, v in unsupported.items() if v]
+    if bad:
+        raise NotImplementedError(f"ranks > 1 does not support {bad}")
 
 
-def halo_exchange(table_loc: jnp.ndarray, plan: Dict[str, jnp.ndarray],
-                  max_halo: int, axis: str = "data",
-                  scales_loc: jnp.ndarray = None):
-    """Inside shard_map: [rows, d] local history shard -> [max_halo, d]
-    halo rows pulled from their owners via (P-1) static ppermute rounds.
-
-    Rows travel in RAW storage precision: an int8 shard ppermutes int8
-    rows, and its per-row scale shard (`scales_loc`, [rows] f32) rides
-    along as a second ppermute per round, so only int8 bytes + one f32
-    scalar per row cross the interconnect — never a dequantized f32
-    halo. With `scales_loc` the return is the `(halo_rows, halo_scales)`
-    pair; the caller dequantizes at the receiver
-    (`rows.astype(f32) * scales[:, None]`), which is bitwise the
-    single-host `dequantize_rows` of the same table rows."""
-    # static rank count (jax.lax.axis_size is jax >= 0.5; the per-peer
-    # send table is [P, C], so its leading dim is the portable source)
-    P_ = plan["send_idx"].shape[0]
-    me = jax.lax.axis_index(axis)
-    halo = jnp.zeros((max_halo, table_loc.shape[-1]), table_loc.dtype)
-    hscl = (None if scales_loc is None
-            else jnp.zeros((max_halo,), scales_loc.dtype))
-    for shift in range(1, P_):
-        to = (me + shift) % P_
-        frm = (me - shift) % P_
-        perm = [(r, (r + shift) % P_) for r in range(P_)]
-        payload = jnp.take(plan["send_idx"], to, axis=0)        # [C]
-        mask = jnp.take(plan["send_mask"], to, axis=0)
-        # mask via where, not multiply: keeps int8 rows int8 on the wire
-        rows = jnp.where(mask[:, None],
-                         jnp.take(table_loc, payload, axis=0), 0)
-        got = jax.lax.ppermute(rows, axis, perm=perm)
-        pos = jnp.take(plan["recv_pos"], frm, axis=0)
-        halo = halo.at[pos].add(got)
-        if scales_loc is not None:
-            srows = jnp.where(mask, jnp.take(scales_loc, payload), 0)
-            hscl = hscl.at[pos].add(
-                jax.lax.ppermute(srows, axis, perm=perm))
-    return halo if scales_loc is None else (halo, hscl)
+def make_mesh(ranks: int, devices=None) -> Mesh:
+    """A one-axis mesh of the first `ranks` devices (or of `devices`)."""
+    devs = list(jax.devices() if devices is None else devices)
+    if len(devs) < ranks:
+        raise ValueError(f"ranks={ranks} needs {ranks} devices, "
+                         f"found {len(devs)}")
+    return Mesh(np.array(devs[:ranks]), (AXIS,))
 
 
-def make_dist_loss_fn(spec, structs: DistStructs, mesh,
-                      axis: str = "data") -> Callable:
-    """Builds loss(params, store, x_pad, y_pad, mask_pad, batch, exchange)
-    where `store` is a `core.history.HistoryStore` (row-sharded tables),
-    `batch` the rank-stacked `GASBatch` (`structs.device_batch()`) and
-    `exchange` the ppermute routing dict (`structs.exchange_arrays()`);
-    everything node-indexed is sharded over `axis` and params are
-    replicated. Returns (loss, (new_store, acc, logits)) — the same
-    typed history/batch surface as the single-host runtime."""
-    from repro.gnn.model import _post, _pre, _prop
-
-    rows, max_h = structs.rows, structs.max_halo
-    num_layers = spec.num_layers
-
-    def make_shard_body(quantized: bool):
-        def shard_body(params, tables, scales, x_loc, y_loc, m_loc, batch,
-                       plan):
-            # batch/plan leaves arrive with a leading local rank axis of
-            # size 1
-            batch = jax.tree_util.tree_map(lambda a: a[0], batch)
-            plan = jax.tree_util.tree_map(lambda a: a[0], plan)
-            node_mask = batch.batch_mask
-            edges = (batch.edge_dst.astype(jnp.int32),
-                     batch.edge_src.astype(jnp.int32))
-            edge_w = batch.edge_w
-
-            hb = _pre(params, spec, x_loc) * node_mask[:, None]
-            # exact layer-0 halo: exchange *input features* transformed by
-            # pre (per-node, exact — no staleness at layer 0, per Thm. 2)
-            hh0 = halo_exchange(hb, plan, max_h, axis)
-            hh0 = hh0 * batch.halo_mask[:, None]
-            ctx = {"h0": hb}
-
-            new_tables, new_scales = [], []
-            x_cur = hb
-            for ell in range(num_layers):
-                if ell == 0:
-                    halo_rows = hh0
-                else:
-                    if quantized:
-                        # raw int8 rows + scales on the wire; dequantize
-                        # at the receiver (bitwise `dequantize_rows`)
-                        hraw, hscl = halo_exchange(
-                            tables[ell - 1], plan, max_h, axis,
-                            scales_loc=scales[ell - 1])
-                        halo_rows = hraw.astype(jnp.float32) * hscl[:, None]
+def build_dist(part: np.ndarray, batches: GASBatch, ranks: int
+               ) -> Tuple[DistBatches, np.ndarray, Dict[str, int]]:
+    """Host routing for `batches` (`gas.build_batches` of all parts):
+    (DistBatches with numpy leaves, each node's row in the sharded tables
+    [N], per-epoch row counts of one hidden layer: `remote` halo rows
+    that cross chips and `local` ones read from the rank's own shard).
+    Timed as span `gas/dist/plan`, the routing as `gas/dist/plan/routing`.
+    """
+    with spans.span("gas/dist/plan"):
+        Pn, N = batches.num_batches, len(part)
+        S = Pn // ranks
+        # a rank's nodes in part order, then node id
+        owner = (np.asarray(part) // S).astype(np.int64)
+        order = np.lexsort((np.arange(N), part))
+        counts = np.bincount(owner, minlength=ranks)
+        starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
+        slot = np.empty(N, np.int64)
+        slot[order] = np.arange(N) - starts[owner[order]]
+        rows = int(counts.max())
+        node_rows = owner * (rows + 1) + slot
+        bn, bm = batches.batch_nodes, batches.batch_mask
+        slots = np.where(bm, slot[np.minimum(bn, N - 1)], rows)
+        with spans.span("gas/dist/plan/routing"):
+            hn, hm = batches.halo_nodes, batches.halo_mask
+            own = [[None] * ranks for _ in range(Pn)]  # [p][q]: halo slots
+            for p in range(Pn):
+                valid = np.flatnonzero(hm[p])
+                q_of = owner[hn[p, valid]]
+                for q in range(ranks):
+                    own[p][q] = valid[q_of == q]
+            L = max(1, max(len(own[p][p // S]) for p in range(Pn)))
+            C = max([1] + [len(own[p][q]) for p in range(Pn)
+                           for q in range(ranks) if q != p // S])
+            max_h = hn.shape[1]
+            zero = L + (ranks - 1) * C
+            loc_idx = np.zeros((Pn, L), np.int32)
+            send_idx = np.zeros((ranks, ranks, S, C), np.int32)
+            src_pos = np.full((Pn, max_h), zero, np.int32)
+            n_remote = n_local = 0
+            for p in range(Pn):
+                r, j = divmod(p, S)
+                for q in range(ranks):
+                    hs = own[p][q]
+                    rows_q = slot[hn[p, hs]]
+                    if q == r:
+                        loc_idx[p, :len(hs)] = rows_q
+                        src_pos[p, hs] = np.arange(len(hs))
+                        n_local += len(hs)
                     else:
-                        halo_rows = halo_exchange(tables[ell - 1], plan,
-                                                  max_h, axis)
-                        halo_rows = halo_rows.astype(jnp.float32)
-                    halo_rows = halo_rows * batch.halo_mask[:, None]
-                dummy = jnp.zeros((1, x_cur.shape[-1]), x_cur.dtype)
-                x_all = jnp.concatenate([x_cur, halo_rows, dummy], axis=0)
-                x_next = _prop(params, spec, ell, x_all, edges, edge_w,
-                               rows, ctx)
-                if ell < num_layers - 1:
-                    fresh = (jax.lax.stop_gradient(x_next)
-                             * node_mask[:, None])
-                    if quantized:
-                        q, s = H.quantize_rows(fresh)
-                        new_tables.append(q)
-                        new_scales.append(s)
-                    else:
-                        new_tables.append(
-                            fresh.astype(tables[ell].dtype))
-                x_cur = x_next
+                        k = (r - q) % ranks
+                        send_idx[q, r, j, :len(hs)] = rows_q
+                        src_pos[p, hs] = L + (k - 1) * C + np.arange(len(hs))
+                        n_remote += len(hs)
 
-            logits = _post(params, spec, x_cur)
-            m = m_loc & node_mask
-            logz = jax.scipy.special.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(logits, y_loc[:, None], axis=-1)[:, 0]
-            ce_sum = jnp.sum((logz - gold) * m)
-            cnt = jnp.sum(m)
-            correct = jnp.sum((jnp.argmax(logits, -1) == y_loc) & m)
-            ce_sum, cnt, correct = (jax.lax.psum(v, axis)
-                                    for v in (ce_sum, cnt, correct))
-            loss = ce_sum / jnp.maximum(cnt, 1)
-            acc = correct / jnp.maximum(cnt, 1)
-            return loss, acc, new_tables, new_scales, logits
+    def ranked(a):
+        return a.reshape((ranks, S) + a.shape[1:])
 
-        return shard_body
+    stacked = jax.tree_util.tree_map(ranked, batches)
+    dist = DistBatches(batch=stacked, slots=ranked(slots.astype(np.int32)),
+                       loc_idx=ranked(loc_idx), send_idx=send_idx,
+                       src_pos=ranked(src_pos), ranks=ranks, rows=rows)
+    return dist, node_rows, {"remote": n_remote, "local": n_local}
 
-    batch_specs = jax.tree_util.tree_map(lambda _: P(axis), structs.batch)
-    plan_specs = {k: P(axis) for k in ("send_idx", "send_mask", "recv_pos")}
-    smapped_cache = {}
 
-    def get_smapped(quantized: bool):
-        # two traced variants (the scales operand list is [] for
-        # non-int8 stores, so the pytree structure is static per flag)
-        if quantized not in smapped_cache:
-            nscl = (num_layers - 1) if quantized else 0
-            smapped_cache[quantized] = jax.shard_map(
-                make_shard_body(quantized), mesh=mesh,
-                in_specs=(P(), [P(axis)] * (num_layers - 1),
-                          [P(axis)] * nscl, P(axis), P(axis),
-                          P(axis), batch_specs, plan_specs),
-                out_specs=(P(), P(), [P(axis)] * (num_layers - 1),
-                           [P(axis)] * nscl, P(axis)),
-                check_vma=False)
-        return smapped_cache[quantized]
+def count_exchange(counts: Dict[str, int], dims, history_dtype: str,
+                   supersteps: int) -> None:
+    """The plan's counters: halo rows an epoch over every hidden layer,
+    the bytes of the remote ones as stored (int8 scales included)."""
+    codec = H.get_codec(history_dtype)
+    item = np.dtype(codec.storage).itemsize
+    row_bytes = [d * item + (4 if codec.scaled else 0) for d in dims]
+    spans.count("gas/dist/exchange_rows", counts["remote"] * len(dims))
+    spans.count("gas/dist/exchange_bytes", counts["remote"] * sum(row_bytes))
+    spans.count("gas/dist/local_halo_rows", counts["local"] * len(dims))
+    spans.count("gas/dist/supersteps", supersteps)
 
-    def loss_fn(params, store: Union[H.HistoryStore, List], x_pad, y_pad,
-                m_pad, batch: GASBatch, exchange: Dict):
-        legacy = not isinstance(store, H.HistoryStore)
-        if not legacy and H.get_codec(store.history_dtype).vq:
-            raise NotImplementedError(
-                "dist_gas does not support history_dtype='vq' (no "
-                "codebook exchange on the wire) — use f32/bf16/int8")
-        tables = list(store) if legacy else list(store.tables)
-        quantized = (not legacy) and store.scales is not None
-        scales = list(store.scales) if quantized else []
-        loss, acc, new_tables, new_scales, logits = get_smapped(quantized)(
-            params, tables, scales, x_pad, y_pad, m_pad, batch, exchange)
-        if legacy:
-            return loss, (new_tables, acc, logits)
-        # every rank pushes all of its rows each superstep, so the whole
-        # clock resets: histories are exactly one superstep stale
-        new_store = H.HistoryStore(
-            tables=tuple(new_tables),
-            age=jnp.zeros_like(store.age),
-            scales=tuple(new_scales) if quantized else None,
-            backend=store.backend, history_dtype=store.history_dtype,
-            storage=store.storage)
-        return loss, (new_store, acc, logits)
 
-    return loss_fn
+def rank_orders(order, ranks: int):
+    """[R, S] part of each rank (index within the rank) in each superstep:
+    a rank's parts in the order the epoch's permutation of all parts
+    lists them. Works on numpy and jax arrays alike."""
+    xp = jnp if isinstance(order, jax.Array) else np
+    S = order.shape[0] // ranks
+    idx = xp.argsort(order // S, stable=True)
+    return (order[idx] % S).reshape(ranks, S)
+
+
+def replicated(mesh: Mesh) -> NamedSharding:
+    return NamedSharding(mesh, P())
+
+
+def sharded(mesh: Mesh) -> NamedSharding:
+    return NamedSharding(mesh, P(AXIS))
+
+
+def init_store(dims, ranks: int, rows: int, mesh: Mesh, backend: str,
+               history_dtype: str) -> H.HistoryStore:
+    """Zeroed history tables [R * (rows + 1), d], row-sharded over
+    `mesh`: each shard holds one rank's rows and its own sentinel."""
+    store = H.HistoryStore.create(ranks * (rows + 1), dims, backend=backend,
+                                  history_dtype=history_dtype,
+                                  storage="device")
+    return jax.device_put(store, sharded(mesh))
+
+
+def _rows(table, idx, backend: str):
+    """Raw rows table[idx] in storage precision: the `gather_rows` kernel
+    for float tables on the kernel backends."""
+    if backend == "jnp" or table.dtype != jnp.float32:
+        return jnp.take(table, idx, axis=0)
+    return ops.pull_rows(table, idx, backend=backend)
+
+
+def halo_view(table, scales, dist: DistBatches, me, per_rank, s, j,
+              backend: str):
+    """Inside `shard_map`: the halo rows of this rank's part `j` from the
+    layer's table as it stands, in storage precision: (rows [max_h, d],
+    scales [max_h] or None), what `HistoryStore.with_pulled` takes. Own
+    rows are gathered from the local shard; each other rank sends the
+    rows it owns, found by the receiver's part in this superstep."""
+    R = dist.ranks
+    bufs = [_rows(table, dist.loc_idx[j], backend)]
+    sbufs = [] if scales is None else [jnp.take(scales, dist.loc_idx[j])]
+    for k in range(1, R):
+        to = (me + k) % R
+        idx = dist.send_idx[to, per_rank[to, s]]
+        perm = [(q, (q + k) % R) for q in range(R)]
+        bufs.append(jax.lax.ppermute(_rows(table, idx, backend), AXIS, perm))
+        if scales is not None:
+            sbufs.append(jax.lax.ppermute(jnp.take(scales, idx), AXIS, perm))
+    bufs.append(jnp.zeros((1, table.shape[1]), table.dtype))
+    pos = dist.src_pos[j]
+    view = jnp.take(jnp.concatenate(bufs), pos, axis=0)
+    if scales is None:
+        return view, None
+    sbufs.append(jnp.ones((1,), scales.dtype))
+    return view, jnp.take(jnp.concatenate(sbufs), pos)
+
+
+def make_epoch_fn(spec, config, backend: str, mesh: Mesh):
+    """The un-jitted sharded epoch `(state, dist, order, x, y, train_mask)
+    -> (state, per-superstep metrics)`: one `shard_map` around one
+    `lax.scan` of S supersteps. `order` is the epoch's permutation of
+    all parts (as the one-chip epoch takes it); `dist` the device
+    `DistBatches`; x, y and train_mask are in node order, replicated."""
+    from repro.gnn.model import gas_batch_forward
+    from repro.train.optimizer import adamw_update, clip_by_global_norm
+    from .runtime import GASState
+
+    R = mesh.shape[AXIS]
+
+    def body(state, dist, per_rank, x, y, train_mask):
+        dist = jax.tree_util.tree_map(lambda a: a[0], dist)
+        me = jax.lax.axis_index(AXIS)
+        S = per_rank.shape[1]
+
+        def superstep(st, s):
+            j = per_rank[me, s]
+            batch = jax.tree_util.tree_map(lambda a: a[j], dist.batch)
+            slots = dist.slots[j]
+            bmask = batch.batch_mask
+            max_b, max_h = bmask.shape[0], batch.halo_mask.shape[0]
+            store = st.histories
+            pulled = tuple(
+                halo_view(store.tables[ell], store.layer_scales(ell), dist,
+                          me, per_rank, s, j, backend)
+                for ell in range(store.num_layers))
+            # layer 0 reads the replicated features of the batch and halo
+            ids = jnp.concatenate([batch.batch_nodes, batch.halo_nodes])
+            x_view = jnp.take(x, ids, axis=0, mode="clip")
+            local = batch.replace(
+                batch_nodes=jnp.arange(max_b, dtype=jnp.int32),
+                halo_nodes=max_b + jnp.arange(max_h, dtype=jnp.int32))
+            labels = jnp.take(y, batch.batch_nodes, mode="clip")
+            m = jnp.take(train_mask, batch.batch_nodes, mode="clip") & bmask
+            count = jax.lax.psum(jnp.sum(m), AXIS)
+            denom = jnp.maximum(count, 1)
+            rng, sub = jax.random.split(st.rng)
+
+            def loss_fn(p):
+                logits, _, reg, diags, pushed = gas_batch_forward(
+                    p, spec, x_view, local, store,
+                    use_history=config.use_history, rng=sub,
+                    backend=backend, fuse_halo=config.fuse_halo,
+                    pulled=pulled, return_pushed=True, apply_pushes=False)
+                logz = jax.scipy.special.logsumexp(logits, axis=-1)
+                gold = jnp.take_along_axis(logits, labels[:, None],
+                                           axis=-1)[:, 0]
+                ce = jnp.sum((logz - gold) * m)
+                ok = jnp.sum((jnp.argmax(logits, -1) == labels) & m)
+                loss = ce / denom + spec.reg_weight * reg / R
+                return loss, (pushed, ce, ok, reg, diags["hist_quant_err"])
+
+            (loss, (pushed, ce, ok, reg, qerr)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(st.params)
+            grads = jax.lax.psum(grads, AXIS)
+            grads, _gn = clip_by_global_norm(grads, config.grad_clip)
+            params, opt_state = adamw_update(
+                grads, st.opt_state, st.params, lr=config.lr, b1=0.9,
+                b2=0.999, weight_decay=config.weight_decay)
+            for ell, rows in enumerate(pushed):
+                store = store.push(ell, slots, rows, bmask)
+            store = store.tick(slots, bmask)
+            metrics = {"loss": jax.lax.psum(loss, AXIS),
+                       "ce": jax.lax.psum(ce, AXIS) / denom,
+                       "acc": jax.lax.psum(ok, AXIS) / denom,
+                       "reg": jax.lax.pmean(reg, AXIS),
+                       "hist_quant_err": jax.lax.pmean(qerr, AXIS)}
+            return GASState(params=params, opt_state=opt_state,
+                            histories=store, rng=rng), metrics
+
+        return jax.lax.scan(superstep, state, jnp.arange(S))
+
+    state_spec = GASState(params=P(), opt_state=P(), histories=P(AXIS),
+                          rng=P())
+    mapped = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(state_spec, P(AXIS), P(), P(), P(), P()),
+        out_specs=(state_spec, P()), check_vma=False)
+
+    def epoch_fn(state, dist, order, x, y, train_mask):
+        return mapped(state, dist, rank_orders(order, R), x, y, train_mask)
+
+    return epoch_fn

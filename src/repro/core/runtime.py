@@ -43,6 +43,7 @@ import numpy as np
 from repro.data.graphs import Graph
 from repro.kernels import ops
 from repro.utils import spans
+from . import dist_gas
 from . import gas as G
 from . import history as H
 from .batch import GASBatch
@@ -78,7 +79,14 @@ class GASConfig(HistoryExecConfig):
     dispatch and its use — see `history.HistoryStore.patch_pulled`).
     `history_storage="host"` pins the history tables in host RAM
     (`history.resolve_history_storage`), scaling table capacity with CPU
-    RAM instead of HBM."""
+    RAM instead of HBM.
+
+    `ranks=R > 1` shards training over R devices (`core.dist_gas`):
+    the parts go to ranks P/R each, contiguous by part id, the history
+    tables are row-sharded over a mesh of the plan's devices, and an
+    epoch is one jitted `shard_map` of P/R supersteps in which every rank
+    trains one of its parts on the same kernels as one chip. `ranks=1`
+    is the one-chip path."""
     num_parts: int
     partitioner: str = "metis"          # "metis" | "random"
     clusters_per_batch: int = 1
@@ -97,6 +105,7 @@ class GASConfig(HistoryExecConfig):
     halo_age_decay: float = 0.0
     prefetch_depth: int = 0              # 0 = synchronous epochs
     history_storage: Optional[str] = None  # "device" | "host"
+    ranks: int = 1                       # chips the epoch is sharded over
     lr: float = 0.01
     weight_decay: float = 5e-4
     grad_clip: float = 2.0
@@ -142,6 +151,9 @@ class GASPlan:
     eval_w: jnp.ndarray
     build_blocks: bool
     unit_blocks: bool
+    mesh: Any = None                     # ranks > 1: the plan's devices
+    node_rows: Optional[np.ndarray] = None  # ranks > 1: row of each node
+    #                                      in the sharded history tables
     _pad_to: Optional[Tuple[int, int, int]] = None
     _pad_k: int = 1
     _pad_k_t: int = 1
@@ -168,25 +180,32 @@ def _accuracy(logits, labels, mask):
 # ---------------------------------------------------------------------------
 
 def build_plan(graph: Graph, spec, config: GASConfig,
-               part: Optional[np.ndarray] = None) -> GASPlan:
+               part: Optional[np.ndarray] = None,
+               devices=None) -> GASPlan:
     """Partition the graph, build (stack, upload) the typed batch
     structures, resolve the kernel backend — everything static. `part`
     (e.g. another plan's `part` over the same graph) skips partitioning,
-    the slow host-side step of plan construction. Timed as span
-    `gas/plan`, with `gas/plan/partition` and `gas/plan/device_put`
-    inside (`utils.spans`); the bytes handed to the device count into
+    the slow host-side step of plan construction. With `config.ranks >
+    1` the plan's mesh is the first `ranks` of `devices` (default
+    `jax.devices()`), and the routing of the halo exchange is built too
+    (`dist_gas.build_dist`). Timed as span `gas/plan`, with
+    `gas/plan/partition` and `gas/plan/device_put` (sharded plans:
+    `gas/dist/plan` and `gas/dist/plan/device_put`) inside
+    (`utils.spans`); the bytes handed to the device count into
     `gas/plan/upload_bytes`."""
     with spans.span("gas/plan"):
-        return _build_plan(graph, spec, config, part)
+        return _build_plan(graph, spec, config, part, devices)
 
 
 def _build_plan(graph: Graph, spec, config: GASConfig,
-                part: Optional[np.ndarray]) -> GASPlan:
+                part: Optional[np.ndarray], devices=None) -> GASPlan:
     from repro.gnn.model import BLOCK_OPS, UNIT_BLOCK_OPS
 
     backend = ops.resolve_backend(config.backend)
     history_dtype = H.resolve_history_dtype(config.history_dtype)
     history_storage = H.resolve_history_storage(config.history_storage)
+    if config.ranks > 1:
+        dist_gas.check_config(config, history_dtype, history_storage)
     build_blocks = spec.op in BLOCK_OPS and backend != "jnp"
     unit_blocks = build_blocks and spec.op in UNIT_BLOCK_OPS
     N = graph.num_nodes
@@ -228,6 +247,11 @@ def _build_plan(graph: Graph, spec, config: GASConfig,
                                        build_blocks=build_blocks,
                                        unit_weights=unit_blocks)
     dst, src, w = G.gcn_edge_weights(graph)   # exact full-propagation eval
+    if config.ranks > 1:
+        _place_sharded(plan, devices)
+        plan.eval_edges = (jnp.asarray(dst), jnp.asarray(src))
+        plan.eval_w = jnp.asarray(w)
+        return plan
 
     with spans.span("gas/plan/device_put"):
         plan.batch_stack = plan.batches.device()
@@ -243,6 +267,30 @@ def _build_plan(graph: Graph, spec, config: GASConfig,
             (plan.batch_stack, plan.x, plan.y, plan.train_mask,
              plan.eval_edges, plan.eval_w))))
     return plan
+
+
+def _place_sharded(plan: GASPlan, devices) -> None:
+    """A sharded plan's routing, mesh and upload: the batches and routing
+    sharded by rank, features, labels and masks replicated."""
+    R = plan.config.ranks
+    graph = plan.graph
+    host, plan.node_rows, counts = dist_gas.build_dist(plan.part,
+                                                       plan.batches, R)
+    dist_gas.count_exchange(counts, plan.spec.hist_dims(),
+                            plan.history_dtype, host.slots.shape[1])
+    plan.mesh = dist_gas.make_mesh(R, devices)
+    rep = dist_gas.replicated(plan.mesh)
+    with spans.span("gas/dist/plan/device_put"):
+        plan.batch_stack = jax.device_put(host,
+                                          dist_gas.sharded(plan.mesh))
+        plan.x = jax.device_put(graph.x, rep)
+        plan.y = jax.device_put(
+            np.concatenate([graph.y, [0]]).astype(np.int32), rep)
+        plan.train_mask = jax.device_put(
+            np.concatenate([graph.train_mask, [False]]), rep)
+    spans.count("gas/plan/upload_bytes", sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(
+            (plan.batch_stack, plan.x, plan.y, plan.train_mask))))
 
 
 def _regroup(plan: GASPlan) -> None:
@@ -271,6 +319,15 @@ def init_state(plan: GASPlan) -> GASState:
 
     cfg = plan.config
     params = init_gnn(jax.random.key(cfg.seed), plan.spec)
+    if plan.mesh is not None:
+        dist = plan.batch_stack
+        state = GASState(
+            params=params, opt_state=adamw_init(params),
+            histories=None, rng=jax.random.key(cfg.seed + 1))
+        state = jax.device_put(state, dist_gas.replicated(plan.mesh))
+        return state.replace(histories=dist_gas.init_store(
+            plan.spec.hist_dims(), dist.ranks, dist.rows, plan.mesh,
+            plan.backend, plan.history_dtype))
     return GASState(
         params=params,
         opt_state=adamw_init(params),
@@ -397,6 +454,13 @@ def _resolved_depth(plan: GASPlan) -> int:
     return max(0, min(plan.config.prefetch_depth, nb - 1))
 
 
+def _one_chip(plan: GASPlan, what: str) -> None:
+    if plan.mesh is not None:
+        raise NotImplementedError(
+            f"{what} runs on one chip; a plan with ranks > 1 trains by "
+            "train_epoch and evaluates by evaluate_exact")
+
+
 def _jitted_step(plan: GASPlan) -> Callable:
     if plan._step is None:
         # donate the whole state: history tables and optimizer moments are
@@ -409,6 +473,7 @@ def train_step(plan: GASPlan, state: GASState,
                batch: GASBatch) -> Tuple[GASState, Dict[str, jnp.ndarray]]:
     """One jitted optimization step on one cluster batch. `state` is
     donated — keep only the returned state."""
+    _one_chip(plan, "train_step")
     return _jitted_step(plan)(state, batch, plan.x, plan.y, plan.train_mask)
 
 
@@ -425,6 +490,10 @@ def train_epoch(plan: GASPlan, state: GASState, epoch: int
     compute, the paper's §5 concurrent execution at the epoch level.
     Bit-identical to the synchronous schedule (state, metrics, and
     checkpoint round-trips), fused or not.
+
+    With `config.ranks > 1` the epoch is the sharded one
+    (`dist_gas.make_epoch_fn`): one jitted call of P/R supersteps, whose
+    metrics hold one value per superstep.
 
     Timed as span `gas/epoch` (`utils.spans`), in which
     `gas/epoch/regroup` rebuilds the batches, `gas/epoch/dispatch` draws
@@ -465,6 +534,13 @@ def _dispatch_epoch(plan: GASPlan, state: GASState, order: np.ndarray
     """Dispatches one epoch's steps in `order`; returns the state and
     each metric's per-batch values (device arrays, not waited for)."""
     cfg = plan.config
+    if plan.mesh is not None:
+        if plan._epoch is None:
+            plan._epoch = jax.jit(
+                dist_gas.make_epoch_fn(plan.spec, cfg, plan.backend,
+                                       plan.mesh), donate_argnums=(0,))
+        return plan._epoch(state, plan.batch_stack, jnp.asarray(order),
+                           plan.x, plan.y, plan.train_mask)
     depth = _resolved_depth(plan)
     if cfg.fused_epoch:
         if plan._epoch is None:
@@ -560,6 +636,7 @@ def predict(plan: GASPlan, state: GASState) -> jnp.ndarray:
     jitted dispatch, `lax.scan` over the stacked batches. Histories are
     NOT donated — `state` stays valid for further training."""
     from repro.gnn.model import gas_batch_forward
+    _one_chip(plan, "predict")
 
     if plan._predict is None:
         spec, cfg, backend = plan.spec, plan.config, plan.backend

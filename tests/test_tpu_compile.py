@@ -12,6 +12,11 @@ largest, and for a refresh whose halo outgrows the panel, which takes
 the per-block path. The vq form of `fused.gather_spmm` is left out: it
 does not compile, and `ops.gas_aggregate` refuses it on `pallas`.
 
+The sharded epoch of `GASConfig(ranks=4)` (`dist_gas.make_epoch_fn`:
+`shard_map`, halo exchange by `ppermute`, the kernels inside) is compiled
+whole for the four chips of the described host, at the cell's widths on
+a small graph, with f32 and int8 histories.
+
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every xdist worker imports this
 file."""
@@ -36,14 +41,18 @@ f32, i32, i8, u8 = jnp.float32, jnp.int32, jnp.int8, jnp.uint8
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -174,3 +183,61 @@ def test_gather_spmm_staging_path(name, width):
                                         s, dt in _cases()[name][1][:5]]
     assert fused.panel_width(x_in, table, vals, halo_nodes,
                              bd=BN) == width
+
+
+def _sharded_epoch_args(topo, history_dtype):
+    """The un-jitted sharded epoch and abstract arguments for it on a mesh
+    of the described host's four chips: GCN 128 -> 256 -> 256 -> 40, a
+    1,000-node graph in 8 parts (2 supersteps)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core import dist_gas, gas, history, runtime
+    from repro.core.partition import random_partition
+    from repro.data.graphs import citation_graph
+    from repro.gnn.model import GNNSpec, init_gnn
+    from repro.train.optimizer import adamw_init
+
+    g = citation_graph(num_nodes=1000, num_features=128, num_classes=40,
+                       seed=1)
+    part = random_partition(g.num_nodes, 8, seed=0)
+    host, _, _ = dist_gas.build_dist(
+        part, gas.build_batches(g, part, build_blocks=True), 4)
+    mesh = Mesh(np.array(topo.devices[:4]), (dist_gas.AXIS,))
+    shard = NamedSharding(mesh, P(dist_gas.AXIS))
+    rep = NamedSharding(mesh, P())
+    spec = GNNSpec("gcn", 128, 256, 40, 3)
+
+    def place(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding), tree)
+
+    def state():
+        params = init_gnn(jax.random.key(0), spec)
+        store = history.HistoryStore.create(
+            4 * (host.rows + 1), spec.hist_dims(), backend="pallas",
+            history_dtype=history_dtype)
+        return runtime.GASState(params, adamw_init(params), store,
+                                jax.random.key(1))
+
+    st = jax.eval_shape(state)
+    st = runtime.GASState(place(st.params, rep), place(st.opt_state, rep),
+                          place(st.histories, shard), place(st.rng, rep))
+    n = g.num_nodes
+    rest = [jax.ShapeDtypeStruct(s, dt, sharding=rep) for s, dt in
+            (((8,), i32), ((n, 128), f32), ((n + 1,), i32),
+             ((n + 1,), jnp.bool_))]
+    cfg = runtime.GASConfig(num_parts=8, ranks=4, backend="pallas",
+                            history_dtype=history_dtype)
+    fn = dist_gas.make_epoch_fn(spec, cfg, "pallas", mesh)
+    return fn, [st, place(host, shard)] + rest
+
+
+@pytest.mark.parametrize("history_dtype", ["f32", "int8"])
+def test_sharded_epoch_compiles_for_four_v5e_chips(history_dtype, topo):
+    fn, args = _sharded_epoch_args(topo, history_dtype)
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text and "all-reduce" in text
