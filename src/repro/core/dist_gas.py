@@ -7,10 +7,10 @@
    parts r*S .. r*S+S-1, S = P/R, and an epoch is S supersteps.
  - Each rank owns a contiguous block of history rows: node n of rank r is
    row `r * (rows + 1) + slot` of every table, `rows` the largest rank's
-   node count, and each shard ends in its own sentinel row (the kernel
-   push scatters padding into it). Tables, their int8 scales and the age
-   clock are row-sharded over the mesh; parameters, Adam's state and the
-   features are replicated.
+   node count, and each shard ends in its own sentinel row (padding
+   rows point at it, and the push keeps real rows off it). Tables, their
+   int8 scales and the age clock are row-sharded over the mesh;
+   parameters, Adam's state and the features are replicated.
  - The block stacks are the one-chip `gas.build_batches` stacks of all P
    parts, regrouped [R, S, ...]: every rank runs the same
    `gnn.model.gas_batch_forward` as one chip, so the fused

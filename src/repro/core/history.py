@@ -230,7 +230,7 @@ def quantize_rows(values: jnp.ndarray
     Symmetric per-row quantization: `s_i = row_scales(v)_i`, `q_i =
     round(v_i / s_i)` clipped to [-127, 127]. Per-element error <=
     s_i / 2. The round/clip half is mirrored in-kernel by
-    `kernels.scatter._q_kernel` (it cannot be shared across the
+    `kernels.scatter._quantize` (it cannot be shared across the
     pallas_call boundary) — keep the two in lockstep."""
     v = values.astype(jnp.float32)
     scales = row_scales(v)
@@ -370,10 +370,10 @@ def quantization_error(values: jnp.ndarray, mask: jnp.ndarray,
 
 class Histories(NamedTuple):
     """GAS executors allocate tables with num_nodes = N + 1: the last row
-    is a masked sentinel that padded indices point at. The kernel push
-    path (`kernels/ops.push_rows(..., scratch_last_row=True)`) relies on
-    that sacrificial row — with an [N, d] table it would silently clobber
-    real rows on the kernel backends. Always `init_histories(N + 1, ...)`
+    is a masked sentinel that padded indices point at. The push
+    (`kernels/ops.push_rows(..., scratch_last_row=True)`) clips valid
+    indices below that row — with an [N, d] table it would silently
+    clobber the last real row. Always `init_histories(N + 1, ...)`
     when the tables flow through `gas_forward`/`gas_batch_forward`."""
     tables: List[jnp.ndarray]        # L-1 tables [N+1, d_hidden]
     age: jnp.ndarray                 # [N+1] int32 — iters since last push
@@ -624,8 +624,8 @@ class HistoryStore:
              mask: jnp.ndarray) -> "HistoryStore":
         """Scatter fresh in-batch rows into H̄^(ell), quantizing to the
         store's history_dtype on the way in. The table's sentinel row is
-        sacrificial (`scratch_last_row`), letting the kernel path scatter
-        into a donated buffer in place."""
+        declared (`scratch_last_row`): valid rows never land there, and
+        the vq kernel path scatters masked rows into it, in place."""
         from repro.kernels import ops
         codec = get_codec(self.history_dtype)
         if codec.vq:

@@ -37,6 +37,7 @@ import numpy as np
 from .bcsr_spmm import bcsr_spmm
 from .decode_attn import flash_decode
 from .gather import gather_rows, gather_rows_vq
+from . import scatter
 from .scatter import scatter_rows, scatter_rows_vq
 from . import edge_softmax as esk
 from . import fused
@@ -641,14 +642,15 @@ def push_rows(table: jnp.ndarray, idx: jnp.ndarray, values: jnp.ndarray,
     """History push: table[idx[i]] = values[i] where mask[i]; padding rows
     (mask False) are dropped. Matches `core.history.push` semantics.
 
-    `scratch_last_row=True` declares that the caller's last table row is
-    sacrificial (GAS history tables are allocated [N+1, d] with a sentinel
-    row that is only ever read through a mask): masked rows are then
-    redirected into that row instead of being dropped, which lets the
-    kernel path scatter into the caller's buffer directly — no pad/slice
-    copies, and the donated table is updated in place. The scratch row's
-    contents become unspecified (they differ between backends); valid
-    indices must stay below N-1.
+    `scratch_last_row=True` declares that the caller's last table row is a
+    sentinel (GAS history tables are allocated [N+1, d] with a sentinel
+    row that is only ever read through a mask): valid indices are then
+    clipped below it. Either way the kernel path scatters into the
+    caller's buffer directly, in place when it is donated, for tables
+    whose width is a multiple of bd (narrower ones are lane-padded and
+    sliced, two copies); masked rows point past the table, where
+    `scatter_rows` drops them at no cost. Tracing a kernel-path call
+    counts its grid steps, `gas/push/chunks` (`repro.utils.spans`).
     """
     backend = resolve_backend(backend)
     N, D = table.shape
@@ -656,21 +658,31 @@ def push_rows(table: jnp.ndarray, idx: jnp.ndarray, values: jnp.ndarray,
         safe_idx = jnp.where(mask, idx, N)  # OOB -> dropped
         return table.at[safe_idx].set(values.astype(table.dtype),
                                       mode="drop", unique_indices=False)
-    interpret = backend == "interpret"
-    if scratch_last_row and D % bd == 0:
-        safe_idx = jnp.where(mask, jnp.clip(idx, 0, N - 2),
-                             N - 1).astype(jnp.int32)
-        return scatter_rows(table, safe_idx, values, bd=bd,
-                            interpret=interpret)
-    # general path: redirect masked rows to an appended sacrificial row
-    # (pad + slice copy the table — alignment-constrained callers that
-    # own a scratch row should pass scratch_last_row=True instead)
-    safe_idx = jnp.where(mask, jnp.clip(idx, 0, N - 1), N).astype(jnp.int32)
+    new_t, _ = _kernel_push(table, idx, values, mask, None,
+                            backend=backend, bd=bd,
+                            scratch_last_row=scratch_last_row)
+    return new_t
+
+
+def _kernel_push(table, idx, values, mask, row_scale, *, backend, bd,
+                 scratch_last_row):
+    """`scatter_rows` for `push_rows` / `push_rows_q`: valid rows clipped
+    into the table (below the sentinel with `scratch_last_row`), masked
+    rows to N, where the kernel drops them; tables narrower than a
+    multiple of bd lane-padded and sliced back. Counts the kernel's grid
+    steps, `gas/push/chunks`. Returns (new_table, indices)."""
+    N, D = table.shape
+    hi = N - 2 if scratch_last_row else N - 1
+    safe_idx = jnp.where(mask, jnp.clip(idx, 0, hi), N).astype(jnp.int32)
     d_pad = _pad_dim(D, bd)
-    tp = jnp.pad(table, ((0, 1), (0, d_pad - D)))
-    vp = jnp.pad(values.astype(table.dtype), ((0, 0), (0, d_pad - D)))
-    out = scatter_rows(tp, safe_idx, vp, bd=bd, interpret=interpret)
-    return out[:N, :D]
+    if d_pad != D:
+        table = jnp.pad(table, ((0, 0), (0, d_pad - D)))
+        values = jnp.pad(values, ((0, 0), (0, d_pad - D)))
+    m = idx.shape[0]
+    spans.count("gas/push/chunks", -(-m // scatter.chunk_rows(m, d_pad)))
+    out = scatter_rows(table, safe_idx, values, row_scale,
+                       interpret=backend == "interpret")
+    return (out[:, :D] if d_pad != D else out), safe_idx
 
 
 def push_rows_q(table: jnp.ndarray, scales: jnp.ndarray, idx: jnp.ndarray,
@@ -688,9 +700,8 @@ def push_rows_q(table: jnp.ndarray, scales: jnp.ndarray, idx: jnp.ndarray,
     (`scatter_rows` with its scales), so the quantized copy of the
     payload is never materialized in HBM; only the [M] row-max reduction
     happens outside.
-    Returns (new_table, new_scales); masking / `scratch_last_row` match
-    `push_rows` (the sentinel row's scale becomes garbage — sentinel
-    reads are masked everywhere).
+    Returns (new_table, new_scales); masking, `scratch_last_row` and the
+    `gas/push/chunks` counter match `push_rows`.
     """
     from repro.core.history import quantize_rows, row_scales
     backend = resolve_backend(backend)
@@ -704,28 +715,16 @@ def push_rows_q(table: jnp.ndarray, scales: jnp.ndarray, idx: jnp.ndarray,
         new_s = scales.at[safe_idx].set(row_scale, mode="drop",
                                         unique_indices=False)
         return new_t, new_s
-    interpret = backend == "interpret"
     # kernel path: the divide-round-clip runs inside scatter_rows; the
     # per-row scale comes from the SAME row_scales the jnp path uses, so
     # backends agree bit-for-bit
     row_scale = row_scales(v)
-    if scratch_last_row and D % bd == 0:
-        safe_idx = jnp.where(mask, jnp.clip(idx, 0, N - 2),
-                             N - 1).astype(jnp.int32)
-        new_t = scatter_rows(table, safe_idx, v, row_scale, bd=bd,
-                             interpret=interpret)
-        new_s = scales.at[safe_idx].set(row_scale, unique_indices=False)
-        return new_t, new_s
-    # general path: appended sacrificial row (pad + slice copies)
-    safe_idx = jnp.where(mask, jnp.clip(idx, 0, N - 1), N).astype(jnp.int32)
-    d_pad = _pad_dim(D, bd)
-    tp = jnp.pad(table, ((0, 1), (0, d_pad - D)))
-    vp = jnp.pad(v, ((0, 0), (0, d_pad - D)))
-    new_t = scatter_rows(tp, safe_idx, vp, row_scale, bd=bd,
-                         interpret=interpret)
+    new_t, safe_idx = _kernel_push(table, idx, v, mask, row_scale,
+                                   backend=backend, bd=bd,
+                                   scratch_last_row=scratch_last_row)
     new_s = scales.at[safe_idx].set(row_scale, mode="drop",
                                     unique_indices=False)
-    return new_t[:N, :D], new_s
+    return new_t, new_s
 
 
 def push_rows_vq(table: jnp.ndarray, scales: jnp.ndarray, idx: jnp.ndarray,

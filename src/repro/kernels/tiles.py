@@ -6,7 +6,10 @@ a tiled table ("Slice shape along dimension 0 must be aligned to tiling
 (8)"). So rows move in their aligned tile GROUP — row t lives in rows
 [t - t % 8, t - t % 8 + 8) — and `pick_row` selects the row out of the
 staged tile in VMEM. That reads GROUP x the row's own bytes from HBM:
-4 KiB per 128 lanes for an f32 table, 1 KiB for int8.
+4 KiB per 128 lanes for an f32 table, 1 KiB for int8. A push writes
+rows back the same way, as a read-modify-write of their tile:
+`scatter.scatter_rows` stages each distinct tile a chunk of sorted rows
+touches once, merges the chunk's rows into it, and writes it back.
 
 A block's last two dims must be (8, 128)-aligned or whole, so per-node
 vectors arrive as rows; `to_col` / `to_row` swap a vector's orientation

@@ -108,6 +108,91 @@ def test_scatter_rows_padded_rows_out_of_range():
         np.testing.assert_array_equal(np.asarray(out), expect)
 
 
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(f"u{a.dtype.itemsize}")
+
+
+def _chunk_case(case, rng):
+    """(N, D, idx, mask, scratch_last_row) of a `scatter_rows` chunk case."""
+    if case == "chunks":         # 3 chunks, a tile's rows split by each cut
+        N, M = 4096, 700
+        idx = 3 + rng.permutation(M)
+    elif case == "few":          # M < GROUP: one chunk of one GROUP
+        N, M = 64, 5
+        idx = rng.choice(N, M, replace=False)
+    elif case == "dense":        # most tiles take several rows
+        N, M = 200, 700
+        idx = rng.integers(0, N, M)
+    elif case == "dups":         # runs of one index across the chunk cuts
+        N, M = 1024, 700
+        idx = rng.integers(0, 64, M)
+    elif case == "tail":         # real rows in the partial last tile
+        N, M = 1003, 300
+        idx = rng.integers(960, N, M)
+    else:                        # "masked": padding rows to the sentinel
+        N, M = 1001, 600
+        idx = rng.integers(0, N - 1, M)
+    mask = rng.random(M) < 0.7 if case == "masked" else np.ones(M, bool)
+    return N, 128, idx.astype(np.int32), mask, case == "masked"
+
+
+CHUNK_CASES = ["chunks", "few", "dense", "dups", "tail", "masked"]
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_scatter_rows_chunked(dtype, case):
+    """The chunked push is bitwise `scatter_rows_ref`: rows in several
+    chunks, tiles split by a chunk cut, duplicates (last wins), the
+    table's partial last tile, masked rows, and -0.0 / inf / NaN
+    payloads, which the merge's select keeps."""
+    from repro.kernels import scatter
+    rng = np.random.default_rng(CHUNK_CASES.index(case))
+    N, D, idx, mask, scratch = _chunk_case(case, rng)
+    M = idx.shape[0]
+    c = scatter.chunk_rows(M, D)
+    order = np.sort(idx[mask])
+    if case in ("chunks", "dups"):
+        assert M > 2 * c
+        assert any(order[k * c - 1] // 8 == order[k * c] // 8
+                   for k in range(1, -(-M // c)))
+    table = jnp.asarray(rng.normal(size=(N, D)).astype(np.float32), dtype)
+    vals = rng.normal(size=(M, D)).astype(np.float32)
+    vals[0, :4] = [-0.0, np.inf, -np.inf, np.nan]
+    values = jnp.asarray(vals, dtype)
+    out = ops.push_rows(table, jnp.asarray(idx), values, jnp.asarray(mask),
+                        backend="interpret", scratch_last_row=scratch)
+    ref = scatter_rows_ref(table, jnp.asarray(idx), values,
+                           jnp.asarray(mask))
+    keep = N - 1 if scratch else N        # the sentinel is unspecified
+    np.testing.assert_array_equal(_bits(out)[:keep], _bits(ref)[:keep])
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_scatter_rows_q_chunked(case):
+    """Quantizing mode: each pushed row lands as
+    `core.history.quantize_rows` of its last occurrence, bitwise, in the
+    same cases; the scales land beside it."""
+    rng = np.random.default_rng(10 + CHUNK_CASES.index(case))
+    N, D, idx, mask, scratch = _chunk_case(case, rng)
+    M = idx.shape[0]
+    table = jnp.asarray(rng.integers(-127, 128, (N, D)), jnp.int8)
+    scales = jnp.asarray(rng.random(N).astype(np.float32))
+    values = jnp.asarray(rng.normal(size=(M, D)).astype(np.float32) * 3)
+    new_t, new_s = ops.push_rows_q(table, scales, jnp.asarray(idx), values,
+                                   jnp.asarray(mask), backend="interpret",
+                                   scratch_last_row=scratch)
+    q, s = H.quantize_rows(values)
+    ref_t = scatter_rows_ref(table, jnp.asarray(idx), q, jnp.asarray(mask))
+    ref_s = scatter_rows_ref(scales[:, None], jnp.asarray(idx), s[:, None],
+                             jnp.asarray(mask))[:, 0]
+    keep = N - 1 if scratch else N
+    np.testing.assert_array_equal(np.asarray(new_t)[:keep],
+                                  np.asarray(ref_t)[:keep])
+    np.testing.assert_array_equal(_bits(new_s)[:keep], _bits(ref_s)[:keep])
+
+
 def test_push_pull_roundtrip_matches_history_module():
     """ops.push_rows/pull_rows on the kernel path == core.history push/pull."""
     rng = np.random.default_rng(2)

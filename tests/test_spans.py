@@ -239,3 +239,32 @@ def test_fused_epoch_counts_its_staging_path(recording, monkeypatch, budget,
     assert rec.counters[counted] >= 2
     assert absent not in rec.counters
     assert np.isfinite(metrics["loss"])
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("M", [5, 700, 2176])
+def test_push_counts_its_chunks(recording, quantized, M):
+    """Tracing a kernel-path push counts the grid steps its chunked
+    `scatter_rows` compiles, ceil(M / C)."""
+    import jax.numpy as jnp
+    from repro.kernels import ops, scatter
+    N, D = 3001, 256
+    idx = jax.ShapeDtypeStruct((M,), jnp.int32)
+    vals = jax.ShapeDtypeStruct((M, D), jnp.float32)
+    mask = jax.ShapeDtypeStruct((M,), jnp.bool_)
+    if quantized:
+        jax.eval_shape(
+            lambda t, s, i, v, m: ops.push_rows_q(
+                t, s, i, v, m, backend="interpret", scratch_last_row=True),
+            jax.ShapeDtypeStruct((N, D), jnp.int8),
+            jax.ShapeDtypeStruct((N,), jnp.float32), idx, vals, mask)
+    else:
+        jax.eval_shape(
+            lambda t, i, v, m: ops.push_rows(
+                t, i, v, m, backend="interpret", scratch_last_row=True),
+            jax.ShapeDtypeStruct((N, D), jnp.float32), idx, vals, mask)
+    rec = spans.stop()
+    c = scatter.chunk_rows(M, D)
+    assert c % 8 == 0 and c <= scatter.CHUNK
+    assert rec.counters == {"gas/push/chunks": -(-M // c)}
+    assert -(-M // c) == -(-M // scatter.CHUNK)

@@ -9,8 +9,11 @@ compiles one kernel at the widths of `chip_smoke.py` (GCN 3 x 256 on a
 benchmark cells' shape (forward blocks [17, 80, 128, 128], 2,176 batch
 and 8,107 halo rows of a 100,001-row table), where its VMEM panel is
 largest, and for a refresh whose halo outgrows the panel, which takes
-the per-block path. The vq form of `fused.gather_spmm` is left out: it
-does not compile, and `ops.gas_aggregate` refuses it on `pallas`.
+the per-block path. `scatter.scatter_rows` is compiled at the cells'
+shape too (2,176 rows pushed into the 100,001-row table, f32 and int8),
+and checked to push in place into a donated table. The vq form of
+`fused.gather_spmm` is left out: it does not compile, and
+`ops.gas_aggregate` refuses it on `pallas`.
 
 The sharded epoch of `GASConfig(ranks=4)` (`dist_gas.make_epoch_fn`:
 `shard_map`, halo exchange by `ppermute`, the kernels inside) is compiled
@@ -100,6 +103,15 @@ def _cases():
         "scatter_rows_q": (
             functools.partial(scatter.scatter_rows, **kw),
             [((N, D), i8), ((M,), i32), ((M, D), f32), ((M,), f32)]),
+        # the cells' push: a padded part into the partial-tiled table
+        "scatter_rows_cell_f32": (
+            functools.partial(scatter.scatter_rows, **kw),
+            [((N_CELL, D), f32), ((N_IN_CELL,), i32),
+             ((N_IN_CELL, D), f32)]),
+        "scatter_rows_cell_int8": (
+            functools.partial(scatter.scatter_rows, **kw),
+            [((N_CELL, D), i8), ((N_IN_CELL,), i32),
+             ((N_IN_CELL, D), f32), ((N_IN_CELL,), f32)]),
         "gather_spmm_f32": (
             functools.partial(fused.gather_spmm, **kw),
             [((N_IN, D), f32), ((N, D), f32)] + blocks + halo(M)),
@@ -154,6 +166,7 @@ def _cases():
 
 KERNELS = ["bcsr_spmm", "gather_rows", "gather_rows_dq", "gather_rows_vq",
            "scatter_rows", "scatter_rows_q", "scatter_rows_vq",
+           "scatter_rows_cell_f32", "scatter_rows_cell_int8",
            "gather_spmm_f32", "gather_spmm_int8", "gather_spmm_refresh",
            "gather_spmm_refresh_per_block", "gather_spmm_cell_f32",
            "gather_spmm_cell_int8",
@@ -183,6 +196,21 @@ def test_gather_spmm_staging_path(name, width):
                                         s, dt in _cases()[name][1][:5]]
     assert fused.panel_width(x_in, table, vals, halo_nodes,
                              bd=BN) == width
+
+
+@pytest.mark.parametrize("name", ["scatter_rows_cell_f32",
+                                  "scatter_rows_cell_int8"])
+def test_scatter_rows_pushes_in_place(name, one_chip):
+    """With the table donated, the compiled push writes into it: the
+    output aliases the table, and no temporary holds a copy of it."""
+    fn, shapes = _cases()[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    mem = jax.jit(fn, donate_argnums=0).lower(*args).compile() \
+        .memory_analysis()
+    table = args[0].size * args[0].dtype.itemsize    # before tile padding
+    assert mem.alias_size_in_bytes >= table
+    assert mem.temp_size_in_bytes < table // 8
 
 
 def _sharded_epoch_args(topo, history_dtype):
