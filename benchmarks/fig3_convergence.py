@@ -5,9 +5,10 @@ from __future__ import annotations
 
 import time
 
+from repro.core import runtime as R
 from repro.data.graphs import citation_graph, sbm_cluster_graph
 from repro.gnn.model import GNNSpec
-from repro.train.gas_trainer import FullBatchTrainer, GASTrainer, TrainConfig
+from repro.train.baselines import FullBatchTrainer, TrainConfig
 
 # (name, operator kwargs, graph, Eq.3 reg on?) — the paper applies Eq. 3
 # only to non-linear message passing (GIN); L2/clipping suffices for linear.
@@ -49,18 +50,16 @@ def run(quick=False):
         acc_full = fb.evaluate()["test_acc"]
 
         # naive history baseline: random partitions, no reg, single cluster
-        naive = GASTrainer(g, GNNSpec(**spec_kw), num_parts=parts,
-                           partitioner="random", clusters_per_batch=k,
-                           tcfg=tcfg)
-        naive.fit()
-        acc_naive = naive.evaluate()["test_acc"]
-
+        accs = []
         reg_kw = dict(reg_delta=0.05, reg_weight=0.05) if use_reg else {}
-        gas = GASTrainer(g, GNNSpec(**spec_kw, **reg_kw), num_parts=parts,
-                         partitioner="metis", clusters_per_batch=k,
-                         tcfg=tcfg)
-        gas.fit()
-        acc_gas = gas.evaluate()["test_acc"]
+        for partitioner, spec in (("random", GNNSpec(**spec_kw)),
+                                  ("metis", GNNSpec(**spec_kw, **reg_kw))):
+            plan = R.build_plan(g, spec, R.GASConfig(
+                num_parts=parts, partitioner=partitioner,
+                clusters_per_batch=k, epochs=epochs, lr=0.01, seed=0))
+            state, _ = R.fit(plan, R.init_state(plan))
+            accs.append(R.evaluate_exact(plan, state)["test_acc"])
+        acc_naive, acc_gas = accs
 
         rows.append((f"fig3/{name}", (time.time() - t0) * 1e6,
                      f"full={acc_full*100:.2f} naive={acc_naive*100:.2f} "

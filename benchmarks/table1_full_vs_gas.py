@@ -9,9 +9,10 @@ import time
 
 from common import mean_std  # noqa: F401
 
+from repro.core import runtime as R
 from repro.data.graphs import citation_graph
 from repro.gnn.model import GNNSpec
-from repro.train.gas_trainer import FullBatchTrainer, GASTrainer, TrainConfig
+from repro.train.baselines import FullBatchTrainer, TrainConfig
 
 OPS = [("gcn", 2), ("gat", 2), ("appnp", 5), ("gcnii", 8)]
 
@@ -34,10 +35,11 @@ def run(seeds=(0, 1, 2), epochs=60, quick=False):
             fb = FullBatchTrainer(g, spec, tcfg)
             fb.fit()
             accs_f.append(fb.evaluate()["test_acc"])
-            gas = GASTrainer(g, spec, num_parts=8, partitioner="metis",
-                             tcfg=tcfg)
-            gas.fit()
-            accs_g.append(gas.evaluate()["test_acc"])
+            plan = R.build_plan(g, spec, R.GASConfig(
+                num_parts=8, partitioner="metis", epochs=epochs, lr=0.01,
+                seed=s))
+            state, _ = R.fit(plan, R.init_state(plan))
+            accs_g.append(R.evaluate_exact(plan, state)["test_acc"])
         mf, sf = mean_std(accs_f)
         mg, sg = mean_std(accs_g)
         us = (time.time() - t0) / max(len(seeds), 1) * 1e6

@@ -7,9 +7,10 @@ import time
 
 from common import mean_std
 
+from repro.core import runtime as R
 from repro.data.graphs import citation_graph, sbm_cluster_graph
 from repro.gnn.model import GNNSpec
-from repro.train.gas_trainer import FullBatchTrainer, GASTrainer, TrainConfig
+from repro.train.baselines import FullBatchTrainer, TrainConfig
 
 VARIANTS = [
     ("baseline", dict(partitioner="random", reg=False)),
@@ -20,7 +21,6 @@ VARIANTS = [
 
 
 def _run_case(g, spec_kw, epochs, seeds, parts, k=1):
-    tcfg0 = TrainConfig(epochs=epochs, lr=0.01, seed=0)
     full_accs = []
     for s in seeds:
         spec = GNNSpec(**spec_kw)
@@ -38,12 +38,11 @@ def _run_case(g, spec_kw, epochs, seeds, parts, k=1):
             if opt["reg"]:
                 kw.update(reg_delta=0.05, reg_weight=0.05)
             spec = GNNSpec(**kw)
-            tr = GASTrainer(g, spec, num_parts=parts,
-                            partitioner=opt["partitioner"],
-                            clusters_per_batch=k,
-                            tcfg=TrainConfig(epochs=epochs, lr=0.01, seed=s))
-            tr.fit()
-            accs.append(tr.evaluate()["test_acc"])
+            plan = R.build_plan(g, spec, R.GASConfig(
+                num_parts=parts, partitioner=opt["partitioner"],
+                clusters_per_batch=k, epochs=epochs, lr=0.01, seed=s))
+            state, _ = R.fit(plan, R.init_state(plan))
+            accs.append(R.evaluate_exact(plan, state)["test_acc"])
         out[name] = mean_std(accs)[0] - full_acc
     return full_acc, out
 

@@ -5,10 +5,10 @@ from __future__ import annotations
 
 import time
 
+from repro.core import runtime as R
 from repro.data.graphs import citation_graph
 from repro.gnn.model import GNNSpec
-from repro.train.baselines import GraphSAGETrainer, SGCTrainer
-from repro.train.gas_trainer import GASTrainer, TrainConfig
+from repro.train.baselines import GraphSAGETrainer, SGCTrainer, TrainConfig
 
 
 def run(quick=False):
@@ -16,7 +16,6 @@ def run(quick=False):
     g = citation_graph(num_nodes=1500 if quick else 4000, num_features=64,
                        num_classes=6, homophily=0.7, feature_noise=2.5,
                        seed=80)
-    tcfg = TrainConfig(epochs=epochs, lr=0.01, seed=0)
     parts = 8 if quick else 16
     rows = []
 
@@ -36,30 +35,25 @@ def run(quick=False):
     rows.append(("table5/sgc", (time.time() - t0) * 1e6,
                  f"test={sgc.evaluate()['test_acc']*100:.2f}"))
 
-    t0 = time.time()
-    spec = GNNSpec(op="gcn", d_in=64, d_hidden=48, num_classes=6,
-                   num_layers=2)
-    cgcn = GASTrainer(g, spec, num_parts=parts, partitioner="metis",
-                      use_history=False, tcfg=tcfg)
-    cgcn.fit()
-    rows.append(("table5/cluster-gcn", (time.time() - t0) * 1e6,
-                 f"test={cgcn.evaluate()['test_acc']*100:.2f}"))
-
-    for name, spec in (
-            ("gas-gcn", GNNSpec(op="gcn", d_in=64, d_hidden=48,
-                                num_classes=6, num_layers=2)),
+    gcn = GNNSpec(op="gcn", d_in=64, d_hidden=48, num_classes=6,
+                  num_layers=2)
+    for name, spec, use_history in (
+            ("cluster-gcn", gcn, False),
+            ("gas-gcn", gcn, True),
             ("gas-gcnii16", GNNSpec(op="gcnii", d_in=64, d_hidden=48,
                                     num_classes=6, num_layers=16,
-                                    alpha=0.1)),
+                                    alpha=0.1), True),
             ("gas-pna", GNNSpec(op="pna", d_in=64, d_hidden=48,
                                 num_classes=6, num_layers=2,
-                                log_deg_mean=1.8))):
+                                log_deg_mean=1.8), True)):
         t0 = time.time()
-        tr = GASTrainer(g, spec, num_parts=parts, partitioner="metis",
-                        tcfg=tcfg)
-        tr.fit()
+        plan = R.build_plan(g, spec, R.GASConfig(
+            num_parts=parts, partitioner="metis", use_history=use_history,
+            epochs=epochs, lr=0.01, seed=0))
+        state, _ = R.fit(plan, R.init_state(plan))
+        acc = R.evaluate_exact(plan, state)["test_acc"]
         rows.append((f"table5/{name}", (time.time() - t0) * 1e6,
-                     f"test={tr.evaluate()['test_acc']*100:.2f}"))
+                     f"test={acc*100:.2f}"))
     return rows
 
 

@@ -261,8 +261,7 @@ def train_phase(g, spec, backend, history_dtype, part, num_parts, seed,
 
     def config(be):
         return R.GASConfig(num_parts=num_parts, backend=be,
-                           history_dtype=history_dtype, fused_epoch=True,
-                           seed=seed)
+                           history_dtype=history_dtype, seed=seed)
 
     tag = f"train[{history_dtype}]"
     plan = R.build_plan(g, spec, config(backend), part=part)

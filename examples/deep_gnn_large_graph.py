@@ -7,10 +7,10 @@ receptive field spans the whole graph.
 import argparse
 import time
 
+from repro.core import runtime as R
 from repro.core.partition import inter_intra_ratio
 from repro.data.graphs import citation_graph, sbm_cluster_graph
 from repro.gnn.model import GNNSpec
-from repro.train.gas_trainer import GASTrainer, TrainConfig
 
 
 def main():
@@ -30,14 +30,15 @@ def main():
     spec = GNNSpec(op="gcnii", d_in=128, d_hidden=64, num_classes=10,
                    num_layers=32, alpha=0.1)
     t0 = time.time()
-    tr = GASTrainer(graph, spec, num_parts=parts, partitioner="metis",
-                    clusters_per_batch=2,
-                    tcfg=TrainConfig(epochs=args.epochs, lr=0.01))
+    plan = R.build_plan(graph, spec, R.GASConfig(
+        num_parts=parts, partitioner="metis", clusters_per_batch=2,
+        epochs=args.epochs, lr=0.01))
     print("inter/intra after clustering:",
-          round(inter_intra_ratio(graph.indptr, graph.indices, tr.part), 3))
-    tr.fit(log_every=10)
-    print(f"GCNII-32L: {tr.evaluate()} in {time.time()-t0:.0f}s")
-    b = tr.batches
+          round(inter_intra_ratio(graph.indptr, graph.indices, plan.part), 3))
+    state, _ = R.fit(plan, R.init_state(plan), log_every=10)
+    print(f"GCNII-32L: {R.evaluate_exact(plan, state)} "
+          f"in {time.time()-t0:.0f}s")
+    b = plan.batches
     ws = (b.max_b + b.max_h) * 64 * 4 * 32 / 1e6
     print(f"device working set {ws:.1f}MB for a {graph.num_nodes}-node graph "
           f"(constant in graph size — paper's central claim)")
@@ -48,11 +49,11 @@ def main():
     spec2 = GNNSpec(op="gin", d_in=sbm.x.shape[1], d_hidden=64,
                     num_classes=10, num_layers=4, reg_delta=0.05,
                     reg_weight=0.05)
-    tr2 = GASTrainer(sbm, spec2, num_parts=40, partitioner="metis",
-                     clusters_per_batch=10,
-                     tcfg=TrainConfig(epochs=max(args.epochs, 40), lr=0.01))
-    tr2.fit(log_every=10)
-    print(f"GIN-4L on CLUSTER-SBM: {tr2.evaluate()}")
+    plan2 = R.build_plan(sbm, spec2, R.GASConfig(
+        num_parts=40, partitioner="metis", clusters_per_batch=10,
+        epochs=max(args.epochs, 40), lr=0.01))
+    state2, _ = R.fit(plan2, R.init_state(plan2), log_every=10)
+    print(f"GIN-4L on CLUSTER-SBM: {R.evaluate_exact(plan2, state2)}")
 
 
 if __name__ == "__main__":

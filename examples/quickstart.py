@@ -6,8 +6,7 @@ history-backed mini-batch executor. Uses the typed plan/state/step runtime
 (`repro.core.runtime`): one `GASConfig` holds every knob, `build_plan`
 does all one-time work (partition, padded `GASBatch` structures, kernel
 backend resolution), and training threads an explicit `GASState` through
-pure jitted steps. (`GASTrainer` wraps exactly this loop if you prefer an
-object.)
+pure jitted epochs (`R.fit` runs the same loop for `config.epochs`).
 
     PYTHONPATH=src python examples/quickstart.py [--backend jnp|interpret|pallas]
                                                  [--history-dtype f32|bf16|int8|vq]
@@ -39,7 +38,7 @@ from repro.data.graphs import citation_graph
 from repro.gnn.model import GNNSpec
 from repro.kernels import ops
 from repro.launch.compile_cache import enable_compile_cache
-from repro.train.gas_trainer import FullBatchTrainer, TrainConfig
+from repro.train.baselines import FullBatchTrainer, TrainConfig
 
 
 def main(backend=None, epochs=60, nodes=2500, history_dtype=None,

@@ -4,7 +4,7 @@
 #
 # Public typed GAS runtime surface (see core/runtime.py):
 from .batch import BlockStructure, GASBatch                      # noqa: F401
-from .history import Histories, HistoryStore                     # noqa: F401
+from .history import HistoryStore                                # noqa: F401
 from .runtime import (GASConfig, GASPlan, GASState, build_plan,  # noqa: F401
                       evaluate_exact, fit, init_state, make_step_fn,
                       predict, train_epoch, train_step)

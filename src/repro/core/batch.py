@@ -160,3 +160,13 @@ class GASBatch:
 
     def replace(self, **kw) -> "GASBatch":
         return replace(self, **kw)
+
+
+def ensure_batch(batch: GASBatch) -> GASBatch:
+    """Type guard for the forward's entry: `GASBatch` is the only
+    accepted batch type."""
+    if not isinstance(batch, GASBatch):
+        raise TypeError(
+            f"expected core.batch.GASBatch, got {type(batch)} (the legacy "
+            "dict shim was removed; build_batches returns a GASBatch)")
+    return batch

@@ -26,7 +26,7 @@ class HistoryExecConfig:
     `backend` — kernel backend for history I/O and aggregation; None
     auto-selects via `kernels.ops.resolve_backend` ($REPRO_KERNEL_BACKEND
     -> platform default). For serving, None additionally defers to the
-    bound store's backend (`gas.resolve_store`).
+    bound store's backend (`gnn.model.resolve_store`).
 
     `history_dtype` — history-table storage precision; None resolves via
     `history.resolve_history_dtype` ($REPRO_HISTORY_DTYPE -> "f32").

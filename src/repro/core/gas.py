@@ -1,4 +1,4 @@
-"""GAS mini-batch executor (paper Algorithm 1) with static padded shapes.
+"""GAS mini-batch construction (paper Algorithm 1's static padded shapes).
 
 Setup (numpy, once): partition nodes into B clusters; for each cluster build
 the pruned computation graph — in-batch nodes + 1-hop halo + the COO edges
@@ -8,41 +8,24 @@ into block-CSR form (`blk_vals` [B,R,K,bn,bn] / `blk_cols` [B,R,K], K
 padded to the max over batches) so the kernel backends can aggregate with
 dense MXU block matmuls instead of gather/segment ops.
 
-Execution (jit, per batch): for each layer ℓ, assemble
-    x_all = [ in-batch rows (exact) ; halo rows (pulled from H̄^{ℓ-1}) ; 0 ]
-run the operator on the local COO (or its BCSR blocks), push the new
-in-batch rows to H̄^{ℓ}. Layer 0 inputs are raw features for both in-batch
-and halo rows (exact — this is why Theorem 2 has no ε^(0) term).
-
-All history pull/push and feature gathers route through the
-`kernels/ops.py` backend dispatch ("pallas" | "interpret" | "jnp"), so the
-identical call sites run Pallas kernels on TPU and are testable on CPU.
+This module only builds batches: `build_batches` (whole partitions),
+`patch_batches` (after a graph delta), `subgraph_batch` (an arbitrary
+node set — serving and the dynamic re-push), the GCN edge weights and the
+padding bounds of regrouped epochs. The forward that consumes them — per
+layer ℓ, `x_all = [in-batch rows ; halo rows pulled from H̄^{ℓ-1} ; 0]`,
+then a push of the new in-batch rows to H̄^{ℓ} — is
+`gnn.model.gas_batch_forward`.
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Callable, Dict, Optional, Tuple, Union
+from typing import Optional, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.data.graphs import Graph
 from repro.kernels import ops
 from repro.utils import spans
-from . import history as H
 from .batch import BlockStructure, GASBatch
-
-
-def ensure_batch(batch: GASBatch) -> GASBatch:
-    """Type guard for the executor entry points. The one-release legacy
-    batch-dict deprecation shim (`coerce_batch`) is gone — `GASBatch` is
-    the only accepted batch type."""
-    if not isinstance(batch, GASBatch):
-        raise TypeError(
-            f"expected core.batch.GASBatch, got {type(batch)} (the legacy "
-            "dict shim was removed; build_batches returns a GASBatch)")
-    return batch
 
 
 def gcn_edge_weights(graph: Graph, add_self_loops: bool = True
@@ -511,144 +494,3 @@ def subgraph_batch(indptr: np.ndarray, src: np.ndarray, w: np.ndarray,
                     forward=fwd, transposed=tr, unit=un,
                     unit_transposed=un_t, num_batches=1,
                     max_b=max_b, max_h=max_h, max_e=max_e, bn=bn)
-
-
-# ---------------------------------------------------------------------------
-# GAS forward pass
-# ---------------------------------------------------------------------------
-
-LayerFn = Callable[..., jnp.ndarray]
-
-
-def staleness_diags(age: jnp.ndarray, halo_nodes: jnp.ndarray,
-                    halo_mask: jnp.ndarray) -> Dict[str, jnp.ndarray]:
-    """Mean/max history age (iterations since last push) of the halo rows
-    this batch pulls — the staleness that Lemma 1 / Theorem 2 bound."""
-    hage = jnp.take(age, halo_nodes, mode="clip").astype(jnp.float32)
-    valid = halo_mask.astype(jnp.float32)
-    n = jnp.maximum(jnp.sum(valid), 1.0)
-    return {"halo_age_mean": jnp.sum(hage * valid) / n,
-            "halo_age_max": jnp.max(hage * valid)}
-
-
-def resolve_store(hist: Union[H.HistoryStore, H.Histories],
-                  backend: Optional[str]
-                  ) -> Tuple[H.HistoryStore, bool, str]:
-    """Normalize the history argument: returns (store, was_legacy,
-    backend). A `HistoryStore` carries its own bound backend, which wins
-    when the caller passes `backend=None`; the legacy `Histories` tuple
-    gets the usual `ops.resolve_backend` resolution."""
-    if isinstance(hist, H.HistoryStore):
-        backend = hist.backend if backend is None \
-            else ops.resolve_backend(backend)
-        return (hist if backend == hist.backend
-                else dataclasses.replace(hist, backend=backend),
-                False, backend)
-    backend = ops.resolve_backend(backend)
-    return H.HistoryStore.from_histories(hist, backend), True, backend
-
-
-def materialize_x_all(ell: int, x_cur: jnp.ndarray, xh: jnp.ndarray,
-                      store: H.HistoryStore, batch: GASBatch,
-                      use_history: bool,
-                      halo_scale: Optional[jnp.ndarray] = None
-                      ) -> jnp.ndarray:
-    """Unfused layer input `x_all = [x_cur ; halo_rows ; dummy-zero row]`:
-    layer 0 uses the exact precomputed halo rows `xh`; layers >= 1 pull
-    stale rows from the previous layer's history table (dequantized for
-    compressed stores; zeros when history is off). Shared by
-    `gas_forward` and `gnn.model.gas_batch_forward` so the fallback path
-    cannot drift between them. `halo_scale` [max_h], when given, damps
-    the pulled rows (haste-makes-waste staleness compensation — see
-    `GASConfig.halo_age_decay`); layer-0 halo rows are exact raw
-    features and are never scaled."""
-    if ell == 0:
-        halo_rows = xh
-    elif use_history:
-        halo_rows = store.pull(ell - 1, batch.halo_nodes)
-        halo_rows = halo_rows.astype(x_cur.dtype) * \
-            batch.halo_mask[:, None]
-        if halo_scale is not None:
-            halo_rows = halo_rows * halo_scale[:, None]
-    else:
-        halo_rows = jnp.zeros((batch.halo_nodes.shape[0],
-                               x_cur.shape[-1]), x_cur.dtype)
-    dummy = jnp.zeros((1, x_cur.shape[-1]), x_cur.dtype)
-    return jnp.concatenate([x_cur, halo_rows, dummy], axis=0)
-
-
-def gas_forward(layer_apply: Callable[[int, jnp.ndarray, GASBatch],
-                                      jnp.ndarray],
-                num_layers: int,
-                x_global: jnp.ndarray,
-                batch: GASBatch,
-                hist: Union[H.HistoryStore, H.Histories],
-                use_history: bool = True,
-                backend: Optional[str] = None,
-                fused_layer_apply: Optional[Callable] = None,
-                ) -> Tuple[jnp.ndarray, Union[H.HistoryStore, H.Histories],
-                           Dict[str, jnp.ndarray]]:
-    """Runs L layers on one padded cluster batch.
-
-    layer_apply(ℓ, x_all, batch) -> new in-batch rows [max_b, d_{ℓ+1}].
-    `batch` is a single-batch `GASBatch`; `hist` is a `HistoryStore`
-    (preferred — its bound backend is used when `backend` is None) or a
-    legacy `Histories`, and the updated histories are returned as
-    whichever type came in. All history I/O (halo pulls, in-batch pushes)
-    and the layer-0 feature gathers dispatch on the resolved backend via
-    `kernels/ops.py`.
-
-    `fused_layer_apply(ℓ, x_cur, (table, scales, codebook, halo_nodes,
-    halo_mask), batch)`, when given, is used for layers ℓ >= 1 on the
-    kernel backends instead of materializing `x_all`: the callee
-    aggregates through `ops.gas_aggregate`, which reads halo columns
-    directly out of the history table (no per-layer pull + concatenate
-    copy; `scales` is the per-row dequant table for int8/vq stores and
-    `codebook` the [S, C, ds] vq codebook, None otherwise) and needs the
-    transposed BCSR structure — batches built without it
-    (`batch.transposed is None`) fall back to the materialized path,
-    matching `gnn.model.gas_batch_forward`'s gating. See that function
-    for the operator-zoo instantiation.
-
-    Returns (batch outputs, updated histories, diagnostics — mean/max
-    history age of the pulled halo rows plus the mean relative
-    quantization error of this step's pushes, `hist_quant_err`).
-    """
-    batch = ensure_batch(batch)
-    store, legacy_hist, backend = resolve_store(hist, backend)
-    bmask = batch.batch_mask
-
-    # layer 0 inputs are exact for batch AND halo rows
-    xb = ops.pull_rows(x_global, batch.batch_nodes, backend=backend)
-    xb = xb * bmask[:, None]
-    xh = ops.pull_rows(x_global, batch.halo_nodes, backend=backend)
-    xh = xh * batch.halo_mask[:, None]
-
-    diags = staleness_diags(store.age, batch.halo_nodes, batch.halo_mask)
-    fuse = (fused_layer_apply is not None and backend != "jnp"
-            and use_history and batch.transposed is not None)
-    qerr = jnp.zeros((), jnp.float32)
-    x_cur = xb
-    for ell in range(num_layers):
-        if ell > 0 and fuse:
-            x_next = fused_layer_apply(
-                ell, x_cur, (store.tables[ell - 1],
-                             store.layer_scales(ell - 1),
-                             store.layer_codebook(ell - 1),
-                             batch.halo_nodes, batch.halo_mask), batch)
-        else:
-            x_all = materialize_x_all(ell, x_cur, xh, store, batch,
-                                      use_history)
-            x_next = layer_apply(ell, x_all, batch)
-        if ell < num_layers - 1:
-            # push new embeddings (histories receive *detached* values;
-            # the [N+1, d] sentinel row lets the kernel path scatter into
-            # the donated table in place)
-            pushed = jax.lax.stop_gradient(x_next)
-            store = store.push(ell, batch.batch_nodes, pushed, bmask)
-            qerr = qerr + store.quant_error(pushed, bmask, ell)
-        x_cur = x_next
-
-    diags["hist_quant_err"] = qerr / max(num_layers - 1, 1)
-    store = store.tick(batch.batch_nodes, bmask)
-    return x_cur, (store.to_histories() if legacy_hist else store), diags

@@ -19,8 +19,7 @@ between these semantics and the Pallas gather/scatter kernels per backend.
 resolved kernel backend is bound ONCE at construction (aux data on the
 pytree, so it cannot silently change between jitted calls), and all
 history I/O goes through its `pull`/`push`/`tick`/`bytes` methods instead
-of free functions plus per-call `backend=` threading. The legacy
-`Histories` NamedTuple remains as the thin reference container.
+of free functions plus per-call `backend=` threading.
 
 Compression (`history_dtype ∈ {"f32", "bf16", "int8", "vq"}`, also aux
 data, one registry entry each — see `HistoryCodec`/`get_codec`):
@@ -47,7 +46,7 @@ from __future__ import annotations
 import functools
 import os
 from dataclasses import dataclass, field, replace
-from typing import Any, Callable, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -368,24 +367,6 @@ def quantization_error(values: jnp.ndarray, mask: jnp.ndarray,
     return jnp.sum((num / den) * valid) / jnp.maximum(jnp.sum(valid), 1.0)
 
 
-class Histories(NamedTuple):
-    """GAS executors allocate tables with num_nodes = N + 1: the last row
-    is a masked sentinel that padded indices point at. The push
-    (`kernels/ops.push_rows(..., scratch_last_row=True)`) clips valid
-    indices below that row — with an [N, d] table it would silently
-    clobber the last real row. Always `init_histories(N + 1, ...)`
-    when the tables flow through `gas_forward`/`gas_batch_forward`."""
-    tables: List[jnp.ndarray]        # L-1 tables [N+1, d_hidden]
-    age: jnp.ndarray                 # [N+1] int32 — iters since last push
-
-
-def init_histories(num_nodes: int, dims: List[int],
-                   dtype=jnp.float32) -> Histories:
-    return Histories(
-        tables=[jnp.zeros((num_nodes, d), dtype) for d in dims],
-        age=jnp.zeros((num_nodes,), jnp.int32))
-
-
 def pull(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """Gather halo rows. idx is padded with num_nodes-safe dummy (clip)."""
     return jnp.take(table, idx, axis=0, mode="clip")
@@ -399,16 +380,12 @@ def push(table: jnp.ndarray, idx: jnp.ndarray, values: jnp.ndarray,
                                   unique_indices=False)
 
 
-def tick(hist: Histories, batch_idx: jnp.ndarray,
+def tick(age: jnp.ndarray, batch_idx: jnp.ndarray,
          mask: jnp.ndarray) -> jnp.ndarray:
     """age += 1 everywhere, reset to 0 for just-pushed nodes."""
-    age = hist.age + 1
+    age = age + 1
     safe = jnp.where(mask, batch_idx, age.shape[0])
     return age.at[safe].set(0, mode="drop")
-
-
-def history_bytes(hist: Histories) -> int:
-    return sum(int(np.prod(t.shape)) * t.dtype.itemsize for t in hist.tables)
 
 
 # ---------------------------------------------------------------------------
@@ -424,11 +401,13 @@ class HistoryStore:
     """Historical-embedding store with the kernel backend bound once.
 
     A frozen pytree: `tables` (one [N+1, d] array per hidden layer — the
-    +1 sentinel row is REQUIRED, see `Histories`), the staleness clock
-    `age`, and (int8 only) the per-row `scales` tables ([N+1] f32 each)
-    are leaves; `backend`, `history_dtype` and `storage` are static aux
-    data, so a store created for one backend/precision/placement cannot
-    flow into a step traced for another without a re-trace. All methods
+    +1 sentinel row is REQUIRED: padded indices point at it, and the
+    push clips valid indices below it, so an [N, d] table would have its
+    last real row clobbered), the staleness clock `age`, and (int8
+    only) the per-row `scales` tables ([N+1] f32 each) are leaves;
+    `backend`, `history_dtype` and `storage` are static aux data, so a
+    store created for one backend/precision/placement cannot flow into a
+    step traced for another without a re-trace. All methods
     are pure — they return a new store. `pull` always yields dequantized
     rows; `push` takes full-precision rows and quantizes on the way in.
 
@@ -466,8 +445,8 @@ class HistoryStore:
         hd = resolve_history_dtype(history_dtype)
         codec = get_codec(hd)
         st = codec.storage if (hd != "f32" or dtype is None) else dtype
-        h = init_histories(num_nodes,
-                           [codec.table_width(d) for d in dims], st)
+        tables = tuple(jnp.zeros((num_nodes, codec.table_width(d)), st)
+                       for d in dims)
         scales = (tuple(jnp.ones((num_nodes,), jnp.float32) for _ in dims)
                   if codec.scaled else None)
         codebooks = (tuple(vq_init_codebook(d) for d in dims)
@@ -476,9 +455,10 @@ class HistoryStore:
                         for cb in codebooks) if codec.vq else None)
         sums = (tuple(jnp.zeros(cb.shape, jnp.float32)
                       for cb in codebooks) if codec.vq else None)
-        return cls(tables=tuple(h.tables), age=h.age, scales=scales,
-                   codebooks=codebooks, cb_counts=counts, cb_sums=sums,
-                   backend=ops.resolve_backend(backend), history_dtype=hd,
+        return cls(tables=tables, age=jnp.zeros((num_nodes,), jnp.int32),
+                   scales=scales, codebooks=codebooks, cb_counts=counts,
+                   cb_sums=sums, backend=ops.resolve_backend(backend),
+                   history_dtype=hd,
                    storage=resolve_history_storage(storage)).place()
 
     def place(self) -> "HistoryStore":
@@ -521,21 +501,6 @@ class HistoryStore:
                   else tuple(_splice(s, 1) for s in self.scales))
         return replace(self, tables=tables, age=age,
                        scales=scales).place()
-
-    @classmethod
-    def from_histories(cls, hist: Histories,
-                       backend: Optional[str] = None) -> "HistoryStore":
-        from repro.kernels import ops
-        return cls(tables=tuple(hist.tables), age=hist.age,
-                   backend=ops.resolve_backend(backend))
-
-    def to_histories(self) -> Histories:
-        if get_codec(self.history_dtype).scaled:
-            raise ValueError(
-                f"{self.history_dtype} HistoryStore cannot round-trip "
-                "through the legacy Histories tuple (it has no "
-                "scale/codebook tables)")
-        return Histories(tables=list(self.tables), age=self.age)
 
     @property
     def num_layers(self) -> int:
@@ -698,9 +663,7 @@ class HistoryStore:
     def tick(self, batch_idx: jnp.ndarray,
              mask: jnp.ndarray) -> "HistoryStore":
         """Advance the staleness clock (age += 1, just-pushed rows -> 0)."""
-        age = tick(Histories(tables=list(self.tables), age=self.age),
-                   batch_idx, mask)
-        return replace(self, age=age)
+        return replace(self, age=tick(self.age, batch_idx, mask))
 
     def patch_pulled(self, pulled: Tuple, halo_nodes: jnp.ndarray,
                      halo_mask: jnp.ndarray, batch_nodes: jnp.ndarray,

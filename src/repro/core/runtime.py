@@ -4,10 +4,8 @@ The runtime splits GAS training into three typed layers:
 
   * `GASConfig` — every knob in one frozen record: partitioning
     (`num_parts`/`partitioner`/`clusters_per_batch`), execution
-    (`backend`/`fuse_halo`/`use_history`/`fused_epoch`) and optimization
-    (`lr`/`weight_decay`/`grad_clip`/`epochs`/`seed`). This absorbs the
-    toggle sprawl that used to live as six interacting `GASTrainer`
-    kwargs plus a separate `TrainConfig`.
+    (`backend`/`fuse_halo`/`use_history`/`prefetch_depth`/`ranks`) and
+    optimization (`lr`/`weight_decay`/`grad_clip`/`epochs`/`seed`).
   * `GASPlan` — everything *built once* from (graph, spec, config): the
     partition, the stacked `GASBatch` structures (host + device), the
     resolved kernel backend, padding bounds for regrouped epochs, the
@@ -23,12 +21,12 @@ The step surface is pure and jit-donatable:
 
     state, metrics = train_step(plan, state, batch)    # one cluster batch
     state, metrics = train_epoch(plan, state, epoch)   # shuffled epoch
+    state, history = fit(plan, state)                  # config.epochs
     logits         = predict(plan, state)              # constant-memory
     accs           = evaluate_exact(plan, state)       # full propagation
 
-`train.gas_trainer.GASTrainer` is a thin convenience shell over these;
-new training scenarios (WaveGAS-style multi-pass relaxation, sharded or
-serving deployments) should compose against this module directly.
+An epoch is one jitted program: a `lax.scan` over the batches on one
+chip, a `shard_map` of supersteps on `ranks > 1` chips.
 """
 from __future__ import annotations
 
@@ -86,12 +84,16 @@ class GASConfig(HistoryExecConfig):
     tables are row-sharded over a mesh of the plan's devices, and an
     epoch is one jitted `shard_map` of P/R supersteps in which every rank
     trains one of its parts on the same kernels as one chip. `ranks=1`
-    is the one-chip path."""
+    is the one-chip path.
+
+    `fused_epoch` selects nothing: every epoch is one jitted program (see
+    `train_epoch`), and False is refused; `train_step` runs one step at
+    a time."""
     num_parts: int
     partitioner: str = "metis"          # "metis" | "random"
     clusters_per_batch: int = 1
     use_history: bool = True
-    fused_epoch: bool = False
+    fused_epoch: bool = True
     fuse_halo: bool = True
     vq_refit_every: int = 0              # epochs between vq codebook refits
     # drift-triggered vq refit: also refit whenever the previous epoch's
@@ -111,6 +113,14 @@ class GASConfig(HistoryExecConfig):
     grad_clip: float = 2.0
     epochs: int = 100
     seed: int = 0
+
+    def __post_init__(self):
+        super().__post_init__()
+        if not self.fused_epoch:
+            raise ValueError(
+                "fused_epoch=False: the per-step epoch loop was removed; "
+                "an epoch is one jitted scan (train_epoch), and one step "
+                "at a time is train_step")
 
 
 @functools.partial(
@@ -162,7 +172,6 @@ class GASPlan:
     _step: Optional[Callable] = None
     _predict: Optional[Callable] = None
     _epoch: Optional[Callable] = None
-    _pf_step: Optional[Callable] = None
 
     def batch(self, b) -> GASBatch:
         """One device batch off the stack."""
@@ -479,17 +488,17 @@ def train_step(plan: GASPlan, state: GASState,
 
 def train_epoch(plan: GASPlan, state: GASState, epoch: int
                 ) -> Tuple[GASState, Dict[str, float]]:
-    """One shuffled epoch over every cluster batch. With
-    `config.fused_epoch` the whole epoch is a single jitted
-    `lax.scan` dispatch; otherwise one `train_step` per batch.
+    """One shuffled epoch over every cluster batch, as one jitted
+    `lax.scan` dispatch on one chip: the same steps, in the same order,
+    as a loop of `train_step` over the batches.
 
-    With `config.prefetch_depth > 0` the epoch is software-pipelined
+    With `config.prefetch_depth > 0` the scan is software-pipelined
     (see `make_prefetch_step_fn`): a prologue dispatches the first
     `depth` batches' halo pulls, then every step prefetches batch
     i+depth's halo before running batch i — so history I/O rides behind
     compute, the paper's §5 concurrent execution at the epoch level.
     Bit-identical to the synchronous schedule (state, metrics, and
-    checkpoint round-trips), fused or not.
+    checkpoint round-trips).
 
     With `config.ranks > 1` the epoch is the sharded one
     (`dist_gas.make_epoch_fn`): one jitted call of P/R supersteps, whose
@@ -541,73 +550,50 @@ def _dispatch_epoch(plan: GASPlan, state: GASState, order: np.ndarray
                                        plan.mesh), donate_argnums=(0,))
         return plan._epoch(state, plan.batch_stack, jnp.asarray(order),
                            plan.x, plan.y, plan.train_mask)
-    depth = _resolved_depth(plan)
-    if cfg.fused_epoch:
-        if plan._epoch is None:
-            if depth == 0:
-                step = make_step_fn(plan)
+    if plan._epoch is None:
+        depth = _resolved_depth(plan)
+        if depth == 0:
+            step = make_step_fn(plan)
 
-                @functools.partial(jax.jit, donate_argnums=(0,))
-                def epoch_fn(state, batch_stack, order, x, y, train_mask):
-                    def body(st, idx):
-                        batch = jax.tree_util.tree_map(lambda a: a[idx],
-                                                       batch_stack)
-                        st, metrics = step(st, batch, x, y, train_mask)
-                        return st, metrics
+            @functools.partial(jax.jit, donate_argnums=(0,))
+            def epoch_fn(state, batch_stack, order, x, y, train_mask):
+                def body(st, idx):
+                    batch = jax.tree_util.tree_map(lambda a: a[idx],
+                                                   batch_stack)
+                    st, metrics = step(st, batch, x, y, train_mask)
+                    return st, metrics
 
-                    return jax.lax.scan(body, state, order)
-            else:
-                pf_step = make_prefetch_step_fn(plan, depth)
+                return jax.lax.scan(body, state, order)
+        else:
+            pf_step = make_prefetch_step_fn(plan, depth)
 
-                @functools.partial(jax.jit, donate_argnums=(0,))
-                def epoch_fn(state, batch_stack, order, x, y, train_mask):
-                    def get(i):
-                        return jax.tree_util.tree_map(lambda a: a[i],
-                                                      batch_stack)
+            @functools.partial(jax.jit, donate_argnums=(0,))
+            def epoch_fn(state, batch_stack, order, x, y, train_mask):
+                def get(i):
+                    return jax.tree_util.tree_map(lambda a: a[i],
+                                                  batch_stack)
 
-                    # prologue: the first `depth` batches' pulls are in
-                    # flight before any step runs
-                    queue = tuple(
-                        _prefetch_entry(state.histories, get(order[j]))
-                        for j in range(depth))
+                # prologue: the first `depth` batches' pulls are in
+                # flight before any step runs
+                queue = tuple(
+                    _prefetch_entry(state.histories, get(order[j]))
+                    for j in range(depth))
 
-                    def body(carry, inp):
-                        st, q = carry
-                        idx, fidx = inp
-                        st, metrics, q = pf_step(st, get(idx), get(fidx),
-                                                 q, x, y, train_mask)
-                        return (st, q), metrics
+                def body(carry, inp):
+                    st, q = carry
+                    idx, fidx = inp
+                    st, metrics, q = pf_step(st, get(idx), get(fidx),
+                                             q, x, y, train_mask)
+                    return (st, q), metrics
 
-                    (state, _), metrics = jax.lax.scan(
-                        body, (state, queue),
-                        (order, jnp.roll(order, -depth)))
-                    return state, metrics
+                (state, _), metrics = jax.lax.scan(
+                    body, (state, queue),
+                    (order, jnp.roll(order, -depth)))
+                return state, metrics
 
-            plan._epoch = epoch_fn
-        return plan._epoch(state, plan.batch_stack, jnp.asarray(order),
-                           plan.x, plan.y, plan.train_mask)
-    if depth > 0:
-        if plan._pf_step is None:
-            plan._pf_step = jax.jit(make_prefetch_step_fn(plan, depth),
-                                    donate_argnums=(0, 3))
-        queue = tuple(
-            _prefetch_entry(state.histories,
-                            plan.batch_stack[int(order[j])])
-            for j in range(depth))
-        agg = []
-        nb = len(order)
-        for i, b in enumerate(order):
-            fb = plan.batch_stack[int(order[(i + depth) % nb])]
-            state, metrics, queue = plan._pf_step(
-                state, plan.batch_stack[int(b)], fb, queue, plan.x,
-                plan.y, plan.train_mask)
-            agg.append(metrics)
-        return state, {k: [m[k] for m in agg] for k in agg[0]}
-    agg = []
-    for b in order:
-        state, metrics = train_step(plan, state, plan.batch_stack[int(b)])
-        agg.append(metrics)
-    return state, {k: [m[k] for m in agg] for k in agg[0]}
+        plan._epoch = epoch_fn
+    return plan._epoch(state, plan.batch_stack, jnp.asarray(order),
+                       plan.x, plan.y, plan.train_mask)
 
 
 def _epoch_metrics(plan: GASPlan, out: Dict[str, float]) -> Dict[str, float]:
@@ -633,8 +619,8 @@ def fit(plan: GASPlan, state: GASState, epochs: Optional[int] = None,
 
 def predict(plan: GASPlan, state: GASState) -> jnp.ndarray:
     """Constant-memory history-based inference (paper advantage #2): one
-    jitted dispatch, `lax.scan` over the stacked batches. Histories are
-    NOT donated — `state` stays valid for further training."""
+    jitted dispatch, `lax.scan` over the stacked batches. The history
+    store is NOT donated — `state` stays valid for further training."""
     from repro.gnn.model import gas_batch_forward
     _one_chip(plan, "predict")
 

@@ -97,7 +97,7 @@ class ServeConfig(HistoryExecConfig):
     `core.config.HistoryExecConfig`: `staleness_slo` (overridden default
     0 — max acceptable history age of any served halo row; 0 refreshes
     to exactness, None never refreshes), `backend` (None = bound store's
-    backend wins, via `gas.resolve_store`) and `history_dtype` (None =
+    backend wins, via `gnn.model.resolve_store`) and `history_dtype` (None =
     bound store's dtype wins; set it to make `init_serve_state` reject a
     store of any other precision). `buckets`: query-size pads (requests
     round up to the next bucket so assorted batch sizes share jit

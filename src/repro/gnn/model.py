@@ -15,16 +15,14 @@ from __future__ import annotations
 import dataclasses
 import math
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple, Union
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import history as H
-from repro.core.batch import GASBatch
-from repro.core.gas import (ensure_batch, materialize_x_all, resolve_store,
-                            staleness_diags)
+from repro.core.batch import GASBatch, ensure_batch
 from repro.kernels import ops
 from . import layers as L
 
@@ -242,9 +240,60 @@ def _halo_prop(params, spec: GNNSpec, ell: int, x_cur,
 # GAS batch execution (Algorithm 1)
 # ---------------------------------------------------------------------------
 
+def staleness_diags(age: jnp.ndarray, halo_nodes: jnp.ndarray,
+                    halo_mask: jnp.ndarray) -> Dict[str, jnp.ndarray]:
+    """Mean/max history age (iterations since last push) of the halo rows
+    this batch pulls — the staleness that Lemma 1 / Theorem 2 bound."""
+    hage = jnp.take(age, halo_nodes, mode="clip").astype(jnp.float32)
+    valid = halo_mask.astype(jnp.float32)
+    n = jnp.maximum(jnp.sum(valid), 1.0)
+    return {"halo_age_mean": jnp.sum(hage * valid) / n,
+            "halo_age_max": jnp.max(hage * valid)}
+
+
+def resolve_store(store: H.HistoryStore, backend: Optional[str]
+                  ) -> Tuple[H.HistoryStore, str]:
+    """The store and the backend to run on: the store's own bound
+    backend when `backend` is None, else `backend` resolved and bound to
+    a copy of the store."""
+    if backend is None:
+        return store, store.backend
+    backend = ops.resolve_backend(backend)
+    if backend != store.backend:
+        store = dataclasses.replace(store, backend=backend)
+    return store, backend
+
+
+def materialize_x_all(ell: int, x_cur: jnp.ndarray, xh: jnp.ndarray,
+                      store: H.HistoryStore, batch: GASBatch,
+                      use_history: bool,
+                      halo_scale: Optional[jnp.ndarray] = None
+                      ) -> jnp.ndarray:
+    """Unfused layer input `x_all = [x_cur ; halo_rows ; dummy-zero row]`:
+    layer 0 uses the exact precomputed halo rows `xh`; layers >= 1 pull
+    stale rows from the previous layer's history table (dequantized for
+    compressed stores; zeros when history is off). `halo_scale` [max_h],
+    when given, damps the pulled rows (haste-makes-waste staleness
+    compensation — see `GASConfig.halo_age_decay`); layer-0 halo rows are
+    exact raw features and are never scaled."""
+    if ell == 0:
+        halo_rows = xh
+    elif use_history:
+        halo_rows = store.pull(ell - 1, batch.halo_nodes)
+        halo_rows = halo_rows.astype(x_cur.dtype) * \
+            batch.halo_mask[:, None]
+        if halo_scale is not None:
+            halo_rows = halo_rows * halo_scale[:, None]
+    else:
+        halo_rows = jnp.zeros((batch.halo_nodes.shape[0],
+                               x_cur.shape[-1]), x_cur.dtype)
+    dummy = jnp.zeros((1, x_cur.shape[-1]), x_cur.dtype)
+    return jnp.concatenate([x_cur, halo_rows, dummy], axis=0)
+
+
 def gas_batch_forward(params, spec: GNNSpec, x_global: jnp.ndarray,
                       batch: GASBatch,
-                      hist: Union[H.HistoryStore, H.Histories],
+                      hist: H.HistoryStore,
                       use_history: bool = True,
                       rng: Optional[jax.Array] = None,
                       backend: Optional[str] = None,
@@ -253,8 +302,7 @@ def gas_batch_forward(params, spec: GNNSpec, x_global: jnp.ndarray,
                       halo_age_decay: float = 0.0,
                       return_pushed: bool = False,
                       apply_pushes: bool = True,
-                      ) -> Tuple[jnp.ndarray,
-                                 Union[H.HistoryStore, H.Histories],
+                      ) -> Tuple[jnp.ndarray, H.HistoryStore,
                                  jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Returns (logits [max_b, C], new histories, Eq.3 reg loss,
     diagnostics — mean/max history age of the pulled halo rows plus the
@@ -263,10 +311,8 @@ def gas_batch_forward(params, spec: GNNSpec, x_global: jnp.ndarray,
     `return_pushed=True`, a 5th element: the per-hidden-layer pushed
     payload tuple (what `HistoryStore.patch_pulled` consumes).
 
-    `batch` is a single-batch `GASBatch`; `hist` is a `HistoryStore` —
-    whose bound backend is used when `backend` is None — or a legacy
-    `Histories`, and the updated histories come back as whichever type
-    went in.
+    `batch` is a single-batch `GASBatch`; `hist` is a `HistoryStore`,
+    whose bound backend is used when `backend` is None.
 
     The resolved backend selects the kernel path for history I/O and the
     aggregation — BCSR SpMM for the weighted-sum ops, the edge-softmax /
@@ -314,7 +360,7 @@ def gas_batch_forward(params, spec: GNNSpec, x_global: jnp.ndarray,
     rows), so 0.0 is bit-identical to no compensation.
     """
     batch = ensure_batch(batch)
-    store, legacy_hist, backend = resolve_store(hist, backend)
+    store, backend = resolve_store(hist, backend)
     bmask = batch.batch_mask
     hmask = batch.halo_mask
     edges = (batch.edge_dst, batch.edge_src)
@@ -419,10 +465,9 @@ def gas_batch_forward(params, spec: GNNSpec, x_global: jnp.ndarray,
     if apply_pushes:
         store = store.tick(batch.batch_nodes, bmask)
     logits = _post(params, spec, x_cur)
-    out_hist = store.to_histories() if legacy_hist else store
     if return_pushed:
-        return logits, out_hist, reg, diags, tuple(pushed_rows)
-    return logits, out_hist, reg, diags
+        return logits, store, reg, diags, tuple(pushed_rows)
+    return logits, store, reg, diags
 
 
 # ---------------------------------------------------------------------------

@@ -1,25 +1,101 @@
-"""Scalability baselines the paper compares against (Tables 3/5):
+"""The baselines the paper compares GAS against (Tables 1/3/5), and their
+`TrainConfig`; GAS itself trains through `core.runtime`:
 
+  - FullBatchTrainer — full-graph training with exact propagation: the
+    accuracy reference of Table 1.
   - GraphSAGETrainer — node-wise neighbor sampling (Hamilton et al., 2017):
     recursive fixed-fanout L-hop mini-batches; drops edges, working set
     grows ~fanout^L (the neighbor-explosion regime GAS eliminates).
   - SGCTrainer — Simplifying Graph Convolution (Wu et al., 2019):
     non-trainable propagation Â^K X precomputed once, then logistic
     regression; fast but provably less expressive (no trainable MESSAGE).
-  - CLUSTER-GCN is GASTrainer(use_history=False) — intra-cluster edges only.
+  - CLUSTER-GCN is `GASConfig(use_history=False)` — intra-cluster edges
+    only.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.gas import gcn_edge_weights
+from repro.core.runtime import _accuracy
 from repro.data.graphs import Graph
 from repro.gnn import layers as L
+from repro.gnn.model import GNNSpec, full_forward, init_gnn
 from .optimizer import adamw_init, adamw_update, clip_by_global_norm
-from .gas_trainer import TrainConfig, _accuracy
+
+
+@dataclass
+class TrainConfig:
+    lr: float = 0.01
+    weight_decay: float = 5e-4
+    grad_clip: float = 2.0
+    epochs: int = 100
+    seed: int = 0
+
+
+# ---------------------------------------------------------------------------
+# Full-batch training
+# ---------------------------------------------------------------------------
+
+class FullBatchTrainer:
+    def __init__(self, graph: Graph, spec: GNNSpec,
+                 tcfg: Optional[TrainConfig] = None):
+        tcfg = TrainConfig() if tcfg is None else tcfg
+        self.graph, self.spec, self.tcfg = graph, spec, tcfg
+        dst, src, w = gcn_edge_weights(graph)
+        self.edges = (jnp.asarray(dst), jnp.asarray(src))
+        self.edge_w = jnp.asarray(w)
+        self.x = jnp.asarray(graph.x)
+        self.y = jnp.asarray(graph.y)
+        self.masks = {n: jnp.asarray(m) for n, m in
+                      (("train", graph.train_mask), ("val", graph.val_mask),
+                       ("test", graph.test_mask))}
+        key = jax.random.key(tcfg.seed)
+        self.params = init_gnn(key, spec)
+        self.opt_state = adamw_init(self.params)
+        self._step = jax.jit(self._make_step())
+
+    def _make_step(self):
+        spec, tcfg, N = self.spec, self.tcfg, self.graph.num_nodes
+
+        def step(params, opt_state, x, y, train_mask, edges, edge_w):
+            def loss_fn(p):
+                logits = full_forward(p, spec, x, edges, edge_w, N)
+                logz = jax.scipy.special.logsumexp(logits, axis=-1)
+                gold = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+                ce = jnp.sum((logz - gold) * train_mask) / \
+                    jnp.maximum(jnp.sum(train_mask), 1)
+                return ce, _accuracy(logits, y, train_mask)
+
+            (loss, acc), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
+            grads, _ = clip_by_global_norm(grads, tcfg.grad_clip)
+            params, opt_state = adamw_update(
+                grads, opt_state, params, lr=tcfg.lr, b1=0.9, b2=0.999,
+                weight_decay=tcfg.weight_decay)
+            return params, opt_state, {"loss": loss, "acc": acc}
+
+        return step
+
+    def fit(self, epochs: Optional[int] = None) -> List[Dict[str, float]]:
+        out = []
+        for _ in range(epochs or self.tcfg.epochs):
+            self.params, self.opt_state, m = self._step(
+                self.params, self.opt_state, self.x, self.y,
+                self.masks["train"], self.edges, self.edge_w)
+            out.append({k: float(v) for k, v in m.items()})
+        return out
+
+    def evaluate(self) -> Dict[str, float]:
+        logits = full_forward(self.params, self.spec, self.x, self.edges,
+                              self.edge_w, self.graph.num_nodes)
+        return {f"{n}_acc": float(_accuracy(logits, self.y, m))
+                for n, m in self.masks.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -156,7 +232,6 @@ class GraphSAGETrainer:
 
     def evaluate(self) -> Dict[str, float]:
         """Exact full-graph inference (no sampling at test time)."""
-        from repro.core.gas import gcn_edge_weights
         dst, src, w = gcn_edge_weights(self.g)
         h = jnp.asarray(self.g.x)
         for ell in range(self.L):
@@ -181,7 +256,6 @@ class GraphSAGETrainer:
 class SGCTrainer:
     def __init__(self, graph: Graph, k: int = 2,
                  tcfg: Optional[TrainConfig] = None):
-        from repro.core.gas import gcn_edge_weights
         tcfg = TrainConfig() if tcfg is None else tcfg
         self.g, self.tcfg = graph, tcfg
         dst, src, w = gcn_edge_weights(graph)

@@ -305,16 +305,17 @@ def test_gas_forward_backend_equivalence(dtype, tol, d_hidden):
         np.testing.assert_allclose(ti, tj, rtol=tol, atol=tol)
 
 
-def test_gas_trainer_backend_equivalence():
+def test_gas_training_backend_equivalence():
     """Full jitted train steps agree between backends (fwd+bwd+AdamW)."""
-    from repro.train.gas_trainer import GASTrainer, TrainConfig
+    from repro.core import runtime as R
     g, _ = _citation_batches(n=200, parts=2)
     spec = GNNSpec(op="gcn", d_in=16, d_hidden=16, num_classes=4,
                    num_layers=2)
     losses = {}
     for backend in ("jnp", "interpret"):
-        tr = GASTrainer(g, spec, num_parts=2, backend=backend,
-                        tcfg=TrainConfig(epochs=2, seed=0))
-        losses[backend] = [m["loss"] for m in tr.fit(2)]
+        plan = R.build_plan(g, spec, R.GASConfig(
+            num_parts=2, backend=backend, epochs=2, seed=0))
+        _, out = R.fit(plan, R.init_state(plan))
+        losses[backend] = [m["loss"] for m in out]
     np.testing.assert_allclose(losses["interpret"], losses["jnp"],
                                rtol=1e-4, atol=1e-4)

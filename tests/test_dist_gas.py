@@ -425,10 +425,9 @@ def test_rank_one_is_the_one_chip_path():
     spec = GNNSpec(op="gcn", d_in=8, d_hidden=16, num_classes=3,
                    num_layers=3)
     out = []
-    for cfg in (R.GASConfig(num_parts=4, backend="interpret",
-                            fused_epoch=True, seed=2),
-                R.GASConfig(num_parts=4, backend="interpret",
-                            fused_epoch=True, seed=2, ranks=1)):
+    for cfg in (R.GASConfig(num_parts=4, backend="interpret", seed=2),
+                R.GASConfig(num_parts=4, backend="interpret", seed=2,
+                            ranks=1)):
         plan = R.build_plan(g, spec, cfg)
         assert plan.mesh is None and plan.node_rows is None
         state = R.init_state(plan)

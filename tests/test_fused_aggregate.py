@@ -25,6 +25,7 @@ import pytest
 
 from repro.core import gas as G
 from repro.core import history as H
+from repro.core import runtime as R
 from repro.data.graphs import citation_graph
 from repro.gnn.model import (BLOCK_OPS, UNIT_BLOCK_OPS, GNNSpec,
                              gas_batch_forward, init_gnn)
@@ -726,22 +727,21 @@ def test_gas_batch_forward_fused_matches_jnp(op):
 # ---------------------------------------------------------------------------
 
 def test_gas_predict_jitted_scan_matches_manual_loop():
-    from repro.train.gas_trainer import GASTrainer, TrainConfig
     g = citation_graph(num_nodes=200, num_features=16, num_classes=4, seed=6)
     spec = GNNSpec(op="gcn", d_in=16, d_hidden=16, num_classes=4,
                    num_layers=3)
-    tr = GASTrainer(g, spec, num_parts=3, backend="jnp",
-                    tcfg=TrainConfig(epochs=2, seed=0))
-    tr.fit(2)
-    got = np.asarray(tr.gas_predict())
+    plan = R.build_plan(g, spec, R.GASConfig(num_parts=3, backend="jnp",
+                                             epochs=2, seed=0))
+    state, _ = R.fit(plan, R.init_state(plan), 2)
+    got = np.asarray(R.predict(plan, state))
 
     N, C = g.num_nodes, spec.num_classes
     expect = np.zeros((N, C), np.float32)
-    hist = tr.hist
-    for bi in range(tr.batches.num_batches):
-        batch = tr.batch_stack[bi]
+    hist = state.histories
+    for bi in range(plan.batches.num_batches):
+        batch = plan.batch_stack[bi]
         logits, hist, _, _ = gas_batch_forward(
-            tr.params, spec, tr.x, batch, hist, backend="jnp")
+            state.params, spec, plan.x, batch, hist, backend="jnp")
         nodes = np.asarray(batch.batch_nodes)
         mask = np.asarray(batch.batch_mask)
         expect[nodes[mask]] = np.asarray(logits)[mask]
@@ -749,60 +749,59 @@ def test_gas_predict_jitted_scan_matches_manual_loop():
 
 
 def test_staleness_diags_in_train_metrics():
-    from repro.train.gas_trainer import GASTrainer, TrainConfig
     g = citation_graph(num_nodes=200, num_features=16, num_classes=4, seed=6)
     spec = GNNSpec(op="gcn", d_in=16, d_hidden=16, num_classes=4,
                    num_layers=3)
-    tr = GASTrainer(g, spec, num_parts=4, tcfg=TrainConfig(epochs=3, seed=0))
-    m0 = tr.train_epoch(0)
+    plan = R.build_plan(g, spec, R.GASConfig(num_parts=4, epochs=3, seed=0))
+    state = R.init_state(plan)
+    state, m0 = R.train_epoch(plan, state, 0)
     assert {"halo_age_mean", "halo_age_max"} <= set(m0)
-    m2 = tr.train_epoch(1), tr.train_epoch(2)
+    state, _ = R.train_epoch(plan, state, 1)
+    state, m2 = R.train_epoch(plan, state, 2)
     # after warmup, pulled halo rows are genuinely stale (age > 0) and the
     # max is at least the mean
-    assert m2[1]["halo_age_mean"] > 0.0
-    assert m2[1]["halo_age_max"] >= m2[1]["halo_age_mean"]
+    assert m2["halo_age_mean"] > 0.0
+    assert m2["halo_age_max"] >= m2["halo_age_mean"]
 
 
 def test_gas_forward_diags_and_fused_hook():
-    """core.gas.gas_forward populates staleness diags, and its
-    fused_layer_apply hook produces the same outputs as the materialized
-    path (single GCN-style weighted-sum layer stack)."""
+    """`gas_batch_forward` populates the staleness and quantization
+    diagnostics, and its fused route (`fuse_halo=True`: layers >= 1
+    aggregate straight out of the history tables) gives the same logits
+    and pushes the same rows as the materialized route, on a second batch
+    that pulls the first batch's pushes."""
     g = citation_graph(num_nodes=200, num_features=16, num_classes=4, seed=2)
     part = np.random.default_rng(0).integers(0, 2, g.num_nodes)
     part = np.unique(part, return_inverse=True)[1].astype(np.int32)
     b = G.build_batches(g, part, build_blocks=True)
-    batch = b.device_batch(0)
+    assert len(b.device_batch(0).blocks) == 4   # transposed family present
+    spec = GNNSpec(op="gcn", d_in=16, d_hidden=16, num_classes=4,
+                   num_layers=3)
+    params = init_gnn(jax.random.key(0), spec)
     x = jnp.asarray(g.x)
-    hist = H.HistoryStore.create(g.num_nodes + 1, [16, 16],
-                                 backend="interpret")
-    key = jax.random.key(0)
-    ws = [jax.random.normal(jax.random.fold_in(key, i), (16, 16)) * 0.1
-          for i in range(3)]
-    blocks = batch.blocks
-    assert len(blocks) == 4          # transposed family present -> 4-tuple
 
-    def layer_apply(ell, x_all, bt):
-        agg = ops.gcn_aggregate(x_all, (bt.edge_dst, bt.edge_src),
-                                bt.edge_w, b.max_b, blocks,
-                                backend="interpret")
-        return agg @ ws[ell]
-
-    def fused_layer_apply(ell, x_cur, halo_src, bt):
-        table, scales, codebook, hn, hm = halo_src
-        agg = ops.gas_aggregate(x_cur, table, hn, hm, b.max_b, blocks,
-                                scales=scales, codebook=codebook,
-                                backend="interpret")
-        return agg @ ws[ell]
-
-    out_a, hist_a, diags = G.gas_forward(layer_apply, 3, x, batch, hist,
-                                         backend="interpret")
-    assert set(diags) == {"halo_age_mean", "halo_age_max",
-                          "hist_quant_err"}
-    out_b, hist_b, _ = G.gas_forward(layer_apply, 3, x, batch, hist,
+    outs = {}
+    for fuse in (False, True):
+        hist = H.HistoryStore.create(g.num_nodes + 1, spec.hist_dims(),
                                      backend="interpret",
-                                     fused_layer_apply=fused_layer_apply)
-    np.testing.assert_allclose(np.asarray(out_b), np.asarray(out_a),
-                               rtol=1e-4, atol=1e-4)
+                                     history_dtype="f32")
+        logits = []
+        for bb in range(b.num_batches):
+            lg, hist, _, diags = gas_batch_forward(
+                params, spec, x, b.device_batch(bb), hist,
+                backend="interpret", fuse_halo=fuse)
+            logits.append(np.asarray(lg))
+            assert set(diags) == {"halo_age_mean", "halo_age_max",
+                                  "hist_quant_err"}
+        assert all(np.abs(np.asarray(t)).sum() > 0 for t in hist.tables)
+        outs[fuse] = (logits, hist)
+    for a, c in zip(outs[True][0], outs[False][0]):
+        np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-4)
+    for a, c in zip(outs[True][1].tables, outs[False][1].tables):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(outs[True][1].age),
+                                  np.asarray(outs[False][1].age))
 
 
 def test_fused_vq_aggregate_refused_on_pallas():

@@ -492,12 +492,6 @@ def test_resolve_history_dtype_env(monkeypatch):
         H.resolve_history_dtype(None)
 
 
-def test_int8_store_rejects_legacy_histories_export():
-    store = H.HistoryStore.create(8, [4], history_dtype="int8")
-    with pytest.raises(ValueError):
-        store.to_histories()
-
-
 def test_vq_rejects_indivisible_widths():
     """Product quantization needs d % VQ_SUBDIM == 0 (d is recovered from
     the codebook shape); anything else fails loudly at creation."""

@@ -10,8 +10,8 @@ core/runtime.py plan/state/step):
    reference free functions;
  - GASState checkpoint round-trip: save -> restore -> one more train_step
    bit-identical to uninterrupted training;
- - plan/state/step surface: train_step/train_epoch/predict agree with
-   the GASTrainer shell, and GASConfig consolidates the toggles.
+ - plan/state/step surface: GASConfig consolidates the toggles and
+   refuses the removed per-step epoch loop (`fused_epoch=False`).
 """
 import jax
 import jax.numpy as jnp
@@ -21,7 +21,7 @@ import pytest
 from repro.core import gas as G
 from repro.core import history as H
 from repro.core import runtime as R
-from repro.core.batch import GASBatch
+from repro.core.batch import GASBatch, ensure_batch
 from repro.data.graphs import citation_graph
 from repro.gnn.model import GNNSpec
 from repro.train.checkpoint import load_gas_state, save_gas_state
@@ -116,11 +116,11 @@ def test_executors_reject_non_gasbatch():
     assert not hasattr(G, "coerce_batch")
     assert not hasattr(GASBatch, "from_legacy")
     with pytest.raises(TypeError):
-        G.ensure_batch([1, 2, 3])
+        ensure_batch([1, 2, 3])
     with pytest.raises(TypeError):
-        G.ensure_batch({"batch_nodes": np.zeros(3)})
+        ensure_batch({"batch_nodes": np.zeros(3)})
     _, b = _graph_and_batches()
-    assert G.ensure_batch(b) is b
+    assert ensure_batch(b) is b
 
 
 # ---------------------------------------------------------------------------
@@ -220,43 +220,17 @@ def test_build_plan_reuses_a_given_partition():
                      part=plan.part[:-1])
 
 
-def test_runtime_matches_trainer_shell():
-    """GASTrainer is a thin shell: running the runtime surface directly
-    reproduces its training trajectory exactly."""
-    from repro.train.gas_trainer import GASTrainer, TrainConfig
-    g = citation_graph(num_nodes=150, num_features=16, num_classes=4,
-                       seed=11)
-    spec = GNNSpec(op="gcn", d_in=16, d_hidden=16, num_classes=4,
-                   num_layers=3)
-    tr = GASTrainer(g, spec, num_parts=3, backend="jnp",
-                    tcfg=TrainConfig(epochs=2, seed=0))
-    m_shell = [m["loss"] for m in tr.fit(2)]
-
-    plan, state = _small_plan()
-    losses = []
-    for e in range(2):
-        state, m = R.train_epoch(plan, state, e)
-        losses.append(m["loss"])
-    np.testing.assert_allclose(losses, m_shell, rtol=0, atol=0)
-    got = np.asarray(R.predict(plan, state))
-    np.testing.assert_allclose(got, np.asarray(tr.gas_predict()),
-                               rtol=0, atol=0)
-    assert R.evaluate_exact(plan, state) == tr.evaluate()
-
-
 def test_gasconfig_consolidates_toggles():
-    plan, state = _small_plan(fuse_halo=False, use_history=False,
-                              fused_epoch=True)
+    plan, state = _small_plan(fuse_halo=False, use_history=False)
     assert plan.config.fused_epoch and not plan.config.fuse_halo
-    state, m = R.train_epoch(plan, state, 0)   # single fused dispatch
+    state, m = R.train_epoch(plan, state, 0)   # single scan dispatch
     assert np.isfinite(m["loss"])
-    # trainer kwargs land in the same consolidated record
-    from repro.train.gas_trainer import GASTrainer
-    tr = GASTrainer(plan.graph, plan.spec, num_parts=3, backend="jnp",
-                    fuse_halo=False, use_history=False, fused_epoch=True)
-    assert isinstance(tr.config, R.GASConfig)
-    assert (tr.config.fuse_halo, tr.config.use_history,
-            tr.config.fused_epoch) == (False, False, True)
+    # the per-step epoch loop is gone: asking for it is refused
+    with pytest.raises(ValueError, match="train_step"):
+        R.GASConfig(num_parts=2, fused_epoch=False)
+    # the base class's checks still run
+    with pytest.raises(ValueError):
+        R.GASConfig(num_parts=2, backend="cuda")
 
 
 def test_trainer_tcfg_not_shared_between_instances():
@@ -264,15 +238,15 @@ def test_trainer_tcfg_not_shared_between_instances():
     module-import-time instance; mutations leaked across trainers."""
     import inspect
 
-    from repro.train.gas_trainer import FullBatchTrainer, GASTrainer
-    for cls in (GASTrainer, FullBatchTrainer):
-        default = inspect.signature(cls.__init__).parameters["tcfg"].default
-        assert default is None, cls
+    from repro.train.baselines import FullBatchTrainer
+    default = inspect.signature(
+        FullBatchTrainer.__init__).parameters["tcfg"].default
+    assert default is None
     g = citation_graph(num_nodes=120, num_features=8, num_classes=3, seed=1)
     spec = GNNSpec(op="gcn", d_in=8, d_hidden=8, num_classes=3,
                    num_layers=2)
-    a = GASTrainer(g, spec, num_parts=2, backend="jnp")
-    b = GASTrainer(g, spec, num_parts=2, backend="jnp")
+    a = FullBatchTrainer(g, spec)
+    b = FullBatchTrainer(g, spec)
     assert a.tcfg is not b.tcfg
     a.tcfg.lr = 123.0
     assert b.tcfg.lr != 123.0

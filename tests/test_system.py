@@ -7,9 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import runtime as R
 from repro.data.graphs import citation_graph, sbm_cluster_graph
 from repro.gnn.model import GNNSpec
-from repro.train.gas_trainer import FullBatchTrainer, GASTrainer, TrainConfig
+from repro.train.baselines import FullBatchTrainer, TrainConfig
 
 
 @pytest.fixture(scope="module")
@@ -29,9 +30,10 @@ def test_gas_matches_full_batch_gcn(hard_graph):
     fb.fit()
     acc_full = fb.evaluate()["test_acc"]
 
-    gas = GASTrainer(g, spec, num_parts=8, partitioner="metis", tcfg=tcfg)
-    gas.fit()
-    acc_gas = gas.evaluate()["test_acc"]
+    plan = R.build_plan(g, spec, R.GASConfig(
+        num_parts=8, partitioner="metis", epochs=80, lr=0.01, seed=0))
+    state, _ = R.fit(plan, R.init_state(plan))
+    acc_gas = R.evaluate_exact(plan, state)["test_acc"]
     assert acc_gas > acc_full - 0.05, (acc_full, acc_gas)
 
 
@@ -41,11 +43,11 @@ def test_gas_on_sbm_cluster_gin():
     g = sbm_cluster_graph(num_nodes=900, num_communities=6, seed=1)
     spec = GNNSpec(op="gin", d_in=g.x.shape[1], d_hidden=64,
                    num_classes=g.num_classes, num_layers=4)
-    tcfg = TrainConfig(epochs=60, lr=0.005, seed=0)
-    gas = GASTrainer(g, spec, num_parts=24, partitioner="metis",
-                     clusters_per_batch=8, tcfg=tcfg)
-    gas.fit()
-    acc = gas.evaluate()["test_acc"]
+    plan = R.build_plan(g, spec, R.GASConfig(
+        num_parts=24, partitioner="metis", clusters_per_batch=8, epochs=60,
+        lr=0.005, seed=0))
+    state, _ = R.fit(plan, R.init_state(plan))
+    acc = R.evaluate_exact(plan, state)["test_acc"]
     # seeds-only features: random guessing = 1/6 = 0.167
     assert acc > 0.6, acc
 
@@ -54,12 +56,12 @@ def test_history_inference_matches_exact(hard_graph):
     g = hard_graph
     spec = GNNSpec(op="gcn", d_in=g.x.shape[1], d_hidden=32,
                    num_classes=g.num_classes, num_layers=2)
-    tcfg = TrainConfig(epochs=30, lr=0.01, seed=1)
-    gas = GASTrainer(g, spec, num_parts=6, tcfg=tcfg)
-    gas.fit()
-    exact = gas.evaluate()
+    plan = R.build_plan(g, spec, R.GASConfig(num_parts=6, epochs=30,
+                                             lr=0.01, seed=1))
+    state, _ = R.fit(plan, R.init_state(plan))
+    exact = R.evaluate_exact(plan, state)
     # history-based prediction (constant device memory, paper advantage #2)
-    logits = gas.gas_predict()
+    logits = R.predict(plan, state)
     pred = np.asarray(jnp.argmax(logits, -1))
     acc = float((pred[g.test_mask] == g.y[g.test_mask]).mean())
     assert abs(acc - exact["test_acc"]) < 0.05, (acc, exact["test_acc"])
@@ -70,10 +72,10 @@ def test_gas_handles_appnp_and_gcnii(hard_graph):
     for op, L in (("appnp", 4), ("gcnii", 8)):
         spec = GNNSpec(op=op, d_in=g.x.shape[1], d_hidden=32,
                        num_classes=g.num_classes, num_layers=L, alpha=0.1)
-        tcfg = TrainConfig(epochs=30, lr=0.01, seed=2)
-        gas = GASTrainer(g, spec, num_parts=6, tcfg=tcfg)
-        gas.fit()
-        acc = gas.evaluate()["test_acc"]
+        plan = R.build_plan(g, spec, R.GASConfig(num_parts=6, epochs=30,
+                                                 lr=0.01, seed=2))
+        state, _ = R.fit(plan, R.init_state(plan))
+        acc = R.evaluate_exact(plan, state)["test_acc"]
         assert acc > 0.4, (op, acc)
 
 
@@ -83,26 +85,31 @@ def test_gas_handles_gat_and_pna(hard_graph):
         spec = GNNSpec(op=op, d_in=g.x.shape[1], d_hidden=32,
                        num_classes=g.num_classes, num_layers=2,
                        log_deg_mean=float(np.log(g.degrees() + 1).mean()))
-        tcfg = TrainConfig(epochs=30, lr=0.01, seed=3)
-        gas = GASTrainer(g, spec, num_parts=6, tcfg=tcfg)
-        gas.fit()
-        acc = gas.evaluate()["test_acc"]
+        plan = R.build_plan(g, spec, R.GASConfig(num_parts=6, epochs=30,
+                                                 lr=0.01, seed=3))
+        state, _ = R.fit(plan, R.init_state(plan))
+        acc = R.evaluate_exact(plan, state)["test_acc"]
         assert acc > 0.4, (op, acc)
 
 
 def test_fused_epoch_matches_stepwise(hard_graph):
-    """The fused (lax.scan) epoch must produce the same training result as
-    the per-cluster step loop (EXPERIMENTS §Perf pair D2)."""
+    """The epoch (one jitted lax.scan) produces the same training result as
+    a loop of `train_step` over the batches in `train_epoch`'s order."""
     g = hard_graph
     spec = GNNSpec(op="gcn", d_in=g.x.shape[1], d_hidden=32,
                    num_classes=g.num_classes, num_layers=2)
-    tcfg = TrainConfig(epochs=15, lr=0.01, seed=4)
-    a = GASTrainer(g, spec, num_parts=6, tcfg=tcfg)
-    a.fit()
-    b = GASTrainer(g, spec, num_parts=6, fused_epoch=True, tcfg=tcfg)
-    b.fit()
-    acc_a = a.evaluate()["test_acc"]
-    acc_b = b.evaluate()["test_acc"]
+    cfg = R.GASConfig(num_parts=6, epochs=15, lr=0.01, seed=4)
+    a = R.build_plan(g, spec, cfg)
+    state_a = R.init_state(a)
+    for e in range(cfg.epochs):
+        order = np.random.default_rng(cfg.seed * 1000 + e).permutation(
+            a.batches.num_batches)
+        for bi in order:
+            state_a, _ = R.train_step(a, state_a, a.batch(int(bi)))
+    b = R.build_plan(g, spec, cfg, part=a.part)
+    state_b, _ = R.fit(b, R.init_state(b))
+    acc_a = R.evaluate_exact(a, state_a)["test_acc"]
+    acc_b = R.evaluate_exact(b, state_b)["test_acc"]
     assert abs(acc_a - acc_b) < 1e-6, (acc_a, acc_b)
 
 
