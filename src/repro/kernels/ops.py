@@ -324,7 +324,10 @@ def gas_aggregate(x_in: jnp.ndarray, table: jnp.ndarray,
     only one feature tile fits `fused.VMEM_BUDGET`), and every adjacency
     block then contracts against the panel; where neither fits, it
     falls back to staging each adjacency block's columns on its own, once
-    per row block (`fused` module docstring). The choice depends only on
+    per row block (`fused` module docstring). Either way a staged column
+    block is finished whole: its DMAs are drained by count, and its halo
+    rows are picked by strided sublane reads, then dequantized and routed
+    with whole-block selects. The choice depends only on
     shapes; tracing a call counts `gas/agg/panel_calls` or
     `gas/agg/per_block_calls` (`repro.utils.spans`). With `scales` [N]
     f32 the table is symmetric per-row int8

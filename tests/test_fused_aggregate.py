@@ -318,6 +318,72 @@ def test_gas_aggregate_staging_paths_match_oracle(case, hd, vmem_budget):
                                    rtol=1e-4, atol=1e-4, err_msg=name)
 
 
+def _route_problem(hd, n_in=100, max_h=150, N=325, D=256, bn=64, seed=5):
+    """A bracket whose column block 1 holds, together: x_in rows and halo
+    rows (n_in % 8 != 0), halo rows at every sublane offset 0-7 of their
+    tiles, one table row four times, masked rows, and rows of the
+    table's partial last tile (N % 8 == 5), masked and not."""
+    rng = np.random.default_rng(seed)
+    n8 = N // 8 * 8
+    hn = rng.integers(0, N, max_h).astype(np.int32)
+    hm = rng.random(max_h) < 0.8
+    hn[:8] = 8 * np.arange(3, 11) + np.arange(8)   # sublanes 0-7
+    hn[8:12] = 77                                  # one row, four times
+    hn[12:21] = [320, 321, 322, 323, 5, 6, 7, 324, 320]
+    hm[:12] = True
+    hm[12:21] = [True, True, True, True, False, False, False, False, True]
+    table = rng.normal(size=(N, D)).astype(np.float32)
+    scales = None
+    if hd == "int8":
+        table, scales = H.quantize_rows(jnp.asarray(table))
+        deq = np.asarray(table, np.float32) * np.asarray(scales)[:, None]
+    else:
+        table = jnp.asarray(table, jnp.dtype(hd if hd != "f32"
+                                             else "float32"))
+        deq = np.asarray(table.astype(jnp.float32))
+    x_in = rng.normal(size=(n_in, D)).astype(np.float32)
+    ncols = -(-(n_in + max_h + 1) // bn)
+    bracket = np.zeros((ncols * bn, D), np.float32)
+    bracket[:n_in] = x_in
+    bracket[n_in:n_in + max_h] = np.where(hm[:, None], deq[hn], 0.0)
+    # block 1 really holds what the docstring says
+    blk = slice(0, 2 * bn - n_in)          # its halo rows
+    assert n_in % 8 and bn < n_in < 2 * bn
+    t, ok = hn[blk], hm[blk]
+    assert set((t[ok & (t < n8)] % 8).tolist()) == set(range(8))
+    assert (ok & (t >= n8)).any() and (~ok).any()
+    assert np.bincount(t[ok]).max() >= 4
+    # identity adjacency blocks, row block c reading column block c: the
+    # output is the staged bracket itself
+    vals = jnp.broadcast_to(jnp.eye(bn, dtype=jnp.float32),
+                            (ncols, 1, bn, bn))
+    cols = jnp.arange(ncols, dtype=jnp.int32)[:, None]
+    return (jnp.asarray(x_in), table, vals, cols, jnp.asarray(hn),
+            jnp.asarray(hm), scales, bracket)
+
+
+@pytest.mark.parametrize("hd", ("f32", "bfloat16", "int8"))
+@pytest.mark.parametrize("path", ("panel", "one_tile_panel", "per_block"))
+def test_gather_spmm_stages_the_bracket_bitwise(path, hd, vmem_budget):
+    """The route itself: with identity blocks the output rows are the
+    staged rows, bit for bit `[x_in ; dequant(table)[halo] * mask ; 0]`,
+    on each staging path."""
+    x_in, table, vals, cols, hn, hm, scales, bracket = _route_problem(hd)
+    ncols, _, bn, _ = vals.shape
+    if path == "one_tile_panel":
+        vmem_budget(fused._panel_vmem(
+            ncols, x_in.shape[1], bn=bn, x_dtype=x_in.dtype, table=table,
+            vals_dtype=vals.dtype, codebook=None) - 1)
+    elif path == "per_block":
+        vmem_budget(1)
+    want = {"panel": 256, "one_tile_panel": 128, "per_block": None}[path]
+    assert fused.panel_width(x_in, table, vals, hn, bd=128) == want
+    out = fused.gather_spmm(x_in, table, vals, cols, hn, hm, scales,
+                            bn=bn, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out).view(np.uint32),
+                                  bracket.view(np.uint32))
+
+
 # ---------------------------------------------------------------------------
 # Edge-softmax (GAT) + multi-aggregator (PNA) kernels: fwd + grad vs the
 # segment_* reference, float32 and bfloat16, on every backend

@@ -198,6 +198,23 @@ def test_gather_spmm_staging_path(name, width):
                              bd=BN) == width
 
 
+@pytest.mark.parametrize("name", ["gather_spmm_cell_f32",
+                                  "gather_spmm_cell_int8"])
+def test_gather_spmm_cell_vmem_not_above_whole_row_staging(name):
+    """At the cells' shape the panel path's VMEM need stays at or below
+    13,385,728 bytes, what it was when each halo row was routed on its
+    own: a larger scoped limit can move XLA's placement of the arrays
+    around the kernel."""
+    from repro.kernels import fused
+    x_in, table, vals, _, halo_nodes = [jax.ShapeDtypeStruct(s, dt) for
+                                        s, dt in _cases()[name][1][:5]]
+    ncols = fused._ncols(N_IN_CELL, M_CELL, BN)
+    assert ncols == 81
+    assert fused._panel_vmem(ncols, D, bn=BN, x_dtype=x_in.dtype,
+                             table=table, vals_dtype=vals.dtype,
+                             codebook=None) <= 13_385_728
+
+
 @pytest.mark.parametrize("name", ["scatter_rows_cell_f32",
                                   "scatter_rows_cell_int8"])
 def test_scatter_rows_pushes_in_place(name, one_chip):
